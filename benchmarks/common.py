@@ -29,8 +29,9 @@ from repro.core import (EngineConfig, Gateway, InferenceEngine, Replica,
                         scale_gateway_config, summarize)
 from repro.core.client import merge_engine_timestamps, run_workload
 from repro.core.metrics import BenchmarkSummary
+from repro.core.replica import replica_device
 from repro.data.workload import WorkloadSpec, sample_workload
-from repro.models import build_model
+from repro.launch.serve import init_model
 
 ARCH = "mixtral-8x7b"          # the paper's evaluation model (reduced config)
 
@@ -48,9 +49,7 @@ _model_cache: Dict[str, tuple] = {}
 def get_model(arch: str = ARCH):
     if arch not in _model_cache:
         cfg = tiny_config(arch)
-        model = build_model(cfg)
-        params = model.init_params(jax.random.PRNGKey(0))
-        _model_cache[arch] = (cfg, model, params)
+        _model_cache[arch] = (cfg, *init_model(cfg))
     return _model_cache[arch]
 
 
@@ -69,7 +68,8 @@ def build_replicas(style: str, n_replicas: int = 1, *, arch: str = ARCH,
     if step_watchdog_s is not None:
         rkw["step_watchdog_s"] = step_watchdog_s
     return [Replica(f"{style}-{i}",
-                    InferenceEngine(model, params, EngineConfig(**kw), tracer=tracer),
+                    InferenceEngine(model, jax.device_put(params, replica_device(i)),
+                                    EngineConfig(**kw), tracer=tracer),
                     **rkw).start() for i in range(n_replicas)]
 
 

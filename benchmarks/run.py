@@ -10,6 +10,8 @@ throughput) so subsequent PRs have a perf trajectory to regress against.
 The ``traffic`` bench persists its own ``BENCH_traffic.{json,html,md}``
 (windowed SLO timeline + dashboard — see bench_traffic.py).
 Persisted payloads are stamped with the git revision and a UTC timestamp.
+A failing bench is reported and the others still run; the exit code is then
+non-zero.
 """
 from __future__ import annotations
 
@@ -22,7 +24,7 @@ PERSIST_JSON = {"prefill": "BENCH_prefill.json", "prefix": "BENCH_prefix.json",
                 "spec": "BENCH_spec.json"}
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--only", default=None,
@@ -38,6 +40,7 @@ def main() -> None:
                             bench_kernels, bench_prefill, bench_prefix,
                             bench_spec, bench_table1_streaming, bench_traffic)
     from benchmarks.common import stamp, warmup
+    from repro.launch.compile_cache import enable_compile_cache
 
     benches = {
         "fig2": bench_fig2_breakdown,
@@ -59,8 +62,10 @@ def main() -> None:
         ap.error(f"unknown bench name(s): {', '.join(unknown)} "
                  f"(registered: {', '.join(benches)})")
 
+    enable_compile_cache()
     print("name,us_per_call,derived")
     warmup()
+    failed = []
     for name in selected:
         mod = benches[name]
         t0 = time.time()
@@ -68,6 +73,7 @@ def main() -> None:
             rows = mod.run(quick=quick)
         except Exception as e:  # a failing bench must not hide the others
             print(f"{name}.ERROR,0,\"{type(e).__name__}: {e}\"", flush=True)
+            failed.append(name)
             continue
         for r in rows:
             derived = json.dumps(r["derived"], default=str).replace('"', "'")
@@ -78,7 +84,10 @@ def main() -> None:
                            "rows": rows}, f, indent=2, default=str)
             print(f"# wrote {PERSIST_JSON[name]}", file=sys.stderr)
         print(f"# {name} done in {time.time()-t0:.1f}s", file=sys.stderr)
+    if failed:
+        print(f"# failed: {', '.join(failed)}", file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

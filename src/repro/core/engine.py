@@ -132,8 +132,16 @@ class InferenceEngine:
         self.params = params
         self.cfg = cfg
         self.tracer = tracer
-        self.ctx = ctx or RunCtx(attn_backend="xla", moe_strategy="dropless",
-                                 block_q=128, block_kv=128)
+        # the backend follows the platform (RunCtx resolves "auto"): the
+        # compiled Pallas kernels on a TPU, the XLA references elsewhere
+        self.ctx = ctx or RunCtx(moe_strategy="dropless", block_q=128, block_kv=128)
+        # the engine runs where its params sit: the pool, the key and every
+        # per-step upload are created on that device, so each jitted call of
+        # this engine runs there (replicas pinned to different chips)
+        devices = {d for x in jax.tree.leaves(params) for d in x.devices()}
+        if len(devices) != 1:
+            raise ValueError(f"an engine's params must sit on one device, not {devices}")
+        self.device = devices.pop()
         cfgm = model.cfg
         self.pos_offset = cfgm.vision.n_patches if cfgm.vision is not None else 0
         self.chunk = min(cfg.prefill_chunk or max(cfg.prefill_bucket, 1), cfg.max_seq)
@@ -159,12 +167,13 @@ class InferenceEngine:
             cfg.max_slots, self.allocator, policy=cfg.scheduler, max_seq=cfg.max_seq,
             kv_extra=self.pos_offset, prefix_cache=self.prefix_cache,
             tracer=tracer)
-        self.cache = model.init_cache(
-            cfg.max_slots, cfg.max_seq, cfg.cache_dtype, kind="paged",
-            page_size=cfg.page_size, num_pages=cfg.num_pages)
+        with jax.default_device(self.device):
+            self.cache = self._put(model.init_cache(
+                cfg.max_slots, cfg.max_seq, cfg.cache_dtype, kind="paged",
+                page_size=cfg.page_size, num_pages=cfg.num_pages))
+            self._key = self._put(jax.random.PRNGKey(cfg.seed))
         self.page_table = np.zeros((cfg.max_slots, cfg.max_pages_per_seq), np.int32)
         self.extras: Dict[str, Any] = {}  # frames/patches per request (encdec/vlm)
-        self._key = jax.random.PRNGKey(cfg.seed)
         sampling = (cfg.temperature, cfg.top_p, cfg.greedy, cfg.page_size)
         self._step_jit = _cached_jit(
             "step", model, self.ctx, sampling,
@@ -241,6 +250,24 @@ class InferenceEngine:
             f"spec{width}", self.model, self.ctx, self._sampling,
             lambda: jax.jit(self._spec_fn, donate_argnums=(1,)))
 
+    def lower_steps(self) -> Dict[str, Any]:
+        """The two fixed-shape step programs, lowered for this engine's
+        device with the operands ``step()`` passes them: ``"prefill"``
+        (chunk_rows, chunk) and ``"decode"`` (max_slots, 1). Compiling one
+        compiles what that shape's first step would; the step then reuses
+        it."""
+        cfg = self.cfg
+        lowered = {}
+        for name, (B, C) in (("prefill", (self.chunk_rows, self.chunk)),
+                             ("decode", (cfg.max_slots, 1))):
+            ops = self._put((np.zeros((B, C), np.int32), np.zeros((B,), np.int32),
+                             np.zeros((B,), np.int32), np.arange(B, dtype=np.int32),
+                             np.zeros((B,), bool),
+                             np.zeros((B, cfg.max_pages_per_seq), np.int32)))
+            lowered[name] = self._step_jit.lower(self.params, self.cache, *ops,
+                                                 self._key, None, None)
+        return lowered
+
     def _copy_pages_fn(self, cache, src, dst):
         """Device-side page copy (the COW step): kp/vp[:, dst] = kp/vp[:, src]
         across every attention layer, in one fused call. Padding entries are
@@ -270,8 +297,8 @@ class InferenceEngine:
                 dst = np.zeros(COW_BUF, np.int32)
                 for j, (s, d) in enumerate(sub):
                     src[j], dst[j] = s, d
-                self.cache = self._cow_jit(self.cache, jnp.asarray(src),
-                                           jnp.asarray(dst))
+                self.cache = self._cow_jit(self.cache, self._put(src),
+                                           self._put(dst))
             copies = rest
 
     def _register_prefix(self, st: SlotState) -> None:
@@ -287,6 +314,10 @@ class InferenceEngine:
             st.registered_blocks = nb
 
     # ------------------------------------------------------------- helpers
+    def _put(self, x):
+        """Upload host operands (or move arrays) to this engine's device."""
+        return jax.device_put(x, self.device)
+
     def _next_key(self):
         self._key, k = jax.random.split(self._key)
         return k
@@ -312,7 +343,7 @@ class InferenceEngine:
                     v = np.asarray(v)[0]
                     m = min(M, v.shape[0])
                     fr[i, :m] = v[:m]
-            frames = jnp.asarray(fr)
+            frames = self._put(fr)
         if cfgm.vision is not None:
             Np, Dp = cfgm.vision.n_patches, cfgm.vision.d_patch
             pa = np.zeros((B, Np, Dp), np.float32)
@@ -320,7 +351,7 @@ class InferenceEngine:
                 v = self.extras.get((st.request.req_id, "patches"))
                 if v is not None:
                     pa[i] = np.asarray(v)[0]
-            patches = jnp.asarray(pa)
+            patches = self._put(pa)
         return frames, patches
 
     # ------------------------------------------------------------- step
@@ -469,9 +500,9 @@ class InferenceEngine:
             frames, patches = (self._row_extras(grants, B) if first.any()
                                else (None, None))
             nxt, self.cache = self._step_jit(
-                self.params, self.cache, jnp.asarray(tokens), jnp.asarray(starts),
-                jnp.asarray(nvalid), jnp.asarray(slots), jnp.asarray(first),
-                jnp.asarray(pt), self._next_key(), frames, patches)
+                self.params, self.cache,
+                *self._put((tokens, starts, nvalid, slots, first, pt)),
+                self._next_key(), frames, patches)
             nxt = np.asarray(nxt)
             t_emit = now()
             for i, (st, n) in enumerate(grants):
@@ -571,9 +602,9 @@ class InferenceEngine:
             starts[st.slot] = st.fed
             nvalid[st.slot] = 1
         nxt, self.cache = self._step_jit(
-            self.params, self.cache, jnp.asarray(tokens), jnp.asarray(starts),
-            jnp.asarray(nvalid), jnp.asarray(np.arange(M, dtype=np.int32)),
-            jnp.asarray(np.zeros((M,), bool)), jnp.asarray(self.page_table),
+            self.params, self.cache,
+            *self._put((tokens, starts, nvalid, np.arange(M, dtype=np.int32),
+                        np.zeros((M,), bool), self.page_table)),
             self._next_key(), None, None)
         nxt = np.asarray(nxt)
         t_emit = now()
@@ -623,9 +654,9 @@ class InferenceEngine:
             starts[st.slot] = st.fed
             nvalid[st.slot] = 1 + len(d)
         n_acc, out, self.cache = self._spec_jit_for(C)(
-            self.params, self.cache, jnp.asarray(tokens), jnp.asarray(starts),
-            jnp.asarray(nvalid), jnp.asarray(np.arange(M, dtype=np.int32)),
-            jnp.asarray(np.zeros((M,), bool)), jnp.asarray(self.page_table),
+            self.params, self.cache,
+            *self._put((tokens, starts, nvalid, np.arange(M, dtype=np.int32),
+                        np.zeros((M,), bool), self.page_table)),
             self._next_key())
         n_acc, out = np.asarray(n_acc), np.asarray(out)
         t_emit = now()

@@ -1,10 +1,10 @@
 """Replica: one inference engine + its serving thread + health state.
 
 "A replica represents the smallest unit of resource allocation and is
-designed to be homogeneous" (paper §3.1). Each replica owns an engine
-(optionally with a mesh slice / TP degree on real hardware) and steps it on a
-dedicated thread; token events are delivered to per-request callbacks from
-that thread (the gateway bridges them into asyncio).
+designed to be homogeneous" (paper §3.1). Each replica owns an engine on
+one device (``replica_device``) and steps it on a dedicated thread; token
+events are delivered to per-request callbacks from that thread (the gateway
+bridges them into asyncio).
 
 ``kill()`` simulates a replica failure: the thread stops and the in-flight
 requests (with their partial generations) are returned so the router can
@@ -17,10 +17,20 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
+import jax
+
 from repro.core.engine import InferenceEngine, TokenEvent
 from repro.core.metrics import Request
 
 OnEvent = Callable[[TokenEvent], None]
+
+
+def replica_device(i: int):
+    """Device of replica ``i``: the host's devices in turn, so replicas
+    beyond the device count share a chip. Its engine runs where its params
+    are put, so placing the params pins the replica."""
+    devices = jax.devices()
+    return devices[i % len(devices)]
 
 
 class Replica:

@@ -165,8 +165,8 @@ _CACHE_LOGICAL = {
     "k": ("layers", "batch", "kv_seq", "kv_heads", None),
     "v": ("layers", "batch", "kv_seq", "kv_heads", None),
     "slot_pos": ("layers", "batch", "kv_seq"),
-    "kp": ("layers", None, None, "kv_heads", None),
-    "vp": ("layers", None, None, "kv_heads", None),
+    "kp": ("layers", None, "kv_heads", None, None),
+    "vp": ("layers", None, "kv_heads", None, None),
     "ck": ("layers", "batch", None, "kv_heads", None),
     "cv": ("layers", "batch", None, "kv_heads", None),
     "state": ("layers", "batch", "ssm_heads", None, None),

@@ -17,6 +17,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import resolve
 from repro.kernels.flash_attention import kernel as _kernel
 
 
@@ -28,13 +29,6 @@ def _pad_to(x, axis, mult):
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, pad)
     return jnp.pad(x, widths)
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
 
 
 def flash_attention(
@@ -57,8 +51,7 @@ def flash_attention(
     _, Skv, Hkv, _ = k.shape
     if scale is None:
         scale = D ** -0.5
-    if backend == "auto":
-        backend = "pallas" if _on_tpu() else "xla"
+    backend, interpret = resolve(backend, interpret)
 
     if backend == "xla":
         return mha_chunked(
@@ -68,8 +61,6 @@ def flash_attention(
             unroll=unroll,
         )
 
-    if interpret is None:
-        interpret = not _on_tpu()
     block_q = min(block_q, max(Sq, 8))
     block_kv = min(block_kv, max(Skv, 128))
     qp = _pad_to(q, 1, block_q)

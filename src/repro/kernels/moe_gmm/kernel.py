@@ -4,8 +4,11 @@ Rows are sorted by expert and padded so every expert's rows occupy whole
 ``block_m`` tiles (the ops wrapper builds that layout with one scatter).
 ``tile_expert`` — the m-tile -> expert map — is a scalar-prefetch operand, so
 ``BlockSpec.index_map`` streams exactly one expert's weight tile per grid
-step. Grid = (m_tiles, n_tiles); K is kept whole in VMEM (fine for the d_ff
-sizes in the assigned archs: K*block_n*2B <= ~3MB).
+step. Grid = (m_tiles, n_tiles); K is kept whole in VMEM. The tiles enter
+the MXU in their own dtype and accumulate in f32: at Mixtral's down
+projection (K = 14336, bf16, 128x128 tiles) the four double-buffered input
+tiles take 14.7 MB, inside v5e's default 16 MiB scoped VMEM; f32 copies of
+them would not fit.
 """
 from __future__ import annotations
 
@@ -18,10 +21,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _gmm_kernel(te_ref, x_ref, w_ref, o_ref):
-    x = x_ref[...].astype(jnp.float32)       # (bm, K)
-    w = w_ref[0].astype(jnp.float32)         # (K, bn)
     o_ref[...] = jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        x_ref[...], w_ref[0], (((1,), (0,)), ((), ())),   # (bm, K) x (K, bn)
+        preferred_element_type=jnp.float32,
     ).astype(o_ref.dtype)
 
 

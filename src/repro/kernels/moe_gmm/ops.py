@@ -13,15 +13,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import resolve
 from repro.kernels.moe_gmm import kernel as _kernel
 from repro.kernels.moe_gmm.ref import expert_of_rows, gmm_reference
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "backend", "interpret"))
@@ -37,13 +31,10 @@ def gmm(
 ):
     M, K = x.shape
     E, _, N = w.shape
-    if backend == "auto":
-        backend = "pallas" if _on_tpu() else "xla"
+    backend, interpret = resolve(backend, interpret)
     if backend == "xla":
         return gmm_reference(x, w, group_sizes)
 
-    if interpret is None:
-        interpret = not _on_tpu()
     bn = min(block_n, N)
     # --- tile-aligned scatter ------------------------------------------------
     padded_sizes = ((group_sizes + block_m - 1) // block_m) * block_m

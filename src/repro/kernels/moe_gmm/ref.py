@@ -21,6 +21,6 @@ def gmm_reference(x, w, group_sizes):
     E, _, N = w.shape
     eid = expert_of_rows(group_sizes, M)
     # O(E * M * K * N) dense oracle: compute every expert for every row, select.
-    all_out = jnp.einsum("mk,ekn->emn", x.astype(jnp.float32), w.astype(jnp.float32))
+    all_out = jnp.einsum("mk,ekn->emn", x, w, preferred_element_type=jnp.float32)
     out = jnp.take_along_axis(all_out, eid[None, :, None], axis=0)[0]
     return out.astype(x.dtype)

@@ -4,7 +4,11 @@ TPU adaptation of PagedAttention (Kwon et al.): instead of CUDA
 pointer-chasing into the page pool, the page table is a **scalar-prefetch
 operand** and ``BlockSpec.index_map`` selects the physical page for each grid
 step — the Mosaic pipeline turns that into scheduled HBM->VMEM DMAs, which is
-the TPU-native form of paged KV gather (see DESIGN.md §2).
+the TPU-native form of paged KV gather (see DESIGN.md §2). The pool is laid
+out (P, Hkv, page_size, D), so each DMA moves one (page_size, D) tile of one
+kv head: the last two block dims are then whole array dims, as Mosaic
+requires (a (page_size, 1, D) block of a (P, page_size, Hkv, D) pool is
+refused).
 
 Decode: grid = (B, Hkv, max_pages); one query token per sequence; online
 softmax accumulates in VMEM scratch over the page sweep; pages past a
@@ -37,8 +41,8 @@ def _paged_kernel(
     len_ref,     # (B,) int32
     # inputs
     q_ref,       # (1, 1, G, D)
-    k_ref,       # (1, page_size, 1, D)
-    v_ref,       # (1, page_size, 1, D)
+    k_ref,       # (1, 1, page_size, D)
+    v_ref,       # (1, 1, page_size, D)
     # outputs
     o_ref,       # (1, 1, G, D)
     # scratch
@@ -68,8 +72,8 @@ def _paged_kernel(
     @pl.when(needed)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)          # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)    # (page_size, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)          # (page_size, D)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale                                     # (G, page_size)
@@ -106,7 +110,7 @@ def _paged_kernel(
 )
 def paged_attention_pallas(
     q,            # (B, H, D)
-    k_pages,      # (P, page_size, Hkv, D)
+    k_pages,      # (P, Hkv, page_size, D)
     v_pages,
     page_table,   # (B, max_pages) int32
     lengths,      # (B,) int32
@@ -117,7 +121,7 @@ def paged_attention_pallas(
     interpret: bool = False,
 ):
     B, H, D = q.shape
-    P, ps, Hkv, _ = k_pages.shape
+    P, Hkv, ps, _ = k_pages.shape
     maxp = page_table.shape[1]
     G = H // Hkv
     q4 = q.reshape(B, Hkv, G, D)
@@ -131,8 +135,8 @@ def paged_attention_pallas(
         grid=(B, Hkv, maxp),
         in_specs=[
             pl.BlockSpec((1, 1, G, D), lambda b, h, p, pt, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, ps, 1, D), lambda b, h, p, pt, ln: (pt[b, p], 0, h, 0)),
-            pl.BlockSpec((1, ps, 1, D), lambda b, h, p, pt, ln: (pt[b, p], 0, h, 0)),
+            pl.BlockSpec((1, 1, ps, D), lambda b, h, p, pt, ln: (pt[b, p], h, 0, 0)),
+            pl.BlockSpec((1, 1, ps, D), lambda b, h, p, pt, ln: (pt[b, p], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, p, pt, ln: (b, h, 0, 0)),
         scratch_shapes=[
@@ -157,8 +161,8 @@ def _chunked_prefill_kernel(
     start_ref,   # (B,) int32     absolute position of the chunk's first token
     # inputs
     q_ref,       # (1, 1, C*G, D)
-    k_ref,       # (1, page_size, 1, D)
-    v_ref,       # (1, page_size, 1, D)
+    k_ref,       # (1, 1, page_size, D)
+    v_ref,       # (1, 1, page_size, D)
     # outputs
     o_ref,       # (1, 1, C*G, D)
     # scratch
@@ -192,8 +196,8 @@ def _chunked_prefill_kernel(
     @pl.when(needed)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)          # (C*G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)    # (page_size, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)          # (page_size, D)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale                                     # (C*G, page_size)
@@ -231,7 +235,7 @@ def _chunked_prefill_kernel(
 )
 def chunked_prefill_pallas(
     q,            # (B, C, H, D)  chunk of C query tokens per sequence
-    k_pages,      # (P, page_size, Hkv, D)  pool, chunk KV already written
+    k_pages,      # (P, Hkv, page_size, D)  pool, chunk KV already written
     v_pages,
     page_table,   # (B, max_pages) int32
     lengths,      # (B,) int32   resident kv entries incl. this chunk
@@ -243,7 +247,7 @@ def chunked_prefill_pallas(
     interpret: bool = False,
 ):
     B, C, H, D = q.shape
-    P, ps, Hkv, _ = k_pages.shape
+    P, Hkv, ps, _ = k_pages.shape
     maxp = page_table.shape[1]
     G = H // Hkv
     # fold (chunk, G) into one row axis per kv head: row c*G + g <-> (c, g)
@@ -258,8 +262,8 @@ def chunked_prefill_pallas(
         grid=(B, Hkv, maxp),
         in_specs=[
             pl.BlockSpec((1, 1, C * G, D), lambda b, h, p, pt, ln, st: (b, h, 0, 0)),
-            pl.BlockSpec((1, ps, 1, D), lambda b, h, p, pt, ln, st: (pt[b, p], 0, h, 0)),
-            pl.BlockSpec((1, ps, 1, D), lambda b, h, p, pt, ln, st: (pt[b, p], 0, h, 0)),
+            pl.BlockSpec((1, 1, ps, D), lambda b, h, p, pt, ln, st: (pt[b, p], h, 0, 0)),
+            pl.BlockSpec((1, 1, ps, D), lambda b, h, p, pt, ln, st: (pt[b, p], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, C * G, D), lambda b, h, p, pt, ln, st: (b, h, 0, 0)),
         scratch_shapes=[

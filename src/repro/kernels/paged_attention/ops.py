@@ -1,24 +1,18 @@
 """Public paged attention ops (decode + chunked prefill) with pallas/xla
 dispatch.
 
-The xla path (gather via page_table indexing) is what the CPU serving engine
-executes; the pallas path is the TPU target, validated in interpret mode.
+The pallas path is what the engine serves on a TPU; the xla path (gather via
+page_table indexing) is what it serves elsewhere and the oracle the kernels
+are tested against, in interpret mode on the CPU.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import resolve
 from repro.kernels.paged_attention import kernel as _kernel
 from repro.kernels.paged_attention.ref import (chunked_prefill_reference,
                                                paged_attention_reference)
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
 
 
 def paged_attention(
@@ -28,15 +22,12 @@ def paged_attention(
 ):
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    if backend == "auto":
-        backend = "pallas" if _on_tpu() else "xla"
+    backend, interpret = resolve(backend, interpret)
     if backend == "xla":
         return paged_attention_reference(
             q, k_pages, v_pages, page_table, lengths,
             scale=scale, softcap=softcap, window=window,
         )
-    if interpret is None:
-        interpret = not _on_tpu()
     return _kernel.paged_attention_pallas(
         q, k_pages, v_pages, page_table, lengths,
         scale=scale, softcap=softcap, window=window, interpret=interpret,
@@ -58,15 +49,12 @@ def chunked_prefill_attention(
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    if backend == "auto":
-        backend = "pallas" if _on_tpu() else "xla"
+    backend, interpret = resolve(backend, interpret)
     if backend == "xla":
         return chunked_prefill_reference(
             q, k_pages, v_pages, page_table, lengths, q_positions,
             scale=scale, softcap=softcap, window=window,
         )
-    if interpret is None:
-        interpret = not _on_tpu()
     starts = q_positions[:, 0].astype(jnp.int32)
     return _kernel.chunked_prefill_pallas(
         q, k_pages, v_pages, page_table, lengths, starts,

@@ -2,8 +2,8 @@
 
 Decode (one query token per sequence):
   q:          (B, H, D)
-  k_pages:    (P, page_size, Hkv, D)   global physical page pool
-  v_pages:    (P, page_size, Hkv, D)
+  k_pages:    (P, Hkv, page_size, D)   global physical page pool
+  v_pages:    (P, Hkv, page_size, D)
   page_table: (B, max_pages)    int32 physical page id per logical page
   lengths:    (B,)              valid kv entries per sequence (incl. current)
 
@@ -19,18 +19,26 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 
+def gather_pages(pages, page_table):
+    """(P, Hkv, ps, D) pool -> (B, maxp * ps, Hkv, D) per-sequence kv."""
+    B, maxp = page_table.shape
+    _, Hkv, ps, D = pages.shape
+    g = pages[page_table].transpose(0, 1, 3, 2, 4)       # (B, maxp, ps, Hkv, D)
+    return g.reshape(B, maxp * ps, Hkv, D)
+
+
 def paged_attention_reference(
     q, k_pages, v_pages, page_table, lengths, *, scale=None, softcap: float = 0.0, window: int = 0
 ):
     B, H, D = q.shape
-    P, ps, Hkv, _ = k_pages.shape
+    P, Hkv, ps, _ = k_pages.shape
     maxp = page_table.shape[1]
     group = H // Hkv
     if scale is None:
         scale = D ** -0.5
 
-    k = k_pages[page_table].reshape(B, maxp * ps, Hkv, D)
-    v = v_pages[page_table].reshape(B, maxp * ps, Hkv, D)
+    k = gather_pages(k_pages, page_table)
+    v = gather_pages(v_pages, page_table)
     k = jnp.repeat(k, group, axis=2)
     v = jnp.repeat(v, group, axis=2)
 
@@ -59,14 +67,14 @@ def chunked_prefill_reference(
     attends causally to kv positions <= q_positions[b, i] (clipped to
     lengths[b]); rows where q_positions is past lengths produce zeros."""
     B, S, H, D = q.shape
-    P, ps, Hkv, _ = k_pages.shape
+    P, Hkv, ps, _ = k_pages.shape
     maxp = page_table.shape[1]
     group = H // Hkv
     if scale is None:
         scale = D ** -0.5
 
-    k = k_pages[page_table].reshape(B, maxp * ps, Hkv, D)
-    v = v_pages[page_table].reshape(B, maxp * ps, Hkv, D)
+    k = gather_pages(k_pages, page_table)
+    v = gather_pages(v_pages, page_table)
     k = jnp.repeat(k, group, axis=2)
     v = jnp.repeat(v, group, axis=2)
 

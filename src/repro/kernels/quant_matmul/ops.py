@@ -1,18 +1,11 @@
 """w8a16 matmul op with pallas/xla dispatch + quantize helper."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import resolve
 from repro.kernels.quant_matmul import kernel as _kernel
 from repro.kernels.quant_matmul.ref import w8a16_matmul_reference
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
 
 
 def quantize_int8(w, axis: int = 0):
@@ -25,10 +18,7 @@ def quantize_int8(w, axis: int = 0):
 
 
 def w8a16_matmul(x, w_q, scale, *, backend: str = "auto", interpret: bool | None = None, **blocks):
-    if backend == "auto":
-        backend = "pallas" if _on_tpu() else "xla"
+    backend, interpret = resolve(backend, interpret)
     if backend == "xla":
         return w8a16_matmul_reference(x, w_q, scale)
-    if interpret is None:
-        interpret = not _on_tpu()
     return _kernel.w8a16_matmul_pallas(x, w_q, scale, interpret=interpret, **blocks)

@@ -3,30 +3,20 @@ Same signature as models.mamba.ssd_chunked (minus init_state: the kernel owns
 the state in VMEM; chunked-prefill continuation uses the jnp path)."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import resolve
 from repro.kernels.ssd_scan import kernel as _kernel
 from repro.kernels.ssd_scan.ref import ssd_reference
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
 
 
 def ssd_scan(x, dt, A, B_, C, chunk: int, *, backend: str = "auto",
              interpret: bool | None = None):
     """x (B,L,H,P); dt (B,L,H) post-softplus; A (H,) negative; B_/C (B,L,H,N).
     Returns y (B,L,H,P) — matches ssd_reference(...)[0]."""
-    if backend == "auto":
-        backend = "pallas" if _on_tpu() else "xla"
+    backend, interpret = resolve(backend, interpret)
     if backend == "xla":
         return ssd_reference(x, dt, A, B_, C, chunk)[0]
-    if interpret is None:
-        interpret = not _on_tpu()
 
     Bb, L, H, P = x.shape
     N = B_.shape[-1]

@@ -1,11 +1,16 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
-# NOTE: the two lines above MUST run before any jax import — jax locks the
-# device count on first init. Do not set this flag globally (smoke tests and
-# benches should see 1 device).
+# The dry-run compiles for 512 virtual CPU devices and must never take an
+# accelerator, nor let its --all children (one per cell) compete for one.
+# Both settings MUST precede any jax import: jax fixes its platform and
+# device count on first init. The caller's other XLA flags are kept.
+_DEVICE_FLAG = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
+if _DEVICE_FLAG not in os.environ.get("XLA_FLAGS", ""):
+    os.environ["XLA_FLAGS"] = f"{os.environ.get('XLA_FLAGS', '')} {_DEVICE_FLAG}".strip()
 
-__doc__ = """Multi-pod dry-run: prove the distribution config is coherent.
+__doc__ = """Multi-pod dry-run on the CPU: prove the distribution config is coherent
+for meshes of TPU v5e chips (roofline peaks: ``roofline.peaks(V5E_KIND)``).
 
 For every (architecture x input shape) cell, on the single-pod (16,16) and
 multi-pod (2,16,16) production meshes:
@@ -38,8 +43,8 @@ from repro.distributed.sharding import (cache_partition_specs, make_rules, named
                                         param_partition_specs, partition_spec,
                                         use_sharding)
 from repro.launch.mesh import make_production_mesh
-from repro.launch.roofline import (V5E, local_bytes, model_flops,
-                                   parse_collective_bytes, roofline)
+from repro.launch.roofline import (V5E_KIND, local_bytes, model_flops,
+                                   parse_collective_bytes, peaks, roofline)
 from repro.models import RunCtx, build_model
 from repro.models.params import abstract_params, param_specs
 from repro.training.optimizer import AdamWState, adamw_init
@@ -360,19 +365,20 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, moe_mode: str = "tp
     meta["collectives"] = {k.removeprefix("coll_"): v for k, v in full.items()
                            if k.startswith("coll_")}
 
-    # ---- 3) roofline ----
-    terms = roofline(full["flops"], full["bytes"], full["coll_total"])
+    # ---- 3) roofline, against the chip the production meshes are made of ----
+    hw = peaks(V5E_KIND)
+    terms = roofline(full["flops"], full["bytes"], full["coll_total"], hw)
     meta["roofline"] = terms.as_dict()
     # memory floor: weights read once + cache streamed once — the fused-TPU
     # lower bound; XLA's bytes_accessed counts unfused copies and is the
     # upper bound. Real HBM time lies between.
     floor_bytes = sizes["params_local_bytes"] + sizes.get("cache_local_bytes", 0)
-    meta["roofline"]["memory_floor_s"] = floor_bytes / V5E["hbm_bw"]
+    meta["roofline"]["memory_floor_s"] = floor_bytes / hw["hbm_bw"]
     mf = model_flops(cfg, shape)
     meta["model_flops_global"] = mf
     meta["model_flops_per_dev"] = mf / n_dev
     meta["useful_ratio"] = (mf / n_dev) / full["flops"] if full["flops"] else 0.0
-    ideal_s = (mf / n_dev) / 197e12
+    ideal_s = (mf / n_dev) / hw["peak_flops_bf16"]
     meta["roofline_fraction"] = ideal_s / terms.bound_s if terms.bound_s > 0 else 0.0
     return meta
 

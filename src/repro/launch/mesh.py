@@ -12,16 +12,10 @@ paper's EP/TP sweep.
 from __future__ import annotations
 
 import jax
-
-try:                           # jax >= 0.4.35
-    from jax.sharding import AxisType
-except ImportError:            # older jax: meshes are Auto-typed already
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _mk(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape))
 
 

@@ -1,8 +1,9 @@
-"""Roofline analysis (TPU v5e target) from compiled dry-run artifacts.
+"""Roofline analysis from compiled dry-run artifacts, against the published
+peaks of one chip (``PEAKS``, keyed by JAX's ``device_kind``):
 
-  compute term    = HLO_FLOPs_per_device / 197e12       (bf16 MXU peak)
-  memory term     = HLO_bytes_per_device / 819e9        (HBM bandwidth)
-  collective term = collective_bytes_per_device / 50e9  (ICI per-link)
+  compute term    = HLO_FLOPs_per_device / peak_flops_bf16
+  memory term     = HLO_bytes_per_device / hbm_bw
+  collective term = collective_bytes_per_device / ici_bw  (one link)
 
 ``cost_analysis()`` supplies FLOPs / bytes of the SPMD-partitioned
 per-device program. Collective bytes are NOT in cost_analysis — we parse the
@@ -17,13 +18,29 @@ import re
 from dataclasses import dataclass
 from typing import Dict
 
-V5E = {
-    "name": "TPU v5e",
-    "peak_flops_bf16": 197e12,     # per chip
-    "hbm_bw": 819e9,               # bytes/s per chip
-    "ici_bw": 50e9,                # bytes/s per link (~1 effective link assumed)
-    "hbm_capacity": 16e9,
+# Per-chip peaks by ``jax.devices()[0].device_kind``. Source: Google Cloud
+# documentation, "TPU v5e" (cloud.google.com/tpu/docs/v5e): 197 TFLOP/s bf16,
+# 16 GB of HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect (four
+# links of 50 GB/s; the collective term assumes one).
+PEAKS = {
+    "TPU v5 lite": {
+        "name": "TPU v5e",
+        "peak_flops_bf16": 197e12,
+        "hbm_bw": 819e9,
+        "ici_bw": 50e9,
+        "hbm_capacity": 16e9,
+    },
 }
+V5E_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """Published peaks of one chip of this kind; a kind without a table
+    entry is an error, never a default."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind {device_kind!r} "
+                       f"(known: {sorted(PEAKS)})")
+    return PEAKS[device_kind]
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -94,7 +111,7 @@ class RooflineTerms:
 
 
 def roofline(flops_per_dev: float, bytes_per_dev: float,
-             coll_bytes_per_dev: float, hw=V5E) -> RooflineTerms:
+             coll_bytes_per_dev: float, hw: Dict[str, float]) -> RooflineTerms:
     return RooflineTerms(
         compute_s=flops_per_dev / hw["peak_flops_bf16"],
         memory_s=bytes_per_dev / hw["hbm_bw"],

@@ -7,7 +7,7 @@ Cache formats (per layer, unstacked — the scan adds the leading layers dim):
          W = min(max_seq, window) — a ring buffer; slot_pos holds the absolute
          position stored in each slot (-1 = empty). Full attention is W=max_seq
          (slot == position) through the same code.
-  paged: {"kp": (P, ps, Hkv, hd), "vp": ...} + engine-level page_table/lengths.
+  paged: {"kp": (P, Hkv, ps, hd), "vp": ...} + engine-level page_table/lengths.
   cross: {"ck": (B, M, Hkv, hd), "cv": ...} built once at prefill.
 
 Mode "chunk" is the serving engine's unified iteration (DESIGN.md §2): each
@@ -91,14 +91,14 @@ def _write_ring(cache, k, v, positions):
 
 def _write_paged(cache, k, v, positions, page_table):
     """k (B,1,Hkv,hd); positions (B,) absolute; page_table (B, maxp)."""
-    ps = cache["kp"].shape[1]
+    ps = cache["kp"].shape[2]
     b_idx = jnp.arange(k.shape[0])
     logical = positions // ps
     slot = positions % ps
     phys = page_table[b_idx, logical]
     return {
-        "kp": cache["kp"].at[phys, slot].set(k[:, 0]),
-        "vp": cache["vp"].at[phys, slot].set(v[:, 0]),
+        "kp": cache["kp"].at[phys, :, slot].set(k[:, 0].astype(cache["kp"].dtype)),
+        "vp": cache["vp"].at[phys, :, slot].set(v[:, 0].astype(cache["vp"].dtype)),
     }
 
 
@@ -108,7 +108,7 @@ def _write_paged_chunk(cache, k, v, positions, page_table, valid):
     k/v (B, S, Hkv, hd); positions (B, S) absolute; valid (B, S). Invalid
     positions are routed to the reserved null page 0 (the allocator never
     hands it out), so one fixed-shape scatter serves ragged chunks."""
-    ps = cache["kp"].shape[1]
+    ps = cache["kp"].shape[2]
     B, S = positions.shape
     maxp = page_table.shape[1]
     logical = jnp.clip(positions // ps, 0, maxp - 1)
@@ -118,8 +118,8 @@ def _write_paged_chunk(cache, k, v, positions, page_table, valid):
     kf = k.reshape(B * S, *k.shape[2:]).astype(cache["kp"].dtype)
     vf = v.reshape(B * S, *v.shape[2:]).astype(cache["vp"].dtype)
     return {
-        "kp": cache["kp"].at[pf, sf].set(kf),
-        "vp": cache["vp"].at[pf, sf].set(vf),
+        "kp": cache["kp"].at[pf, :, sf].set(kf),
+        "vp": cache["vp"].at[pf, :, sf].set(vf),
     }
 
 
@@ -174,8 +174,8 @@ def attention_sublayer(
             new_cache = {"ck": ck, "cv": cv} if cache is not None else None
         o = flash_attention(
             q, ck, cv, causal=False, softcap=softcap, scale=scale,
-            backend=ctx.attn_backend, block_q=ctx.block_q, block_kv=ctx.block_kv,
-            unroll=ctx.attn_unroll,
+            backend=ctx.attn_backend, interpret=ctx.interpret,
+            block_q=ctx.block_q, block_kv=ctx.block_kv, unroll=ctx.attn_unroll,
         )
         return _out_proj(p, o), new_cache
 
@@ -228,8 +228,8 @@ def attention_sublayer(
     k_roped = shard_act(k_roped, ("batch", None, "kv_heads", None))
     o = flash_attention(
         q, k_roped, v, causal=causal, window=window, softcap=softcap, scale=scale,
-        backend=ctx.attn_backend, block_q=ctx.block_q, block_kv=ctx.block_kv,
-        unroll=ctx.attn_unroll,
+        backend=ctx.attn_backend, interpret=ctx.interpret,
+        block_q=ctx.block_q, block_kv=ctx.block_kv, unroll=ctx.attn_unroll,
     )
     new_cache = cache
     if cache is not None and "k" in cache:                 # prefill: persist kv

@@ -7,33 +7,42 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import resolve
+
 
 @dataclass(frozen=True)
 class RunCtx:
     """Threaded through every layer: mode + execution knobs.
 
     mode: "train" | "prefill" | "decode"
-    attn_backend: "auto" | "pallas" | "xla"  (xla = chunked online-softmax jnp;
-        it is what the dry-run lowers; pallas is the TPU kernel)
+    attn_backend: "auto" | "pallas" | "xla" for attention and the MoE gmm
+        (xla = the jnp references, which the dry-run lowers; pallas = the
+        TPU kernels). "auto" is resolved once, at construction, from the
+        platform: compiled Pallas on a TPU, xla elsewhere.
+    interpret: run Pallas kernels in the interpreter (CPU tests only)
     moe_strategy: "dropless" (exact, serving engine) | "capacity" (local
         capacity buffers) | "tp_shardmap" | "ep_shardmap" (explicit collectives)
     """
     mode: str = "train"
     mesh: Any = None
-    attn_backend: str = "xla"
+    attn_backend: str = "auto"
     moe_strategy: str = "capacity"
     remat: bool = False
     block_q: int = 512
     block_kv: int = 1024
     ep_axis: str = "data"
     tp_axis: str = "model"
-    interpret: bool = True      # pallas interpret mode (CPU)
+    interpret: bool = False
     quant: str = "none"         # none | int8 (weight-only serving quant)
     # Cost-model lowering knobs (launch/dryrun): XLA's cost_analysis counts
     # loop bodies ONCE, so the roofline cost lowering unrolls layers and
     # attention tiles (small repeat counts; affine extrapolation).
     scan_layers: bool = True
     attn_unroll: bool = False
+
+    def __post_init__(self):
+        backend, _ = resolve(self.attn_backend, self.interpret)
+        object.__setattr__(self, "attn_backend", backend)
 
     def with_mode(self, mode: str) -> "RunCtx":
         return replace(self, mode=mode)

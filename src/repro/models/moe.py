@@ -104,11 +104,11 @@ def moe_dropless(p, xf, cfg: ModelConfig, ctx: RunCtx):
     order = jnp.argsort(e)
     xs = xf[tok[order]]
     gs = jnp.bincount(e, length=E).astype(jnp.int32)
-    backend = "pallas" if ctx.attn_backend == "pallas" else "xla"
-    h1 = gmm(xs, p["wg"], gs, backend=backend, interpret=ctx.interpret)
-    h2 = gmm(xs, p["wu"], gs, backend=backend, interpret=ctx.interpret)
+    kw = dict(backend=ctx.attn_backend, interpret=ctx.interpret)
+    h1 = gmm(xs, p["wg"], gs, **kw)
+    h2 = gmm(xs, p["wu"], gs, **kw)
     ys = gmm((jax.nn.silu(h1.astype(jnp.float32)) * h2.astype(jnp.float32)).astype(xs.dtype),
-             p["wd"], gs, backend=backend, interpret=ctx.interpret)
+             p["wd"], gs, **kw)
     w_flat = topw.reshape(-1)[order]
     y = jnp.zeros((T, d), jnp.float32).at[tok[order]].add(ys.astype(jnp.float32) * w_flat[:, None])
     if "shared" in p:

@@ -403,8 +403,8 @@ class LM:
                     W = min(max_seq, cfg.sliding_window) if (k == "L" and cfg.sliding_window) else max_seq
                     if kind == "paged":
                         c["attn"] = {
-                            "kp": jnp.zeros((R, num_pages, page_size, Hkv, hd), dtype),
-                            "vp": jnp.zeros((R, num_pages, page_size, Hkv, hd), dtype),
+                            "kp": jnp.zeros((R, num_pages, Hkv, page_size, hd), dtype),
+                            "vp": jnp.zeros((R, num_pages, Hkv, page_size, hd), dtype),
                         }
                     else:
                         c["attn"] = {

@@ -31,8 +31,8 @@ def setup():
 # ---------------------------------------------------------------- kernel
 def _rand_pool(rng, B, maxp, ps, Hkv, D):
     P = B * maxp + 1
-    kp = jnp.asarray(rng.standard_normal((P, ps, Hkv, D)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((P, ps, Hkv, D)), jnp.float32)
+    kp = jnp.asarray(rng.standard_normal((P, Hkv, ps, D)), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((P, Hkv, ps, D)), jnp.float32)
     pt = jnp.asarray([[1 + b * maxp + i for i in range(maxp)] for b in range(B)],
                      jnp.int32)
     return kp, vp, pt
@@ -49,8 +49,8 @@ def test_chunked_kernel_matches_bruteforce(window):
     q = jnp.asarray(rng.standard_normal((B, C, H, D)), jnp.float32)
     qpos = starts[:, None] + jnp.arange(C)[None]
 
-    kg = np.asarray(kp)[np.asarray(pt)].reshape(B, maxp * ps, Hkv, D)
-    vg = np.asarray(vp)[np.asarray(pt)].reshape(B, maxp * ps, Hkv, D)
+    kg = np.asarray(kp)[np.asarray(pt)].transpose(0, 1, 3, 2, 4).reshape(B, maxp * ps, Hkv, D)
+    vg = np.asarray(vp)[np.asarray(pt)].transpose(0, 1, 3, 2, 4).reshape(B, maxp * ps, Hkv, D)
     oracle = np.zeros((B, C, H, D), np.float32)
     for b in range(B):
         for i in range(C):

@@ -8,6 +8,7 @@ import pytest
 from repro.configs import tiny_config
 from repro.core.engine import EngineConfig, InferenceEngine, sample_tokens
 from repro.core.metrics import Request
+from repro.kernels.backend import on_tpu, resolve
 from repro.models import RunCtx, build_model
 
 CTX = RunCtx(attn_backend="xla", moe_strategy="dropless", block_q=128, block_kv=128)
@@ -47,6 +48,23 @@ def test_engine_matches_reference(setup, num_pages):
     for req, p in zip(reqs, prompts):
         assert req.finished
         assert req.generated == _ref_greedy(model, params, p, 12)
+
+
+def test_backend_follows_platform(setup):
+    """"auto" is resolved once, from the platform: the compiled Pallas
+    kernels on a TPU, the XLA references here. The engine's default takes
+    it; an explicit choice is kept; an unknown backend is an error."""
+    cfg, model, params = setup
+    want = "pallas" if on_tpu() else "xla"
+    assert RunCtx().attn_backend == want and not RunCtx().interpret
+    assert resolve("auto", None) == (want, not on_tpu())
+    assert RunCtx(attn_backend="pallas", interpret=True).attn_backend == "pallas"
+    eng = InferenceEngine(model, params, EngineConfig(max_slots=2, max_seq=32,
+                                                      num_pages=16))
+    assert eng.ctx.attn_backend == want and not eng.ctx.interpret
+    assert eng.device == jax.devices()[0]
+    with pytest.raises(ValueError, match="cuda"):
+        RunCtx(attn_backend="cuda")
 
 
 def test_static_mode_completes(setup):

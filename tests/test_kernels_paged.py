@@ -13,8 +13,8 @@ from repro.kernels.paged_attention import paged_attention, paged_attention_refer
 def test_paged_vs_reference(rng, H, Hkv, D, page_size, window):
     B, P, maxp = 3, 24, 5
     q = jnp.asarray(rng.standard_normal((B, H, D)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((P, page_size, Hkv, D)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((P, page_size, Hkv, D)), jnp.float32)
+    kp = jnp.asarray(rng.standard_normal((P, Hkv, page_size, D)), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((P, Hkv, page_size, D)), jnp.float32)
     pt = jnp.asarray(rng.integers(1, P, (B, maxp)), jnp.int32)
     lengths = jnp.asarray([1, page_size * 2 + 3, maxp * page_size], jnp.int32)
     ref = paged_attention_reference(q, kp, vp, pt, lengths, window=window)
@@ -26,8 +26,8 @@ def test_paged_vs_reference(rng, H, Hkv, D, page_size, window):
 def test_paged_bf16(rng):
     B, H, Hkv, D, P, ps, maxp = 2, 4, 2, 32, 16, 8, 4
     q = jnp.asarray(rng.standard_normal((B, H, D)), jnp.bfloat16)
-    kp = jnp.asarray(rng.standard_normal((P, ps, Hkv, D)), jnp.bfloat16)
-    vp = jnp.asarray(rng.standard_normal((P, ps, Hkv, D)), jnp.bfloat16)
+    kp = jnp.asarray(rng.standard_normal((P, Hkv, ps, D)), jnp.bfloat16)
+    vp = jnp.asarray(rng.standard_normal((P, Hkv, ps, D)), jnp.bfloat16)
     pt = jnp.asarray(rng.integers(0, P, (B, maxp)), jnp.int32)
     lengths = jnp.asarray([7, 30], jnp.int32)
     ref = paged_attention_reference(q, kp, vp, pt, lengths)
@@ -39,8 +39,8 @@ def test_paged_bf16(rng):
 def test_softcap(rng):
     B, H, Hkv, D, P, ps, maxp = 2, 4, 2, 16, 8, 4, 3
     q = jnp.asarray(rng.standard_normal((B, H, D)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((P, ps, Hkv, D)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((P, ps, Hkv, D)), jnp.float32)
+    kp = jnp.asarray(rng.standard_normal((P, Hkv, ps, D)), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((P, Hkv, ps, D)), jnp.float32)
     pt = jnp.asarray(rng.integers(0, P, (B, maxp)), jnp.int32)
     lengths = jnp.asarray([5, 12], jnp.int32)
     ref = paged_attention_reference(q, kp, vp, pt, lengths, softcap=30.0)

@@ -69,7 +69,8 @@ def test_paged_equals_dense_decode():
                 buf = pc["attn"][name]
                 for b in range(B):
                     buf = buf.at[:, pt[b][:npg]].set(
-                        src[:, b].reshape(R, npg, ps, *src.shape[3:]))
+                        src[:, b].reshape(R, npg, ps, *src.shape[3:])
+                        .transpose(0, 1, 3, 2, 4))
                 pc["attn"][name] = buf
     cd, cp, errs = dense, paged, []
     for i in range(gen):

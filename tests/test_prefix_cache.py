@@ -2,6 +2,7 @@
 prefill and reproduce no-sharing outputs, COW on page-aligned prompts,
 preempt/resume/cancel with shared pages leak nothing, SSM/encdec gating."""
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -23,11 +24,11 @@ def setup():
 
 
 def _engine(model, params, *, cache=True, pages=64, slots=4, chunk=PS,
-            budget=0, max_seq=64):
+            budget=0, max_seq=64, cache_dtype=jnp.float32):
     return InferenceEngine(model, params, EngineConfig(
         max_slots=slots, page_size=PS, num_pages=pages, max_seq=max_seq,
         prefill_chunk=chunk, token_budget=budget, greedy=True,
-        enable_prefix_cache=cache))
+        enable_prefix_cache=cache, cache_dtype=cache_dtype))
 
 
 def _reqs(prompts, max_new=6, tag=""):
@@ -106,6 +107,26 @@ def test_page_aligned_prompt_triggers_cow_and_matches(setup):
     assert eng.allocator.cow_copies >= 1
     assert eng.prefix_cached_tokens == 2 * PS - 1
     assert [q.generated for q in reqs] == _reference(model, params, [prompt])
+
+
+def test_bf16_params_and_pool_through_cow(setup):
+    """The chip's dtypes: bf16 params and a bf16 pool through chunked
+    prefill, a prefix hit, the COW page copy and greedy sampling give the
+    tokens of a bf16 engine without sharing."""
+    cfg, model, _ = setup
+    params = model.init_params(jax.random.PRNGKey(0), dtype=jnp.bfloat16)
+    prompt = np.random.default_rng(13).integers(1, cfg.vocab, 2 * PS).astype(np.int32)
+    eng = _engine(model, params, cache_dtype=jnp.bfloat16)
+    eng.generate(_reqs([prompt], tag="cold"))
+    reqs = _reqs([prompt], tag="warm")
+    eng.generate(reqs)
+    eng.allocator.check_invariants()
+    assert eng.allocator.cow_copies >= 1
+    assert eng.cache["groups"][0][0]["attn"]["kp"].dtype == jnp.bfloat16
+    ref = _engine(model, params, cache=False, cache_dtype=jnp.bfloat16)
+    ref_reqs = _reqs([prompt], tag="ref")
+    ref.generate(ref_reqs)
+    assert reqs[0].generated == ref_reqs[0].generated
 
 
 def test_preempt_resume_with_shared_pages_no_leak(setup):

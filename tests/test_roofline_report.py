@@ -5,7 +5,8 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.data.workload import WorkloadSpec, sample_workload
-from repro.launch.roofline import model_flops, parse_collective_bytes, roofline
+from repro.launch.roofline import (V5E_KIND, model_flops, parse_collective_bytes,
+                                   peaks, roofline)
 
 HLO = """
 ENTRY %main {
@@ -34,13 +35,19 @@ def test_parse_collective_bytes():
 
 
 def test_roofline_terms_and_dominant():
-    t = roofline(197e12, 819e9, 25e9)     # 1s compute, 1s memory, 0.5s coll
+    v5e = peaks(V5E_KIND)
+    t = roofline(197e12, 819e9, 25e9, v5e)     # 1s compute, 1s memory, 0.5s coll
     assert abs(t.compute_s - 1.0) < 1e-9
     assert abs(t.memory_s - 1.0) < 1e-9
     assert abs(t.collective_s - 0.5) < 1e-9
-    t2 = roofline(1e12, 1e9, 100e9)
+    t2 = roofline(1e12, 1e9, 100e9, v5e)
     assert t2.dominant == "collective"
     assert t2.bound_s == t2.collective_s
+
+
+def test_peaks_refuse_unknown_device_kind():
+    with pytest.raises(KeyError, match="cpu"):
+        peaks("cpu")
 
 
 def test_model_flops_conventions():
