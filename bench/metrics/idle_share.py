@@ -1,0 +1,9 @@
+"""Device: the share of the traced slice in which no operation ran on the
+chip (mean over chips), percent."""
+
+
+def read(run):
+    tr = run.trace
+    if not tr or not tr.get("window_s"):
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
