@@ -92,7 +92,7 @@ def _prewarm(model, params, c: int, k: int, prompts: List[np.ndarray]) -> None:
     eng = _engine(model, params, c, spec=True, k=k)
     zeros = np.zeros((c,), np.int32)
     for width in eng._spec_widths:
-        _, _, eng.cache = eng._spec_jit_for(width)(
+        *_, eng.cache = eng._spec_jit_for(width)(
             eng.params, eng.cache, jax.numpy.zeros((c, width), jax.numpy.int32),
             jax.numpy.asarray(zeros), jax.numpy.asarray(zeros),
             jax.numpy.arange(c, dtype=jax.numpy.int32),

@@ -6,10 +6,12 @@ Design (TPU-native, runs for real on CPU; see DESIGN.md §2):
   - prefill and decode are ONE model path (``LM.decode_chunk``): every batch
     row feeds a chunk of tokens of one sequence whose KV is written straight
     into the paged pool. Decode is a chunk of 1.
-  - two fixed call shapes, each compiled once: (chunk_rows, prefill_chunk)
-    for the prefill pack and (max_slots, 1) for the decode sweep. There is
-    no per-length bucket recompile ladder, no dense per-request prefill
-    cache, and no post-prefill scatter copy.
+  - two fixed call shapes, each compiled once as its own named program:
+    ``prefill_step`` (chunk_rows, prefill_chunk) for the prefill pack and
+    ``decode_step`` (max_slots, 1) for the decode sweep (``spec_step_<C>``
+    per speculative width, ``cow_copy`` for page copies), so the device
+    trace tells them apart. There is no per-length bucket recompile ladder,
+    no dense per-request prefill cache, and no post-prefill scatter copy.
   - each ``step()`` is a token-budget iteration (Sarathi-style): all pending
     decode tokens plus up to ``token_budget - n_decode`` prefill-chunk
     tokens. Long prompts prefill over several iterations, so an admitted
@@ -35,7 +37,7 @@ import numpy as np
 
 from repro.core.kv_cache import PagedAllocator, PrefixCache
 from repro.core.metrics import Request, now
-from repro.core.observability import Tracer
+from repro.core.observability import Tracer, span
 from repro.core.scheduler import ContinuousBatchScheduler, SlotState
 from repro.core.spec import PromptLookupDraft, verify_draft
 from repro.core.timeline import StepRecord
@@ -75,9 +77,6 @@ class EngineConfig:
     profile_steps: bool = True        # keep one StepRecord per iteration in a
                                       # bounded ring (cheap: a dataclass + a
                                       # dozen counter reads per device call)
-    profile_fence: bool = False       # block_until_ready before timestamping
-                                      # each step (true device wall time; off
-                                      # by default — it serializes dispatch)
     step_records_cap: int = 4096      # ring-buffer capacity for step records
     cache_dtype: Any = jnp.float32
     seed: int = 0
@@ -96,16 +95,20 @@ class TokenEvent:
 
 
 # Module-level jit cache: replicas sharing a model reuse compiled programs
-# (a fleet of N replicas compiles once, not N times). jax.jit retraces per
-# call shape, so the two fixed shapes (chunk pack / decode sweep) coexist in
-# one entry.
+# (a fleet of N replicas compiles once, not N times). Each entry is one
+# named program (``name``) of one call shape.
 _JIT_CACHE: Dict[Tuple, Callable] = {}
 
 
-def _cached_jit(kind: str, model, ctx, sampling, builder):
-    key = (kind, id(model), ctx, sampling)
+def _cached_jit(name: str, model, ctx, sampling, fn, donate: int):
+    """``jax.jit(fn)`` under ``name``: the program, and the module the
+    device trace shows for it, are called ``jit_<name>``."""
+    key = (name, id(model), ctx, sampling)
     if key not in _JIT_CACHE:
-        _JIT_CACHE[key] = builder()
+        def program(*args):
+            return fn(*args)
+        program.__name__ = program.__qualname__ = name
+        _JIT_CACHE[key] = jax.jit(program, donate_argnums=(donate,))
     return _JIT_CACHE[key]
 
 
@@ -132,6 +135,7 @@ class InferenceEngine:
         self.params = params
         self.cfg = cfg
         self.tracer = tracer
+        self.replica_id = ""              # set by the Replica serving it
         # the backend follows the platform (RunCtx resolves "auto"): the
         # compiled Pallas kernels on a TPU, the XLA references elsewhere
         self.ctx = ctx or RunCtx(moe_strategy="dropless", block_q=128, block_kv=128)
@@ -175,12 +179,12 @@ class InferenceEngine:
         self.page_table = np.zeros((cfg.max_slots, cfg.max_pages_per_seq), np.int32)
         self.extras: Dict[str, Any] = {}  # frames/patches per request (encdec/vlm)
         sampling = (cfg.temperature, cfg.top_p, cfg.greedy, cfg.page_size)
-        self._step_jit = _cached_jit(
-            "step", model, self.ctx, sampling,
-            lambda: jax.jit(self._step_fn, donate_argnums=(1,)))
-        self._cow_jit = _cached_jit(
-            "cow", model, self.ctx, sampling,
-            lambda: jax.jit(self._copy_pages_fn, donate_argnums=(0,)))
+        self._prefill_jit = _cached_jit("prefill_step", model, self.ctx, sampling,
+                                        self._step_fn, donate=1)
+        self._decode_jit = _cached_jit("decode_step", model, self.ctx, sampling,
+                                       self._step_fn, donate=1)
+        self._cow_jit = _cached_jit("cow_copy", model, self.ctx, sampling,
+                                    self._copy_pages_fn, donate=0)
         # spec-sweep width ladder: one compiled variant per chunk width
         # C = 1 + k for k in {1, 2, 4, ..., kmax}. The sweep picks the
         # smallest width covering the iteration's longest draft, so compute
@@ -195,11 +199,10 @@ class InferenceEngine:
             self._spec_widths.append(1 + self.spec_kmax)
         self._sampling = sampling
         # resilience hooks (DESIGN.md §5): a FaultInjector evaluated at the
-        # top of every step (None costs one attribute check), the replica id
-        # it matches fault events against, and the brown-out flag that
+        # top of every step (None costs one attribute check; it matches
+        # fault events against ``replica_id``), and the brown-out flag that
         # disables speculative drafting while degraded.
         self.injector = None
-        self.fault_key: Optional[str] = None
         self.degraded = False
         self.steps = 0
         self.decode_tokens = 0
@@ -211,23 +214,23 @@ class InferenceEngine:
         self.prefix_cached_tokens = 0     # prefill tokens skipped via cache hits
         self.iter_token_counts: deque = deque(maxlen=4096)
         # iteration profiler: one StepRecord per step() in a bounded ring
-        # (DESIGN.md §4); per-step row counts set by _step as it packs
+        # (DESIGN.md §4); what the step ran, set by _step as it packs
         self.step_records: deque = deque(maxlen=cfg.step_records_cap)
-        self._last_admitted = 0
-        self._last_prefill_rows = 0
-        self._last_decode_rows = 0
+        self._ran: Dict[str, Any] = {}
 
     # ------------------------------------------------------------- jitted fn
     def _step_fn(self, params, cache, tokens, starts, nvalid, slots, first,
                  page_table, key, frames=None, patches=None):
         """One fused iteration over a packed batch of per-sequence chunks
-        (decode == chunk of 1). Returns (next_token (B,), cache)."""
+        (decode == chunk of 1). Returns (next_token (B,), experts reached
+        per MoE layer, cache)."""
+        counters: Dict[str, Any] = {}
         logits, cache = self.model.decode_chunk(
             params, tokens, cache, starts, nvalid, slots, first, self.ctx,
-            page_table, frames=frames, patches=patches)
+            page_table, frames=frames, patches=patches, counters=counters)
         nxt = sample_tokens(logits, key, self.cfg.temperature, self.cfg.top_p,
                             self.cfg.greedy)
-        return jnp.where(nvalid > 0, nxt, 0), cache
+        return jnp.where(nvalid > 0, nxt, 0), counters["experts"], cache
 
     def _spec_fn(self, params, cache, tokens, starts, nvalid, slots, first,
                  page_table, key):
@@ -235,37 +238,38 @@ class InferenceEngine:
         [last_token, d_1 .. d_k] in one chunk, the head scores all fed
         positions, and verify_draft turns the logits into (accepted-prefix
         length, next committed token) per row."""
+        counters: Dict[str, Any] = {}
         logits, cache = self.model.decode_chunk(
             params, tokens, cache, starts, nvalid, slots, first, self.ctx,
-            page_table, all_logits=True)
+            page_table, all_logits=True, counters=counters)
         n_acc, out = verify_draft(logits, tokens, nvalid, key,
                                   self.cfg.temperature, self.cfg.top_p,
                                   self.cfg.greedy)
-        return n_acc, jnp.where(nvalid > 0, out, 0), cache
+        return n_acc, jnp.where(nvalid > 0, out, 0), counters["experts"], cache
 
     def _spec_jit_for(self, width: int):
-        """Compiled spec sweep for chunk width C = ``width`` (lazy, cached
-        process-wide like the step fn — one entry per ladder width)."""
-        return _cached_jit(
-            f"spec{width}", self.model, self.ctx, self._sampling,
-            lambda: jax.jit(self._spec_fn, donate_argnums=(1,)))
+        """Compiled spec sweep ``spec_step_<width>`` for chunk width C =
+        ``width`` (lazy, cached process-wide like the step programs)."""
+        return _cached_jit(f"spec_step_{width}", self.model, self.ctx,
+                           self._sampling, self._spec_fn, donate=1)
 
     def lower_steps(self) -> Dict[str, Any]:
         """The two fixed-shape step programs, lowered for this engine's
         device with the operands ``step()`` passes them: ``"prefill"``
-        (chunk_rows, chunk) and ``"decode"`` (max_slots, 1). Compiling one
-        compiles what that shape's first step would; the step then reuses
-        it."""
+        (``prefill_step``, chunk_rows × chunk) and ``"decode"``
+        (``decode_step``, max_slots × 1). Compiling one compiles what that
+        shape's first step would; the step then reuses it."""
         cfg = self.cfg
         lowered = {}
-        for name, (B, C) in (("prefill", (self.chunk_rows, self.chunk)),
-                             ("decode", (cfg.max_slots, 1))):
+        for name, program, (B, C) in (
+                ("prefill", self._prefill_jit, (self.chunk_rows, self.chunk)),
+                ("decode", self._decode_jit, (cfg.max_slots, 1))):
             ops = self._put((np.zeros((B, C), np.int32), np.zeros((B,), np.int32),
                              np.zeros((B,), np.int32), np.arange(B, dtype=np.int32),
                              np.zeros((B,), bool),
                              np.zeros((B, cfg.max_pages_per_seq), np.int32)))
-            lowered[name] = self._step_jit.lower(self.params, self.cache, *ops,
-                                                 self._key, None, None)
+            lowered[name] = program.lower(self.params, self.cache, *ops,
+                                          self._key, None, None)
         return lowered
 
     def _copy_pages_fn(self, cache, src, dst):
@@ -286,20 +290,21 @@ class InferenceEngine:
         Copies are applied in order; a batch holds at most one copy per
         destination page so the gather-then-scatter semantics of a single
         call can never race two writes to one page."""
-        while copies:
-            batch, rest, seen = [], [], set()
-            for s, d in copies:
-                (rest if d in seen else batch).append((s, d))
-                seen.add(d)
-            for i in range(0, len(batch), COW_BUF):
-                sub = batch[i:i + COW_BUF]
-                src = np.zeros(COW_BUF, np.int32)
-                dst = np.zeros(COW_BUF, np.int32)
-                for j, (s, d) in enumerate(sub):
-                    src[j], dst[j] = s, d
-                self.cache = self._cow_jit(self.cache, self._put(src),
-                                           self._put(dst))
-            copies = rest
+        with span("engine.cow", pages=len(copies)):
+            while copies:
+                batch, rest, seen = [], [], set()
+                for s, d in copies:
+                    (rest if d in seen else batch).append((s, d))
+                    seen.add(d)
+                for i in range(0, len(batch), COW_BUF):
+                    sub = batch[i:i + COW_BUF]
+                    src = np.zeros(COW_BUF, np.int32)
+                    dst = np.zeros(COW_BUF, np.int32)
+                    for j, (s, d) in enumerate(sub):
+                        src[j], dst[j] = s, d
+                    self.cache = self._cow_jit(self.cache, self._put(src),
+                                               self._put(dst))
+                copies = rest
 
     def _register_prefix(self, st: SlotState) -> None:
         """Insert the slot's newly completed full prompt pages into the
@@ -359,7 +364,15 @@ class InferenceEngine:
         """One token-budget iteration: admissions, the prefill chunk pack,
         then one decode sweep — at most ``token_budget`` tokens total.
         With ``profile_steps`` each iteration leaves one :class:`StepRecord`
-        in the ``step_records`` ring buffer."""
+        in the ``step_records`` ring buffer. The whole iteration is the
+        profiler span ``engine.step`` (DESIGN.md §4)."""
+        with span("engine.step", replica=self.replica_id, step=self.steps + 1) as sp:
+            events = self._recorded_step()
+            sp.set_metadata(prefill_rows=self._ran["prefill_rows"],
+                            decode_rows=self._ran["decode_rows"])
+        return events
+
+    def _recorded_step(self) -> List[TokenEvent]:
         if self.injector is not None:
             self.injector.on_engine_step(self)
         if not self.cfg.profile_steps:
@@ -370,16 +383,11 @@ class InferenceEngine:
         prefill0, decode0 = self.prefill_tokens, self.decode_tokens
         drafted0, accepted0 = self.drafted_tokens, self.accepted_tokens
         events = self._step()
-        if self.cfg.profile_fence:
-            jax.block_until_ready(self.cache)
         alloc = self.allocator
         self.step_records.append(StepRecord(
             step=self.steps, t0=t0, t1=now(), budget=self.token_budget,
             tokens_packed=self.iter_token_counts[-1] if self.iter_token_counts else 0,
-            n_admitted=self._last_admitted,
-            prefill_rows=self._last_prefill_rows,
             prefill_tokens=self.prefill_tokens - prefill0,
-            decode_rows=self._last_decode_rows,
             decode_tokens=self.decode_tokens - decode0,
             drafted_tokens=self.drafted_tokens - drafted0,
             accepted_tokens=self.accepted_tokens - accepted0,
@@ -389,7 +397,7 @@ class InferenceEngine:
             kv_free_pages=alloc.free_pages,
             kv_total_pages=alloc.num_pages - 1,   # page 0 is the null page
             preemptions=self.scheduler.n_preemptions - preempt0,
-            cow_pages=alloc.cow_copies - cow0))
+            cow_pages=alloc.cow_copies - cow0, **self._ran))
         return events
 
     def _step(self) -> List[TokenEvent]:
@@ -400,7 +408,10 @@ class InferenceEngine:
             time.sleep(cfg.host_overhead_s)
         self.steps += 1
         iter_tokens = 0
-        self._last_admitted = self._last_prefill_rows = self._last_decode_rows = 0
+        # what this iteration's device calls ran, for its StepRecord
+        self._ran = dict(n_admitted=0, prefill_rows=0, prefill_work=(),
+                         prefill_experts=(), decode_rows=0, decode_ctx=(),
+                         decode_experts=())
 
         # deadline sweep (DESIGN.md §5): cancel requests past their absolute
         # cutoff before planning, so an expired request provably frees its
@@ -419,9 +430,10 @@ class InferenceEngine:
                 tr.event(req.req_id, "deadline_exceeded", slot=slot)
             events.append(TokenEvent(req, -1, t_exp, True))
 
-        plan = self.scheduler.plan_iteration(self.token_budget, self.chunk,
-                                             self.chunk_rows)
-        self._last_admitted = len(plan.admit)
+        with span("engine.plan"):
+            plan = self.scheduler.plan_iteration(self.token_budget, self.chunk,
+                                                 self.chunk_rows)
+        self._ran["n_admitted"] = len(plan.admit)
         for st in plan.admit:
             r = st.request
             if r.t2 == 0.0:
@@ -470,40 +482,46 @@ class InferenceEngine:
             self._apply_copies(copies)                     # before the chunk writes
         if grants:
             t_pack0 = now()
-            self._last_prefill_rows = len(grants)
-            B, C = self.chunk_rows, self.chunk
-            tokens = np.zeros((B, C), np.int32)
-            starts = np.zeros((B,), np.int32)
-            nvalid = np.zeros((B,), np.int32)
-            slots = np.zeros((B,), np.int32)
-            first = np.zeros((B,), bool)
-            pt = np.zeros((B, cfg.max_pages_per_seq), np.int32)
-            used = set()
-            for i, (st, n) in enumerate(grants):
-                tokens[i, :n] = st.all_tokens[st.fed:st.fed + n]
-                starts[i] = st.fed
-                nvalid[i] = n
-                slots[i] = st.slot
-                first[i] = st.fed == 0
-                row = self.allocator.page_table_row(st.slot)
-                self.page_table[st.slot] = row
-                pt[i] = row
-                used.add(st.slot)
-            # padding rows need distinct (unused) slots: their masked cache
-            # writes must never collide with a live row's slot
-            spare = [s for s in range(cfg.max_slots) if s not in used]
-            for i in range(len(grants), B):
-                slots[i] = spare.pop()
-            # encoder frames / vision patches only matter on a row's FIRST
-            # chunk (cross-KV is persisted per slot; the patch prefix KV is
-            # paged) — packs without first chunks skip the prefix compute.
-            frames, patches = (self._row_extras(grants, B) if first.any()
-                               else (None, None))
-            nxt, self.cache = self._step_jit(
-                self.params, self.cache,
-                *self._put((tokens, starts, nvalid, slots, first, pt)),
-                self._next_key(), frames, patches)
-            nxt = np.asarray(nxt)
+            self._ran.update(prefill_rows=len(grants),
+                             prefill_work=tuple((st.fed, n) for st, n in grants))
+            with span("engine.prefill.pack", rows=len(grants)):
+                B, C = self.chunk_rows, self.chunk
+                tokens = np.zeros((B, C), np.int32)
+                starts = np.zeros((B,), np.int32)
+                nvalid = np.zeros((B,), np.int32)
+                slots = np.zeros((B,), np.int32)
+                first = np.zeros((B,), bool)
+                pt = np.zeros((B, cfg.max_pages_per_seq), np.int32)
+                used = set()
+                for i, (st, n) in enumerate(grants):
+                    tokens[i, :n] = st.all_tokens[st.fed:st.fed + n]
+                    starts[i] = st.fed
+                    nvalid[i] = n
+                    slots[i] = st.slot
+                    first[i] = st.fed == 0
+                    row = self.allocator.page_table_row(st.slot)
+                    self.page_table[st.slot] = row
+                    pt[i] = row
+                    used.add(st.slot)
+                # padding rows need distinct (unused) slots: their masked
+                # cache writes must never collide with a live row's slot
+                spare = [s for s in range(cfg.max_slots) if s not in used]
+                for i in range(len(grants), B):
+                    slots[i] = spare.pop()
+                # encoder frames / vision patches only matter on a row's
+                # FIRST chunk (cross-KV is persisted per slot; the patch
+                # prefix KV is paged) — packs without first chunks skip the
+                # prefix compute.
+                frames, patches = (self._row_extras(grants, B) if first.any()
+                                   else (None, None))
+                ops = self._put((tokens, starts, nvalid, slots, first, pt))
+                key = self._next_key()
+            with span("engine.prefill.run"):
+                nxt, experts, self.cache = self._prefill_jit(
+                    self.params, self.cache, *ops, key, frames, patches)
+            with span("engine.prefill.fetch"):
+                nxt, experts = jax.device_get((nxt, experts))
+            self._ran["prefill_experts"] = tuple(experts.tolist())
             t_emit = now()
             for i, (st, n) in enumerate(grants):
                 st.fed += n
@@ -588,25 +606,30 @@ class InferenceEngine:
         for s in range(M):
             if s not in self.scheduler.running:
                 self.page_table[s] = 0
-        self._last_decode_rows = len(decode_sts)
+        self._ran.update(decode_rows=len(decode_sts),
+                         decode_ctx=tuple(st.fed for st in decode_sts))
         if drafts:
             iter_tokens = self._spec_sweep(decode_sts, drafts, events, iter_tokens)
             self.iter_token_counts.append(iter_tokens)
             return events
         t_dec0 = now()
-        tokens = np.zeros((M, 1), np.int32)
-        starts = np.zeros((M,), np.int32)
-        nvalid = np.zeros((M,), np.int32)
-        for st in decode_sts:
-            tokens[st.slot, 0] = st.last_token
-            starts[st.slot] = st.fed
-            nvalid[st.slot] = 1
-        nxt, self.cache = self._step_jit(
-            self.params, self.cache,
-            *self._put((tokens, starts, nvalid, np.arange(M, dtype=np.int32),
-                        np.zeros((M,), bool), self.page_table)),
-            self._next_key(), None, None)
-        nxt = np.asarray(nxt)
+        with span("engine.decode.pack", rows=len(decode_sts)):
+            tokens = np.zeros((M, 1), np.int32)
+            starts = np.zeros((M,), np.int32)
+            nvalid = np.zeros((M,), np.int32)
+            for st in decode_sts:
+                tokens[st.slot, 0] = st.last_token
+                starts[st.slot] = st.fed
+                nvalid[st.slot] = 1
+            ops = self._put((tokens, starts, nvalid, np.arange(M, dtype=np.int32),
+                             np.zeros((M,), bool), self.page_table))
+            key = self._next_key()
+        with span("engine.decode.run"):
+            nxt, experts, self.cache = self._decode_jit(
+                self.params, self.cache, *ops, key, None, None)
+        with span("engine.decode.fetch"):
+            nxt, experts = jax.device_get((nxt, experts))
+        self._ran["decode_experts"] = tuple(experts.tolist())
         t_emit = now()
         self.decode_tokens += len(decode_sts)
         iter_tokens += len(decode_sts)
@@ -643,22 +666,26 @@ class InferenceEngine:
         M = cfg.max_slots
         kcap = max(len(d) for d in drafts.values())
         C = next(w for w in self._spec_widths if w >= 1 + kcap)
-        tokens = np.zeros((M, C), np.int32)
-        starts = np.zeros((M,), np.int32)
-        nvalid = np.zeros((M,), np.int32)
-        for st in decode_sts:
-            d = drafts.get(st.slot, [])
-            tokens[st.slot, 0] = st.last_token
-            if d:
-                tokens[st.slot, 1:1 + len(d)] = d
-            starts[st.slot] = st.fed
-            nvalid[st.slot] = 1 + len(d)
-        n_acc, out, self.cache = self._spec_jit_for(C)(
-            self.params, self.cache,
-            *self._put((tokens, starts, nvalid, np.arange(M, dtype=np.int32),
-                        np.zeros((M,), bool), self.page_table)),
-            self._next_key())
-        n_acc, out = np.asarray(n_acc), np.asarray(out)
+        with span("engine.decode.pack", rows=len(decode_sts), width=C):
+            tokens = np.zeros((M, C), np.int32)
+            starts = np.zeros((M,), np.int32)
+            nvalid = np.zeros((M,), np.int32)
+            for st in decode_sts:
+                d = drafts.get(st.slot, [])
+                tokens[st.slot, 0] = st.last_token
+                if d:
+                    tokens[st.slot, 1:1 + len(d)] = d
+                starts[st.slot] = st.fed
+                nvalid[st.slot] = 1 + len(d)
+            ops = self._put((tokens, starts, nvalid, np.arange(M, dtype=np.int32),
+                             np.zeros((M,), bool), self.page_table))
+            key = self._next_key()
+        with span("engine.decode.run"):
+            n_acc, out, experts, self.cache = self._spec_jit_for(C)(
+                self.params, self.cache, *ops, key)
+        with span("engine.decode.fetch"):
+            n_acc, out, experts = jax.device_get((n_acc, out, experts))
+        self._ran["decode_experts"] = tuple(experts.tolist())
         t_emit = now()
         self.spec_steps += 1
         for st in decode_sts:

@@ -177,7 +177,7 @@ class FaultInjector:
         """Called by ``InferenceEngine.step`` before each iteration: applies
         slow-step latency (factor x previous measured step duration +
         additive delay) and adjusts the artificial KV hold."""
-        key = getattr(engine, "fault_key", None)
+        key = getattr(engine, "replica_id", None)
         t = self.elapsed()
         ev = self._match("slow", key, t)
         if ev is not None:

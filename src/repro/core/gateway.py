@@ -31,7 +31,7 @@ import numpy as np
 
 from repro.core.engine import TokenEvent
 from repro.core.metrics import Request, now
-from repro.core.observability import MetricsSink, Tracer
+from repro.core.observability import MetricsSink, Tracer, span
 from repro.core.router import NoReplicaAvailable, ReplicaRouter
 from repro.core.safety import AuthError, Authenticator, ContentBlocked, ContentFilter, RateLimited, TokenBucket
 from repro.core.serde import CODECS
@@ -151,78 +151,78 @@ class Gateway:
         sem = self._semaphore()
         if sem is not None:
             await sem.acquire()
-        try:
-            req_id, tokens, params = self.codec.decode_request(raw)
-            if self.require_auth:
-                user = self.auth.verify(auth_token)
-            else:
-                user = "anon"
-            if self.rate_limiter is not None:
-                self.rate_limiter.check(user)
-            if self.content_filter is not None:
-                self.content_filter.check(tokens)
-        except (AuthError, RateLimited, ContentBlocked) as e:
-            self.sink.incr(f"rejected.{type(e).__name__}")
-            client_q.put_nowait(b"")
-            if sem is not None:
-                sem.release()
-            return
-        finally:
-            pass
-
-        # ---- load shedding: bounded admission. Overflow gets an immediate
-        # terminal "shed" event — an explicit no, never a silent hang.
-        self._update_brownout(t1)
-        if self.cfg.max_inflight > 0:
-            with self._degrade_lock:
-                over = self._inflight >= self.cfg.max_inflight
-            if over:
-                request = Request(req_id=req_id,
-                                  prompt_tokens=np.asarray(tokens, np.int32))
-                request.t1 = t1
-                request.error = "shed"
-                request.finished = True
-                request.t3 = now()
-                self.requests[req_id] = request
-                self.sink.incr("shed")
-                if self.tracer:
-                    self.tracer.event(req_id, "shed")
-                    self.tracer.discard(req_id)
-                client_q.put_nowait(self.codec.encode_token(req_id, -1, 0, True))
+        # the admission itself, up to the connection: no await inside
+        with span("gateway.handle"):
+            try:
+                req_id, tokens, params = self.codec.decode_request(raw)
+                if self.require_auth:
+                    user = self.auth.verify(auth_token)
+                else:
+                    user = "anon"
+                if self.rate_limiter is not None:
+                    self.rate_limiter.check(user)
+                if self.content_filter is not None:
+                    self.content_filter.check(tokens)
+            except (AuthError, RateLimited, ContentBlocked) as e:
+                self.sink.incr(f"rejected.{type(e).__name__}")
+                client_q.put_nowait(b"")
                 if sem is not None:
                     sem.release()
                 return
 
-        max_new = int(params.get("max_new_tokens", 64))
-        if self.brownout:
-            # brown-out clamp: shorter generations drain the backlog faster
-            max_new = min(max_new, self.cfg.brownout_max_new_tokens)
-            self.sink.incr("brownout_clamped")
-        request = Request(
-            req_id=req_id,
-            prompt_tokens=np.asarray(tokens, np.int32),
-            max_new_tokens=max_new,
-            temperature=float(params.get("temperature", 0.5)),
-            top_p=float(params.get("top_p", 0.7)),
-            greedy=bool(params.get("greedy", False)),
-            user_id=user,
-        )
-        request.t1 = t1
-        deadline_s = params.get("deadline_s", self.cfg.default_deadline_s)
-        if deadline_s is not None:
-            # absolute cutoff on the shared monotonic clock; enforced by the
-            # engine's per-step deadline sweep
-            request.deadline_s = float(deadline_s)
-            request.deadline_at = t1 + float(deadline_s)
-        self.requests[req_id] = request
-        with self._degrade_lock:
-            self._inflight += 1
-            if self._inflight > self.inflight_max:
-                self.inflight_max = self._inflight
-        if self.tracer:
-            # decode + auth/rate-limit/content checks (the sync-worker path)
-            self.tracer.add(req_id, "gateway_admission", t1, now(),
-                            codec=self.cfg.codec, n_prompt=len(tokens))
+            # ---- load shedding: bounded admission. Overflow gets an immediate
+            # terminal "shed" event — an explicit no, never a silent hang.
+            self._update_brownout(t1)
+            if self.cfg.max_inflight > 0:
+                with self._degrade_lock:
+                    over = self._inflight >= self.cfg.max_inflight
+                if over:
+                    request = Request(req_id=req_id,
+                                      prompt_tokens=np.asarray(tokens, np.int32))
+                    request.t1 = t1
+                    request.error = "shed"
+                    request.finished = True
+                    request.t3 = now()
+                    self.requests[req_id] = request
+                    self.sink.incr("shed")
+                    if self.tracer:
+                        self.tracer.event(req_id, "shed")
+                        self.tracer.discard(req_id)
+                    client_q.put_nowait(self.codec.encode_token(req_id, -1, 0, True))
+                    if sem is not None:
+                        sem.release()
+                    return
+
+            max_new = int(params.get("max_new_tokens", 64))
+            if self.brownout:
+                # brown-out clamp: shorter generations drain the backlog faster
+                max_new = min(max_new, self.cfg.brownout_max_new_tokens)
+                self.sink.incr("brownout_clamped")
+            request = Request(
+                req_id=req_id,
+                prompt_tokens=np.asarray(tokens, np.int32),
+                max_new_tokens=max_new,
+                temperature=float(params.get("temperature", 0.5)),
+                top_p=float(params.get("top_p", 0.7)),
+                greedy=bool(params.get("greedy", False)),
+                user_id=user,
+            )
+            request.t1 = t1
+            deadline_s = params.get("deadline_s", self.cfg.default_deadline_s)
+            if deadline_s is not None:
+                # absolute cutoff on the shared monotonic clock; enforced by the
+                # engine's per-step deadline sweep
+                request.deadline_s = float(deadline_s)
+                request.deadline_at = t1 + float(deadline_s)
+            self.requests[req_id] = request
+            with self._degrade_lock:
+                self._inflight += 1
+                if self._inflight > self.inflight_max:
+                    self.inflight_max = self._inflight
+            if self.tracer:
+                # decode + auth/rate-limit/content checks (the sync-worker path)
+                self.tracer.add(req_id, "gateway_admission", t1, now(),
+                                codec=self.cfg.codec, n_prompt=len(tokens))
 
         loop = asyncio.get_running_loop()
         codec = self.codec

@@ -20,6 +20,14 @@ Three layers:
   more at interpreter exit (``atexit``) or on ``close()``, so a benchmark
   that crashes mid-run still leaves its records on disk.
 
+``span``
+  Named spans of the serving threads (engine step and its phases, replica
+  drain and delivery, gateway admission, garbage collections) written into
+  the profiler's trace through ``jax.profiler.TraceAnnotation``, so they
+  lie on the same timeline as the device's programs. With no profiler
+  running a span costs one ``TraceAnnotation.is_enabled()`` check.
+  ``Tracer.clock_offset_ns`` maps the tracer's clock onto the profiler's.
+
 Timeline aggregation (windowed percentiles, SLO attainment) lives in
 ``repro.core.timeline``; the per-iteration engine profile is
 ``InferenceEngine.step_records``.
@@ -27,8 +35,10 @@ Timeline aggregation (windowed percentiles, SLO attainment) lives in
 from __future__ import annotations
 
 import atexit
+import gc
 import os
 import threading
+import time
 import weakref
 from collections import defaultdict, deque
 from dataclasses import asdict, dataclass, field
@@ -40,6 +50,8 @@ except ImportError:                     # stdlib fallback keeps the module impor
     _orjson = None
     import json as _json
 
+from jax.profiler import TraceAnnotation
+
 from repro.core.metrics import Request, now, request_metrics
 
 
@@ -47,6 +59,53 @@ def _dumps(obj: Any) -> bytes:
     if _orjson is not None:
         return _orjson.dumps(obj)
     return _json.dumps(obj, default=str, separators=(",", ":")).encode()
+
+
+# ------------------------------------------------------- profiler spans
+class _NoSpan:
+    """What ``span`` returns while no profiler runs: enters, exits, and
+    takes metadata, doing nothing."""
+
+    def __enter__(self) -> "_NoSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+    def set_metadata(self, **attrs: Any) -> None:
+        return None
+
+
+_NO_SPAN = _NoSpan()
+
+
+def span(name: str, **attrs: Any):
+    """A context manager that writes ``name`` (with ``attrs``) as a host
+    event into the profiler's trace while one runs; metadata known only at
+    the end goes in through ``set_metadata``. DESIGN.md §4 lists the names."""
+    if not TraceAnnotation.is_enabled():
+        return _NO_SPAN
+    return TraceAnnotation(name, **attrs)
+
+
+_gc_spans = threading.local()
+
+
+def _gc_callback(phase: str, info: Dict[str, Any]) -> None:
+    if phase == "start":
+        _gc_spans.open = span("gc", generation=info["generation"]).__enter__()
+    else:
+        opened = getattr(_gc_spans, "open", None)
+        if opened is not None:
+            _gc_spans.open = None
+            opened.__exit__(None, None, None)
+
+
+def install_gc_spans() -> None:
+    """Write every garbage collection as a ``gc`` span (attr ``generation``)
+    on the thread it stalls. Once per process; later calls do nothing."""
+    if _gc_callback not in gc.callbacks:
+        gc.callbacks.append(_gc_callback)
 
 
 # ----------------------------------------------------------------- tracing
@@ -78,6 +137,11 @@ class Tracer:
 
     A disabled tracer is falsy — instrumentation guards with
     ``if tracer: ...`` and pays nothing else.
+
+    ``clock_offset_ns``, read once, takes span times onto the clock of the
+    profiler's host events: ``t * 1e9 + clock_offset_ns`` is wall-clock
+    nanoseconds, and a trace's host events lie that far after its
+    ``Task Environment`` plane's ``profile_start_time``.
     """
 
     def __init__(self, enabled: bool = True, max_spans: int = 512,
@@ -91,6 +155,7 @@ class Tracer:
         self._lock = threading.Lock()
         self.dropped_spans = 0
         self.evicted_requests = 0
+        self.clock_offset_ns = time.time_ns() - round(now() * 1e9)
 
     def __bool__(self) -> bool:
         return self.enabled
@@ -255,14 +320,17 @@ class MetricsSink:
         with self._lock:
             self._records.append(rec)
 
-    def record_trace(self, r: Request, spans: List[Span]) -> None:
+    def record_trace(self, r: Request, spans: List[Span],
+                     clock_offset_ns: Optional[int] = None) -> None:
         """Export a finished request's span list (DESIGN.md §4) alongside
-        the Figure-4 timestamps it must reconcile with."""
+        the Figure-4 timestamps it must reconcile with, and the tracer's
+        ``clock_offset_ns`` that lays them over a profiler trace."""
         rec = _dumps({
             "kind": "trace", "req_id": r.req_id, "replica_id": r.replica_id,
             "t0": r.t0, "t1": r.t1, "t2": r.t2, "t3": r.t3, "t4": r.t4,
             "t5": r.t5, "t6": r.t6, "n_generated": r.n_generated,
             "preemptions": r.preemptions, "spans": spans_to_dicts(spans),
+            "clock_offset_ns": clock_offset_ns,
         })
         with self._lock:
             self._records.append(rec)

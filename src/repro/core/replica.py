@@ -20,7 +20,8 @@ from typing import Callable, Dict, List, Optional, Tuple
 import jax
 
 from repro.core.engine import InferenceEngine, TokenEvent
-from repro.core.metrics import Request
+from repro.core.metrics import Request, now
+from repro.core.observability import install_gc_spans, span
 
 OnEvent = Callable[[TokenEvent], None]
 
@@ -44,12 +45,13 @@ class Replica:
         self.healthy = True
         self.crashed = False                   # injected crash fired (thread exited)
         self.step_watchdog_s = step_watchdog_s
+        # the engine's spans and fault events carry this replica's id
+        engine.replica_id = replica_id
         # fault injection (DESIGN.md §5): evaluated once per loop iteration;
-        # also wired into the engine's per-step hook under this replica's id.
+        # also wired into the engine's per-step hook.
         self.injector = injector
         if injector is not None and hasattr(engine, "injector"):
             engine.injector = injector
-            engine.fault_key = replica_id
         self.last_step_at = time.monotonic()
         self._inbox: "queue.Queue[Tuple[Request, OnEvent]]" = queue.Queue()
         self._inflight: Dict[str, Tuple[Request, OnEvent]] = {}
@@ -65,6 +67,7 @@ class Replica:
 
     # ------------------------------------------------------------- lifecycle
     def start(self) -> "Replica":
+        install_gc_spans()
         self._thread = threading.Thread(target=self._loop, name=f"replica-{self.replica_id}",
                                         daemon=True)
         self._thread.start()
@@ -121,8 +124,9 @@ class Replica:
     def step_records(self) -> list:
         """Snapshot of the engine's iteration-profile ring buffer
         (``StepRecord`` rows, oldest first). Safe to call from any thread:
-        deque snapshots are atomic under the GIL and records are immutable
-        once appended."""
+        deque snapshots are atomic under the GIL, and a record changes once
+        after it is appended, when the serving thread sets its
+        ``deliver_s``."""
         return list(self.engine.step_records)
 
     @property
@@ -166,44 +170,56 @@ class Replica:
                     time.sleep(min(max(remaining, 0.0), 0.02))
                     continue
             moved = False
-            while True:
-                try:
-                    req, cb = self._inbox.get_nowait()
-                except queue.Empty:
-                    break
-                with self._lock:
-                    self._inflight[req.req_id] = (req, cb)
-                self.engine.submit(req)
-                moved = True
-            while True:
-                try:
-                    rid = self._cancel.get_nowait()
-                except queue.Empty:
-                    break
-                self.engine.cancel(rid)
-                with self._lock:
-                    if self._inflight.pop(rid, None) is not None:
-                        self._outstanding -= 1
-                moved = True
+            with span("replica.drain"):
+                while True:
+                    try:
+                        req, cb = self._inbox.get_nowait()
+                    except queue.Empty:
+                        break
+                    with self._lock:
+                        self._inflight[req.req_id] = (req, cb)
+                    self.engine.submit(req)
+                    moved = True
+                while True:
+                    try:
+                        rid = self._cancel.get_nowait()
+                    except queue.Empty:
+                        break
+                    self.engine.cancel(rid)
+                    with self._lock:
+                        if self._inflight.pop(rid, None) is not None:
+                            self._outstanding -= 1
+                    moved = True
 
             if self.engine.has_work():
                 self.last_step_at = time.monotonic()
-                for ev in self.engine.step():
-                    rid = ev.request.req_id
-                    with self._lock:
-                        entry = self._inflight.get(rid)
-                    if entry is None:
-                        continue                        # cancelled mid-step
-                    _, cb = entry
-                    cb(ev)
-                    if ev.finished:
-                        with self._lock:
-                            if self._inflight.pop(rid, None) is not None:
-                                self._outstanding -= 1
-                        self.total_completed += 1
+                records = self.engine.step_records
+                before = records[-1] if records else None
+                events = self.engine.step()
+                t0 = now()
+                with span("replica.deliver", events=len(events)):
+                    self._deliver(events)
+                if records and records[-1] is not before:   # this step's record
+                    records[-1].deliver_s = now() - t0
             elif not moved:
                 self._wake.wait(timeout=0.002)
                 self._wake.clear()
+
+    def _deliver(self, events: List[TokenEvent]) -> None:
+        """Hand each event to its request's callback; drop finished ones."""
+        for ev in events:
+            rid = ev.request.req_id
+            with self._lock:
+                entry = self._inflight.get(rid)
+            if entry is None:
+                continue                                # cancelled mid-step
+            _, cb = entry
+            cb(ev)
+            if ev.finished:
+                with self._lock:
+                    if self._inflight.pop(rid, None) is not None:
+                        self._outstanding -= 1
+                self.total_completed += 1
 
     def watchdog_expired(self) -> bool:
         """Straggler detection: the replica has work (in the engine OR stuck

@@ -153,7 +153,8 @@ class ReplicaRouter:
             if self.tracer:
                 # the request's span list is complete once its terminal
                 # event fires — export through the JSONL sink and drop
-                self.sink.record_trace(ev.request, self.tracer.pop(rid))
+                self.sink.record_trace(ev.request, self.tracer.pop(rid),
+                                       self.tracer.clock_offset_ns)
         on_event(ev)
 
     @staticmethod

@@ -21,7 +21,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import asdict, dataclass, field
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 from repro.core.metrics import Request, request_metrics
 
@@ -31,7 +31,8 @@ class StepRecord:
     """One engine iteration (``InferenceEngine.step``). Token counts are
     tokens *fed* this step (rejected speculative drafts included — they
     consumed compute); ``preemptions`` / ``cow_pages`` are per-step deltas
-    of the engine's cumulative counters."""
+    of the engine's cumulative counters. ``deliver_s`` is set by the
+    replica after the step, once its events are delivered."""
     step: int
     t0: float                      # monotonic wall-clock (metrics.now)
     t1: float
@@ -51,6 +52,14 @@ class StepRecord:
     kv_total_pages: int
     preemptions: int
     cow_pages: int
+    # what the step's device calls ran: (start, n) per prefill row, the
+    # context of each decode row, and per MoE layer the experts that at
+    # least one live token of the call reached
+    prefill_work: Tuple[Tuple[int, int], ...] = ()
+    decode_ctx: Tuple[int, ...] = ()
+    prefill_experts: Tuple[int, ...] = ()
+    decode_experts: Tuple[int, ...] = ()
+    deliver_s: float = 0.0         # the replica handing out its events (replica.deliver)
 
     @property
     def duration(self) -> float:

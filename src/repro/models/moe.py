@@ -52,6 +52,15 @@ def router_topk(xf, router_w, k: int):
     return topw, topi.astype(jnp.int32), aux
 
 
+def experts_reached(topi, E: int, live=None):
+    """Experts that at least one live token was routed to: ``topi`` (T, k)
+    from ``router_topk``; ``live`` (T,) marks the tokens that count (None:
+    every token). An int32 scalar."""
+    w = None if live is None else jnp.repeat(live.astype(jnp.int32), topi.shape[1])
+    return jnp.count_nonzero(jnp.bincount(topi.reshape(-1), weights=w, length=E)
+                             ).astype(jnp.int32)
+
+
 # --------------------------------------------------------------------------
 # Capacity dispatch/combine (scatter-based; no (T,E,C) one-hot einsum)
 # --------------------------------------------------------------------------
@@ -94,7 +103,7 @@ def _shared_ffn(p_shared, xf, act_name):
 # --------------------------------------------------------------------------
 # Strategy: dropless (sort + ragged gmm) — serving engine path
 # --------------------------------------------------------------------------
-def moe_dropless(p, xf, cfg: ModelConfig, ctx: RunCtx):
+def moe_dropless(p, xf, cfg: ModelConfig, ctx: RunCtx, live=None):
     m = cfg.moe
     E, K = m.num_experts, m.top_k
     T, d = xf.shape
@@ -113,13 +122,13 @@ def moe_dropless(p, xf, cfg: ModelConfig, ctx: RunCtx):
     y = jnp.zeros((T, d), jnp.float32).at[tok[order]].add(ys.astype(jnp.float32) * w_flat[:, None])
     if "shared" in p:
         y = y + _shared_ffn(p["shared"], xf, cfg.act).astype(jnp.float32)
-    return y.astype(xf.dtype), aux
+    return y.astype(xf.dtype), aux, experts_reached(topi, E, live)
 
 
 # --------------------------------------------------------------------------
 # Strategy: capacity (pure local)
 # --------------------------------------------------------------------------
-def moe_capacity(p, xf, cfg: ModelConfig, ctx: RunCtx):
+def moe_capacity(p, xf, cfg: ModelConfig, ctx: RunCtx, live=None):
     m = cfg.moe
     E, K = m.num_experts, m.top_k
     T, d = xf.shape
@@ -130,7 +139,7 @@ def moe_capacity(p, xf, cfg: ModelConfig, ctx: RunCtx):
     y = capacity_combine(ye, info, topw)
     if "shared" in p:
         y = y + _shared_ffn(p["shared"], xf, cfg.act).astype(jnp.float32)
-    return y.astype(xf.dtype), aux
+    return y.astype(xf.dtype), aux, experts_reached(topi, E, live)
 
 
 # --------------------------------------------------------------------------
@@ -233,15 +242,19 @@ def _moe_local_ep(xf, router_w, wg, wu, wd, shared, cfg, ep_axis, tp_axis, cf,
     return y.astype(xf.dtype), aux
 
 
-def moe_sublayer(p: Dict[str, Any], h, cfg: ModelConfig, ctx: RunCtx) -> Tuple[Any, Any]:
-    """h: (B, S, d) normed input. Returns (out (B,S,d), aux loss scalar)."""
+def moe_sublayer(p: Dict[str, Any], h, cfg: ModelConfig, ctx: RunCtx,
+                 live=None) -> Tuple[Any, Any, Any]:
+    """h: (B, S, d) normed input; live (B, S) marks the tokens the experts
+    counter counts (None: all). Returns (out (B,S,d), aux loss scalar,
+    experts reached by live tokens; None on the sharded strategies)."""
     B, S, d = h.shape
     xf = h.reshape(B * S, d)
+    live = None if live is None else live.reshape(B * S)
     strategy = ctx.moe_strategy
     if strategy in ("dropless", "capacity") or ctx.mesh is None:
         fn = moe_dropless if strategy == "dropless" else moe_capacity
-        y, aux = fn(p, xf, cfg, ctx)
-        return y.reshape(B, S, d), aux
+        y, aux, reached = fn(p, xf, cfg, ctx, live)
+        return y.reshape(B, S, d), aux, reached
 
     from jax.experimental.shard_map import shard_map
 
@@ -288,4 +301,4 @@ def moe_sublayer(p: Dict[str, Any], h, cfg: ModelConfig, ctx: RunCtx) -> Tuple[A
     y, aux = shard_map(
         local, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False,
     )(h, p["router"], p["wg"], p["wu"], p["wd"], shared)
-    return y, aux
+    return y, aux, None

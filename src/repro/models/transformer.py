@@ -44,6 +44,7 @@ class LM:
                      positions, memory, page_table, lengths, chunk=None):
         cfg = self.cfg
         aux = jnp.zeros((), jnp.float32)
+        reached = None
         new_c: Dict[str, Any] = {} if c is not None else None
 
         # sequence-parallel placement: constraining each sublayer OUTPUT to
@@ -79,18 +80,23 @@ class LM:
 
         if "moe" in p:
             h2 = rmsnorm(x, p["ln2"], cfg.rms_eps)
-            mo, aux = moe_sublayer(p["moe"], h2, cfg, ctx)
+            mo, aux, reached = moe_sublayer(p["moe"], h2, cfg, ctx,
+                                            live=chunk["valid"] if chunk is not None else None)
             x = x + shard_act(mo, seq_sharded)
         elif "mlp" in p:
             h2 = rmsnorm(x, p["ln2"], cfg.rms_eps)
             x = x + shard_act(dense_mlp(p["mlp"], h2, cfg.act), seq_sharded)
-        return x, new_c, aux
+        return x, new_c, aux, reached
 
     def _run_groups(self, groups_params, x, cache, *, ctx: RunCtx, layer_groups,
                     positions=None, memory=None, page_table=None, lengths=None,
                     kinds_override: Optional[str] = None, chunk=None):
-        """Scan each layer group. Returns (x, new_cache, aux_sum)."""
+        """Scan each layer group. Returns (x, new_cache, aux_sum, experts):
+        ``experts`` (n_moe_layers,) int32 holds, in layer order, the experts
+        each MoE layer's live tokens reached (``moe.experts_reached``; live
+        is the chunk's ``valid`` mask; the sharded strategies count none)."""
         aux_total = jnp.zeros((), jnp.float32)
+        experts: List[Any] = []
         new_groups_cache: List[Any] = []
         for gi, g in enumerate(layer_groups):
             gp = groups_params[gi]["layers"]
@@ -117,9 +123,11 @@ class LM:
 
                         if ctx.remat:
                             run_layer = jax.checkpoint(run_layer)
-                        x, cnew, aux = run_layer(p_sl[pos], x, cpos)
+                        x, cnew, aux, reached = run_layer(p_sl[pos], x, cpos)
                         x = shard_act(x, ("batch", "seq", None))
                         aux_total = aux_total + aux
+                        if reached is not None:
+                            experts.append(reached[None])
                         if new_gc is not None and cnew is not None:
                             new_gc = [
                                 (jax.tree.map(lambda full, new: full.at[r].set(new),
@@ -133,10 +141,10 @@ class LM:
                 xcur = carry
                 p_sl, c_sl = xs
                 auxes = jnp.zeros((), jnp.float32)
-                new_cs = []
+                new_cs, reached_by_pos = [], []
                 for pos, kind in enumerate(pattern):
                     cpos = c_sl[pos] if c_sl is not None else None
-                    xcur, cnew, aux = self._apply_layer(
+                    xcur, cnew, aux, reached = self._apply_layer(
                         p_sl[pos], xcur, cpos, kind=kind, ctx=ctx,
                         positions=positions, memory=memory,
                         page_table=page_table, lengths=lengths, chunk=chunk)
@@ -145,16 +153,21 @@ class LM:
                     xcur = shard_act(xcur, ("batch", "seq", None))
                     new_cs.append(cnew)
                     auxes = auxes + aux
-                return xcur, (new_cs, auxes)
+                    if reached is not None:
+                        reached_by_pos.append(reached)
+                return xcur, (new_cs, auxes, jnp.stack(reached_by_pos)
+                              if reached_by_pos else jnp.zeros((0,), jnp.int32))
 
             if ctx.remat:
                 body = jax.checkpoint(body)
             xs = (gp, gc)
-            x, (stacked_cache, auxes) = jax.lax.scan(body, x, xs)
+            x, (stacked_cache, auxes, reached) = jax.lax.scan(body, x, xs)
             new_groups_cache.append(stacked_cache)
             aux_total = aux_total + jnp.sum(auxes)
+            experts.append(reached.reshape(-1))        # (repeats, moe positions)
         new_cache = {"groups": new_groups_cache} if cache is not None else None
-        return x, new_cache, aux_total
+        experts = jnp.concatenate(experts) if experts else jnp.zeros((0,), jnp.int32)
+        return x, new_cache, aux_total, experts
 
     # ------------------------------------------------------------------ embed
     def _embed(self, params, batch, ctx: RunCtx):
@@ -185,7 +198,7 @@ class LM:
     def _encode(self, params, frames, ctx: RunCtx):
         """Encoder for encdec models. frames: (B, M, d) stub frontend output."""
         enc = params["encoder"]
-        x, _, _ = self._run_groups(
+        x, _, _, _ = self._run_groups(
             enc["groups"], frames, None, ctx=ctx,
             layer_groups=(LayerGroup("E", self.cfg.encoder.n_layers),),
             positions=jnp.arange(frames.shape[1]))
@@ -200,7 +213,7 @@ class LM:
         if cfg.encoder is not None:
             memory = self._encode(params, batch["frames"].astype(x.dtype), ctx)
         positions = jnp.arange(x.shape[1])
-        x, _, aux = self._run_groups(
+        x, _, aux, _ = self._run_groups(
             params["groups"], x, None, ctx=ctx, layer_groups=cfg.layer_groups,
             positions=positions, memory=memory)
         x = rmsnorm(x, params["final_norm"]["w"], cfg.rms_eps)
@@ -235,7 +248,7 @@ class LM:
         if cfg.encoder is not None:
             memory = self._encode(params, batch["frames"].astype(x.dtype), ctx)
         positions = jnp.arange(x.shape[1])
-        x, _, aux = self._run_groups(
+        x, _, aux, _ = self._run_groups(
             params["groups"], x, None, ctx=ctx, layer_groups=cfg.layer_groups,
             positions=positions, memory=memory)
         x = rmsnorm(x, params["final_norm"]["w"], cfg.rms_eps)
@@ -276,7 +289,7 @@ class LM:
         if cfg.encoder is not None:
             memory = self._encode(params, batch["frames"].astype(x.dtype), ctx)
         positions = jnp.arange(x.shape[1])
-        x, new_cache, _ = self._run_groups(
+        x, new_cache, _, _ = self._run_groups(
             params["groups"], x, cache, ctx=ctx, layer_groups=cfg.layer_groups,
             positions=positions, memory=memory)
         x = rmsnorm(x, params["final_norm"]["w"], cfg.rms_eps)
@@ -289,7 +302,7 @@ class LM:
 
     def decode_chunk(self, params, tokens, cache, starts, nvalid, slots, first,
                      ctx: RunCtx, page_table, frames=None, patches=None,
-                     all_logits: bool = False):
+                     all_logits: bool = False, counters: Optional[Dict[str, Any]] = None):
         """Unified serving iteration over a paged cache (DESIGN.md §2): each
         batch row feeds a chunk of up to C tokens of one sequence — C == 1 is
         decode, C > 1 is a prefill chunk. KV goes straight into the paged
@@ -309,7 +322,10 @@ class LM:
         new_cache). With ``all_logits`` the head runs on every token
         position instead — (B, C, vocab), patch-prefix positions dropped —
         which is the verify step of speculative decoding (DESIGN.md §3):
-        position j scores the token fed at index j+1.
+        position j scores the token fed at index j+1. A ``counters`` dict,
+        where given, is filled as the call is traced: ``experts``
+        (n_moe_layers,) int32, the experts each MoE layer's live tokens
+        reached (padding rows and positions past ``nvalid`` left out).
         """
         cfg = self.cfg
         if cfg.vision is not None and any("M" in g.pattern for g in cfg.layer_groups):
@@ -344,10 +360,12 @@ class LM:
             memory = self._encode(params, frames.astype(x.dtype), ctx)
         pack = {"slots": slots, "nvalid": nvalid, "first": first, "valid": valid,
                 "prefix": n_prefix > 0}
-        x, new_cache, _ = self._run_groups(
+        x, new_cache, _, reached = self._run_groups(
             params["groups"], x, cache, ctx=ctx, layer_groups=cfg.layer_groups,
             positions=positions, memory=memory, page_table=page_table,
             lengths=lengths, chunk=pack)
+        if counters is not None:
+            counters["experts"] = reached
         x = rmsnorm(x, params["final_norm"]["w"], cfg.rms_eps)
         if all_logits:
             return self._head(params, x[:, n_prefix:]), new_cache
@@ -367,7 +385,7 @@ class LM:
             x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
         if lengths is None:
             lengths = positions + 1
-        x, new_cache, _ = self._run_groups(
+        x, new_cache, _, _ = self._run_groups(
             params["groups"], x, cache, ctx=ctx, layer_groups=cfg.layer_groups,
             positions=positions, page_table=page_table, lengths=lengths)
         x = rmsnorm(x, params["final_norm"]["w"], cfg.rms_eps)

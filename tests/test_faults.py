@@ -31,7 +31,7 @@ class FakeEngine:
         self.step_sleep = step_sleep
         self.active = {}
         self.injector = None
-        self.fault_key = None
+        self.replica_id = None
         self.degraded = False
         self.step_records = []
 
@@ -112,7 +112,7 @@ def test_kv_pressure_hold_and_release():
     inj = FaultInjector(FaultPlan().kv_pressure("r", 0.0, 1.0, pages=5),
                         clock=lambda: t["v"]).start()
     eng = FakeEngine()
-    eng.fault_key = "r"
+    eng.replica_id = "r"
     eng.allocator = PagedAllocator(num_pages=16, page_size=8,
                                    max_pages_per_seq=8)
     inj.on_engine_step(eng)

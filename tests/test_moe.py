@@ -32,7 +32,7 @@ def test_strategies_agree_on_mesh(moe_setup):
     for strat in ["capacity", "tp_shardmap", "ep_shardmap"]:
         ctx = RunCtx(moe_strategy=strat, mesh=mesh if "shardmap" in strat else None)
         with mesh:
-            y, aux = moe_sublayer(p, h, cfg, ctx)
+            y, aux, _ = moe_sublayer(p, h, cfg, ctx)
         outs[strat] = np.asarray(y)
     np.testing.assert_allclose(outs["capacity"], outs["tp_shardmap"], atol=1e-5)
     np.testing.assert_allclose(outs["capacity"], outs["ep_shardmap"], atol=1e-5)
@@ -43,7 +43,7 @@ def test_dropless_weights_sum(moe_setup):
     against a brute-force dense evaluation."""
     cfg, p, h = moe_setup
     ctx = RunCtx(moe_strategy="dropless")
-    y, aux = moe_sublayer(p, h, cfg, ctx)
+    y, aux, _ = moe_sublayer(p, h, cfg, ctx)
     xf = h.reshape(-1, h.shape[-1])
     topw, topi, _ = router_topk(xf, p["router"], cfg.moe.top_k)
     dense = jnp.einsum("ecd,edf->ecf", xf[None].repeat(cfg.moe.num_experts, 0), p["wg"])
