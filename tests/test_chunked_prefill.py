@@ -11,6 +11,7 @@ from repro.core.engine import EngineConfig, InferenceEngine
 from repro.core.metrics import Request
 from repro.kernels.paged_attention import (chunked_prefill_attention,
                                            chunked_prefill_reference)
+from repro.kernels.paged_attention import kernel
 from repro.models import RunCtx, build_model
 
 CTX = RunCtx(attn_backend="xla", moe_strategy="dropless", block_q=128, block_kv=128)
@@ -29,55 +30,119 @@ def setup():
 
 
 # ---------------------------------------------------------------- kernel
-def _rand_pool(rng, B, maxp, ps, Hkv, D):
-    P = B * maxp + 1
-    kp = jnp.asarray(rng.standard_normal((P, Hkv, ps, D)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((P, Hkv, ps, D)), jnp.float32)
-    pt = jnp.asarray([[1 + b * maxp + i for i in range(maxp)] for b in range(B)],
-                     jnp.int32)
-    return kp, vp, pt
-
-
-@pytest.mark.parametrize("window", [0, 5])
-def test_chunked_kernel_matches_bruteforce(window):
-    rng = np.random.default_rng(0)
-    B, C, H, Hkv, D, ps, maxp = 3, 8, 4, 2, 16, 4, 8
-    kp, vp, pt = _rand_pool(rng, B, maxp, ps, Hkv, D)
-    starts = jnp.asarray([5, 0, 13], jnp.int32)
-    nvalid = np.array([8, 6, 3])
-    lengths = jnp.asarray(np.asarray(starts) + nvalid, jnp.int32)
-    q = jnp.asarray(rng.standard_normal((B, C, H, D)), jnp.float32)
-    qpos = starts[:, None] + jnp.arange(C)[None]
-
-    kg = np.asarray(kp)[np.asarray(pt)].transpose(0, 1, 3, 2, 4).reshape(B, maxp * ps, Hkv, D)
-    vg = np.asarray(vp)[np.asarray(pt)].transpose(0, 1, 3, 2, 4).reshape(B, maxp * ps, Hkv, D)
+def _bruteforce(q, kp, vp, pt, starts, lengths, window, softcap):
+    """Per query and head, in numpy: softmax over the kv positions the query
+    may see, through the page table."""
+    B, C, H, D = q.shape
+    _, Hkv, ps, _ = kp.shape
+    maxp = pt.shape[1]
+    kg = kp[pt].transpose(0, 1, 3, 2, 4).reshape(B, maxp * ps, Hkv, D)
+    vg = vp[pt].transpose(0, 1, 3, 2, 4).reshape(B, maxp * ps, Hkv, D)
+    kv = np.arange(maxp * ps)
     oracle = np.zeros((B, C, H, D), np.float32)
     for b in range(B):
         for i in range(C):
             p_abs = int(starts[b]) + i
             for h in range(H):
                 hk = h // (H // Hkv)
-                s = (kg[b, :, hk] @ np.asarray(q)[b, i, h]) * (D ** -0.5)
-                kv = np.arange(maxp * ps)
+                s = (kg[b, :, hk] @ q[b, i, h]) * (D ** -0.5)
+                if softcap > 0:
+                    s = softcap * np.tanh(s / softcap)
                 m = (kv < int(lengths[b])) & (kv <= p_abs)
                 if window > 0:
                     m &= kv > p_abs - window
                 s = np.where(m, s, -1e30)
-                w = np.exp(s - s.max())
-                w = np.where(m, w, 0.0)
+                w = np.where(m, np.exp(s - s.max()), 0.0)
                 if w.sum() > 0:
                     w /= w.sum()
                 oracle[b, i, h] = w @ vg[b, :, hk]
+    return oracle
 
-    ref = chunked_prefill_reference(q, kp, vp, pt, lengths, qpos,
-                                    scale=D ** -0.5, window=window)
-    pal = chunked_prefill_attention(q, kp, vp, pt, lengths, qpos,
-                                    scale=D ** -0.5, window=window,
-                                    backend="pallas", interpret=True)
+
+# Rows of the swept cases, by resident length: a padding row (0), one page,
+# exactly one block of pages, one past it, the whole table, and a chunk
+# whose 5-token window starts on the last position of the first block. The
+# table (20 pages of 16) is not a whole number of blocks; the chunk ends at
+# the row's length, as the engine issues it.
+SWEPT_LENGTHS = ("zero", "page", "block", "block+1", "table", "window-edge")
+
+
+def _case(C, G, window, softcap, shuffled, head_blocks=1, lengths=SWEPT_LENGTHS):
+    return dict(C=C, G=G, window=window, softcap=softcap, shuffled=shuffled,
+                head_blocks=head_blocks, lengths=lengths)
+
+
+@pytest.mark.parametrize("case", [
+    # ragged chunks in a table of one block (4-token pages, 8 of them)
+    pytest.param(dict(window=0), id="0"),
+    pytest.param(dict(window=5), id="5"),
+    pytest.param(_case(1, 1, 0, 0.0, True), id="C1-mha-shuffled"),
+    pytest.param(_case(1, 4, 5, 30.0, True), id="C1-gqa-window-softcap-shuffled"),
+    pytest.param(_case(1, 4, 0, 0.0, False), id="C1-gqa"),
+    pytest.param(_case(8, 1, 5, 0.0, False), id="C8-mha-window"),
+    pytest.param(_case(8, 4, 0, 30.0, True), id="C8-gqa-softcap-shuffled"),
+    pytest.param(_case(8, 4, 5, 0.0, True), id="C8-gqa-window-shuffled"),
+    pytest.param(_case(8, 4, 0, 0.0, True, head_blocks=2), id="C8-gqa-head-blocks"),
+    pytest.param(_case(8, 4, 5, 0.0, True, head_blocks=2), id="C8-gqa-window-head-blocks"),
+    pytest.param(_case(1, 4, 0, 0.0, True, lengths=("page", "zero", "block+1")),
+                 id="C1-gqa-padding-between-rows"),
+])
+def test_chunked_kernel_matches_bruteforce(case, monkeypatch):
+    rng = np.random.default_rng(0)
+    if "C" not in case:
+        B, C, H, Hkv, D, ps, maxp = 3, 8, 4, 2, 16, 4, 8
+        starts = np.array([5, 0, 13])
+        nvalid = np.array([8, 6, 3])
+        lengths = starts + nvalid
+        pt = np.asarray([[1 + b * maxp + i for i in range(maxp)] for b in range(B)])
+        window, softcap = case["window"], 0.0
+    else:
+        C, G, window, softcap = case["C"], case["G"], case["window"], case["softcap"]
+        Hkv, D, ps, maxp = 2, 16, 16, 20
+        H, B = Hkv * G, len(case["lengths"])
+        ppb, heads = kernel.block_sizes(ps, maxp, C * G, Hkv, D, 4, 4)
+        assert (ppb, heads) == (16, Hkv) and maxp % ppb
+        if case["head_blocks"] > 1:     # a budget that holds fewer kv heads
+            heads = Hkv // case["head_blocks"]
+            monkeypatch.setattr(kernel, "VMEM_BUDGET", kernel._vmem_bytes(
+                ppb, heads, ps, C * G, D, 4, 4))
+            assert kernel.block_sizes(ps, maxp, C * G, Hkv, D, 4, 4) == (ppb, heads)
+            jax.clear_caches()
+        at = {"zero": 0, "page": ps, "block": ppb * ps, "block+1": ppb * ps + 1,
+              "table": maxp * ps, "window-edge": ppb * ps + 3 + C}
+        lengths = np.array([at[n] for n in case["lengths"]])
+        nvalid = np.minimum(lengths, C)
+        starts = lengths - nvalid
+        if case["shuffled"]:
+            # pages scattered over the pool; entries past a row's resident
+            # pages are out of range, so a read of one would fail
+            pt = np.full((B, maxp), -1)
+            pages = rng.permutation(np.arange(1, B * maxp + 1))
+            for b in range(B):
+                used = -(-lengths[b] // ps)
+                pt[b, :used], pages = pages[:used], pages[used:]
+        else:
+            pt = np.asarray([[1 + b * maxp + i for i in range(maxp)] for b in range(B)])
+    P = B * maxp + 1
+    kp = rng.standard_normal((P, Hkv, ps, D)).astype(np.float32)
+    vp = rng.standard_normal((P, Hkv, ps, D)).astype(np.float32)
+    q = rng.standard_normal((B, C, H, D)).astype(np.float32)
+    qpos = jnp.asarray(starts[:, None] + np.arange(C)[None], jnp.int32)
+    oracle = _bruteforce(q, kp, vp, pt, starts, lengths, window, softcap)
+
+    args = (jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(pt, jnp.int32),
+            jnp.asarray(lengths, jnp.int32), qpos)
+    ref = chunked_prefill_reference(*args, scale=D ** -0.5, softcap=softcap, window=window)
+    pal = chunked_prefill_attention(*args, scale=D ** -0.5, softcap=softcap,
+                                    window=window, backend="pallas", interpret=True)
+    if case.get("head_blocks", 1) > 1:
+        jax.clear_caches()
     for b in range(B):
         n = nvalid[b]
-        assert np.abs(np.asarray(ref)[b, :n] - oracle[b, :n]).max() < 1e-5
-        assert np.abs(np.asarray(pal)[b, :n] - oracle[b, :n]).max() < 1e-5
+        assert np.abs(np.asarray(ref)[b, :n] - oracle[b, :n]).max(initial=0) < 1e-5
+        assert np.abs(np.asarray(pal)[b, :n] - oracle[b, :n]).max(initial=0) < 1e-5
+    # a padding row writes zeros
+    assert not np.asarray(pal)[lengths == 0].any()
 
 
 # ---------------------------------------------------------------- model

@@ -44,14 +44,20 @@ def _compile(fn, one_chip, *shapes):
     return jax.jit(fn).lower(*args).compile()
 
 
-@pytest.mark.parametrize("rows,chunk", [(SLOTS, 1), (CHUNK_ROWS, CHUNK)],
-                         ids=["decode_sweep", "prefill_pack"])
-def test_chunked_prefill_compiles_for_v5e(one_chip, rows, chunk):
+# the benchmark cells' sweeps: Mixtral's 64-slot decode over max_seq 2560,
+# DeepSeek-MoE-16B's (16 MHA heads of 128) 12-slot decode and 2-row prefill
+# pack over max_seq 4096; the prefill pack takes the most VMEM of them
+@pytest.mark.parametrize("rows,chunk,h,hkv,maxp", [
+    (SLOTS, 1, H, HKV, MAXP), (CHUNK_ROWS, CHUNK, H, HKV, MAXP),
+    (64, 1, H, HKV, 160), (12, 1, 16, 16, 256), (CHUNK_ROWS, CHUNK, 16, 16, 256),
+], ids=["decode_sweep", "prefill_pack", "mixtral_c64_decode", "deepseek_c12_decode",
+        "deepseek_prefill_pack"])
+def test_chunked_prefill_compiles_for_v5e(one_chip, rows, chunk, h, hkv, maxp):
     compiled = _compile(
         lambda q, k, v, pt, ln, st: chunked_prefill_pallas(q, k, v, pt, ln, st,
                                                            scale=D ** -0.5),
-        one_chip, ((rows, chunk, H, D), BF16), ((PAGES, HKV, PAGE, D), BF16),
-        ((PAGES, HKV, PAGE, D), BF16), ((rows, MAXP), I32), ((rows,), I32),
+        one_chip, ((rows, chunk, h, D), BF16), ((PAGES, hkv, PAGE, D), BF16),
+        ((PAGES, hkv, PAGE, D), BF16), ((rows, maxp), I32), ((rows,), I32),
         ((rows,), I32))
     assert compiled_kernels(compiled.as_text()) == ["chunked_prefill_pallas"]
 
