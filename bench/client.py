@@ -73,22 +73,27 @@ async def _stream(gateway, codec, s: Sent) -> None:
             return
 
 
-async def drive(gateway, source, *, t_start: float, t_close: float,
-                max_wait_s: float) -> List[Sent]:
-    """Run ``source`` from ``t_start`` until ``t_close``; then wait, at most
-    ``max_wait_s``, for the first token of every request due before
-    ``t_close``, and cancel what is still streaming. Returns every request
-    sent, in send order."""
+async def drive(gateway, source, *, t_start: float, t_close: float, max_wait_s: float,
+                sent: Optional[List[Sent]] = None,
+                stop: Optional[asyncio.Event] = None) -> List[Sent]:
+    """Run ``source`` from ``t_start`` until ``t_close``, or until ``stop``
+    is set; then wait, at most ``max_wait_s``, for the first token of every
+    request due before the close, and cancel what is still streaming.
+    Returns every request sent, in send order, in ``sent`` where given (so
+    that the caller can watch it grow)."""
     from repro.core.serde import CODECS
 
     codec = CODECS[gateway.cfg.codec]
-    sent: List[Sent] = []
+    sent = [] if sent is None else sent
     tasks: List[asyncio.Task] = []
+
+    def open_() -> bool:
+        return now() < t_close and not (stop is not None and stop.is_set())
 
     async def closed_client(i: int) -> None:
         await asyncio.sleep(max(0.0, t_start + i * source.stagger_s - now()))
         last = None
-        while now() < t_close:
+        while open_():
             job = source.next(i, last)
             if job is None:
                 return
@@ -103,6 +108,8 @@ async def drive(gateway, source, *, t_start: float, t_close: float,
             if due >= t_close:
                 return
             await asyncio.sleep(max(0.0, due - now()))
+            if not open_():
+                return
             s = Sent(f"o{j}", 0, job, t_due=due)
             sent.append(s)
             tasks.append(asyncio.ensure_future(_stream(gateway, codec, s)))
@@ -111,10 +118,17 @@ async def drive(gateway, source, *, t_start: float, t_close: float,
         tasks.extend(asyncio.ensure_future(closed_client(i)) for i in range(source.clients))
     else:
         tasks.append(asyncio.ensure_future(open_loop()))
-    await asyncio.sleep(max(0.0, t_close - now()))
-    deadline = t_close + max_wait_s
+    if stop is None:
+        await asyncio.sleep(max(0.0, t_close - now()))
+    else:
+        try:
+            await asyncio.wait_for(stop.wait(), max(0.0, t_close - now()))
+        except asyncio.TimeoutError:
+            pass
+    t_end = min(now(), t_close)
+    deadline = t_end + max_wait_s
     while now() < deadline and any(not s.t_first and s.error is None
-                                   for s in sent if s.t_due < t_close):
+                                   for s in sent if s.t_due < t_end):
         await asyncio.sleep(0.02)
     for t in tasks:
         t.cancel()

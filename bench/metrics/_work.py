@@ -1,6 +1,6 @@
 """The work of each engine step in the traced slice, as a lower bound: the
 step's StepRecord and its planned rows, with rows the step did not run left
-out (``bench.flops`` says why each count is a lower bound)."""
+out (``bench.arch`` says why each count is a lower bound)."""
 
 
 def traced_steps(run):

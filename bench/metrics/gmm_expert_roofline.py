@@ -5,19 +5,14 @@ layer, the weights of every expert that one of its live tokens reached (the
 program's counter, ``StepRecord.prefill_experts`` / ``decode_experts``), not
 ``k`` of them, and its rows are those the call ran (``prefill_work``, the
 decode rows and their drafts)."""
-from bench.flops import gmm_work, least_time
+from bench.flops import least_time
 from bench.metrics._records import traced_records
 
 
-def call_least_time(w: dict, tokens: int, reached, peak: dict, nbytes: int = 2) -> float:
+def call_least_time(arch, w: dict, tokens: int, reached, peak: dict) -> float:
     """Least time of one call's grouped matmuls: ``reached`` experts per
     MoE layer, ``tokens`` live tokens."""
-    total = 0.0
-    for experts in reached:
-        flops, nb = gmm_work(w, tokens, nbytes)
-        nb += 3 * (experts - w["k"]) * w["d"] * w["f"] * nbytes   # k already counted
-        total += least_time(flops, nb, peak)
-    return total
+    return sum(least_time(*arch.gmm_work(w, tokens, reached=e), peak) for e in reached)
 
 
 def read(run):
@@ -29,6 +24,6 @@ def read(run):
     for rec in records:
         calls = ((sum(n for _, n in rec.prefill_work), rec.prefill_experts),
                  (rec.decode_rows + rec.drafted_tokens, rec.decode_experts))
-        least += sum(call_least_time(run.widths, t, reached, run.peak)
+        least += sum(call_least_time(run.arch, run.widths, t, reached, run.peak)
                      for t, reached in calls if t)
     return 100.0 * least / device_s

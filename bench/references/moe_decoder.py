@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.model import moe_widths
+from bench.arch.moe_decoder import widths
 
 F32 = jnp.float32
 HI = jax.lax.Precision.HIGHEST
@@ -153,7 +153,7 @@ def forward(params, cfg: dict, seqs: Sequence[np.ndarray], keep: Sequence[int],
     a power of two: every program sees the same few shapes whatever the
     lengths, so it compiles once and is found in the cache on later runs.
     Padding rows are routed to no expert and never read."""
-    w = moe_widths(cfg)
+    w = widths(cfg)
     lens = [len(s) for s in seqs]
     Sp = -(-max([cfg["engine"]["max_seq"], *lens]) // QBLOCK) * QBLOCK
     seg = [Sp] * len(seqs)

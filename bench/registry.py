@@ -4,6 +4,7 @@
 * a traffic mix: ``bench/traffic/<traffic>.json``, whose ``kind`` names its
   generator ``bench/traffic/<kind>.py``;
 * the limits that decide ``correct``: ``bench/limits/<cell>.json``;
+* a configuration's architecture module: ``bench/arch/<arch>.py``;
 * a configuration's plain reference: ``bench/references/<reference>.py``;
 * a per-layer metric: ``bench/metrics/<metric>.py``.
 
@@ -57,6 +58,10 @@ def _module(path: Path) -> ModuleType:
 
 def generator(root: Path, kind: str) -> ModuleType:
     return _module(root / "bench" / "traffic" / f"{kind}.py")
+
+
+def arch(root: Path, name: str) -> ModuleType:
+    return _module(root / "bench" / "arch" / f"{name}.py")
 
 
 def reference(root: Path, name: str) -> ModuleType:

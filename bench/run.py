@@ -38,7 +38,8 @@ import tempfile
 import threading
 from dataclasses import dataclass, field
 from pathlib import Path
-from typing import Dict, List, Optional
+from types import ModuleType
+from typing import Dict, Optional
 
 ROOT = Path(__file__).resolve().parents[1]
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -48,6 +49,7 @@ SPAN_STEP, SPAN_PLAN, SPAN_HANDLE = "engine_step", "plan_iteration", "gateway_ha
 TRACE_S = 4.0                  # length of the traced slice, at most a third of the window
 MAX_WAIT_S = 60.0              # after the window: wait this long for due first tokens
 WARM_PROMPT = 24               # tokens of the warm-up request
+STALL_S = 0.4                  # a step this long gets the host's counters over it logged
 
 
 def log(msg: str) -> None:
@@ -59,7 +61,8 @@ class RunRecord:
     """What a finished run hands the per-layer metric readers."""
     cell: dict
     cfg: dict
-    widths: dict                       # bench.model.moe_widths(cfg)
+    arch: ModuleType                   # the configuration's bench/arch module
+    widths: dict                       # arch.widths(cfg)
     chips: int
     t_open: float
     t_close: float
@@ -140,15 +143,18 @@ class Profiler:
 # ------------------------------------------------------------------ the run
 def run_cell(root: Path, bench: dict, cell: dict, *, seed: int, seconds: float,
              trace: bool, devices: list, control: bool = False,
-             t_process: float = T_PROCESS) -> dict:
+             t_process: float = T_PROCESS, window_tokens: int = 0) -> dict:
+    """One run of ``cell``. ``window_tokens`` > 0 closes the window once that
+    many tokens reached their clients inside it (``seconds`` then caps it):
+    a test's sample then holds the same work however loaded its machine is."""
     import jax
     import numpy as np
 
     from bench import check, e2e, registry
     from bench.client import drive, now
-    from bench.model import (engine_kwargs, make_weights, moe_widths, param_bytes,
-                             program_config, weight_dtype)
+    from bench.model import engine_kwargs, make_weights, param_bytes, weight_dtype
     from bench.peaks import peaks
+    from bench.watch import Watch
     from repro.core import (EngineConfig, Gateway, InferenceEngine, Replica,
                             ReplicaRouter, RouterConfig, scale_gateway_config)
     from repro.core.metrics import Request
@@ -159,6 +165,7 @@ def run_cell(root: Path, bench: dict, cell: dict, *, seed: int, seconds: float,
     mix = registry.traffic(root, cell["traffic"])
     limits = registry.limits(root, cell["name"])
     gen = registry.generator(root, mix["kind"])
+    arch = registry.arch(root, cfg["arch"])
     ref = registry.reference(root, cfg["reference"])
     chips = cell["chips"]
     devs = devices[:chips]
@@ -166,14 +173,12 @@ def run_cell(root: Path, bench: dict, cell: dict, *, seed: int, seconds: float,
 
     log(f"compile cache: {enable_compile_cache()}")
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    compiles: List[float] = []
-    jax.monitoring.register_event_duration_secs_listener(
-        lambda event, secs, **_: compiles.append(secs)
-        if event == "/jax/core/compile/backend_compile_duration" else None)
+    watch = Watch().install()
 
     # ---- weights, replicas, warm-up: every shape the traffic uses
-    model = build_model(program_config(cfg))
-    weights = [make_weights(model, seed, weight_dtype(cfg), d) for d in devs]
+    model = build_model(arch.program_config(cfg))
+    weights = [make_weights(model, seed, weight_dtype(cfg), d, getattr(arch, "FAN_IN", None))
+               for d in devs]
     jax.block_until_ready(weights)
     log(f"weights: {param_bytes(weights[0])} bytes per chip, "
         f"{time.perf_counter() - t_process:.2f} s")
@@ -185,6 +190,7 @@ def run_cell(root: Path, bench: dict, cell: dict, *, seed: int, seconds: float,
         r.engine._apply_copies([(0, 0)])          # the copy-on-write program (null page)
         r.engine.generate([Request(f"warm-{r.replica_id}", warm.astype(np.int32),
                                    max_new_tokens=3)])
+    compiles = watch.compile_seconds()
     log(f"warm: {len(compiles)} compiles ({sum(compiles):.2f} s), "
         f"{time.perf_counter() - t_process:.2f} s")
     plans: Dict[tuple, dict] = {}
@@ -199,7 +205,6 @@ def run_cell(root: Path, bench: dict, cell: dict, *, seed: int, seconds: float,
     ramp = float(mix.get("ramp_s", 0.0))
     source = gen.make(mix, seed, cfg["vocab_size"], ramp + seconds)
     snaps: Dict[str, Dict[str, dict]] = {}
-    n_compiles = [0, 0]
 
     async def serve():
         t_start = now()
@@ -208,28 +213,45 @@ def run_cell(root: Path, bench: dict, cell: dict, *, seed: int, seconds: float,
             profiler.t0 = t_open + (seconds - profiler.secs) / 2
             profiler.thread.start()
 
-        async def snapshot(at: float, key: str, i: int):
-            await asyncio.sleep(max(0.0, at - now()))
-            snaps[key] = {r.replica_id: r.engine.stats() for r in replicas}
-            n_compiles[i] = len(compiles)
+        sent: list = []
+        stop = asyncio.Event() if window_tokens else None
+        closed_at = {}
 
-        marks = [asyncio.ensure_future(snapshot(t_open, "open", 0)),
-                 asyncio.ensure_future(snapshot(t_close, "close", 1))]
-        sent = await drive(gateway, source, t_start=t_start, t_close=t_close,
-                           max_wait_s=MAX_WAIT_S)
+        async def snapshot(at: float, key: str):
+            if key == "close" and stop is not None:
+                await stop.wait()
+            else:
+                await asyncio.sleep(max(0.0, at - now()))
+            snaps[key] = {r.replica_id: r.engine.stats() for r in replicas}
+
+        async def close_on_tokens():
+            while now() < t_close and e2e.tokens_in(sent, t_open, t_close) < window_tokens:
+                await asyncio.sleep(0.02)
+            closed_at["t"] = min(now(), t_close)
+            stop.set()
+
+        marks = [asyncio.ensure_future(snapshot(t_open, "open")),
+                 asyncio.ensure_future(snapshot(t_close, "close"))]
+        if stop is not None:
+            marks.append(asyncio.ensure_future(close_on_tokens()))
+        await drive(gateway, source, t_start=t_start, t_close=t_close, max_wait_s=MAX_WAIT_S,
+                    sent=sent, stop=stop)
         await asyncio.gather(*marks)
         # no replica may deliver into the loop once it has closed
         await asyncio.get_running_loop().run_in_executor(
             None, lambda: [r.stop() for r in replicas])
-        return sent, t_open, t_close
+        return sent, t_open, closed_at.get("t", t_close)
 
     profiler = Profiler(min(TRACE_S, seconds / 3)) if trace else None
     sent, t_open, t_close = asyncio.run(serve())
     if profiler is not None:
         profiler.thread.join(timeout=120)
     setup_s = t_open - t_process
-    window_compiles = compiles[n_compiles[0]:n_compiles[1]]
+    window_compiles = watch.compile_seconds(t_open, t_close)
     log(f"window: {len(window_compiles)} compiles ({sum(window_compiles):.3f} s)")
+    log(f"window events: {watch.describe(t_open, t_close)}")
+    watch.uninstall()
+    log(f"host counters over the window: {watch.host_delta(t_open, t_close)}")
 
     mem_peak = max(d.memory_stats()["peak_bytes_in_use"] if d.memory_stats() else 0
                    for d in devs)
@@ -253,11 +275,27 @@ def run_cell(root: Path, bench: dict, cell: dict, *, seed: int, seconds: float,
             log(f"{name} third of the window: mean queue {np.mean([s.queue_depth for s in part]):.2f}, "
                 f"mean occupancy {np.mean([s.occupancy for s in part]):.2f}")
     slow = sorted((s for st in steps.values() for s in st), key=lambda s: s.t0 - s.t1)[:6]
-    log("slowest steps (ms, prefill rows, decode rows, admitted): "
+    log("slowest steps (ms, prefill rows, decode rows, admitted, preempted, cow pages, "
+        "s into the window): "
         + ", ".join(f"({(s.t1 - s.t0) * 1e3:.1f}, {s.prefill_rows}, {s.decode_rows}, "
-                    f"{s.n_admitted})" for s in slow))
-    record = RunRecord(cell=cell, cfg=cfg, widths=moe_widths(cfg), chips=chips, t_open=t_open,
-                       t_close=t_close, sent=sent, server=server, steps=steps,
+                    f"{s.n_admitted}, {s.preemptions}, {s.cow_pages}, {s.t0 - t_open:.2f})"
+                    for s in slow))
+    for s in slow:
+        if s.t1 - s.t0 > STALL_S:
+            log(f"host counters over the {(s.t1 - s.t0) * 1e3:.1f}-ms step "
+                f"{s.t0 - t_open:.2f} s into the window: {watch.host_delta(s.t0, s.t1)}")
+    counters = ("preemptions", "evicted_pages", "cow_copies", "prefix_cached_tokens",
+                "prefill_tokens", "decode_tokens")
+    log("engine counters over the window: " + ", ".join(
+        f"{k} {sum(snaps['close'][r][k] - snaps['open'][r][k] for r in snaps['open']):.0f}"
+        for k in counters))
+    delivered = sorted(t for s in sent for t in s.token_times if t_open <= t < t_close)
+    pauses = np.diff([t_open] + delivered + [t_close])
+    i = int(pauses.argmax())
+    log(f"token deliveries: longest pause {pauses[i]:.3f} s from {np.sum(pauses[:i]):.2f} s "
+        f"into the window, {int(np.sum(pauses > 0.5))} pauses over 0.5 s")
+    record = RunRecord(cell=cell, cfg=cfg, arch=arch, widths=arch.widths(cfg), chips=chips,
+                       t_open=t_open, t_close=t_close, sent=sent, server=server, steps=steps,
                        stats_open=snaps["open"], stats_close=snaps["close"], peak=peak,
                        plans=plans)
 

@@ -20,8 +20,8 @@ Interval = Tuple[float, float]
 
 DEVICE_PLANE = re.compile(r"^/device:[A-Z]+:(\d+)$")
 OPS_LINES = ("XLA Ops", "Async XLA Ops")
-# the served path's Pallas kernels, by the name their calls carry
-KERNELS = ("gmm_pallas", "chunked_prefill_pallas")
+# a Pallas kernel's calls carry a name that ends so (``<kernel>_pallas``)
+KERNEL_SUFFIX = "_pallas"
 
 
 @dataclass
@@ -104,7 +104,7 @@ def host_activity(host: Sequence[Sequence[Span]], t: float, names: Sequence[str]
 
 def base_name(op: str) -> str:
     """An op's HLO instruction name without its numeric suffix: the TPU
-    trace names an op by its whole instruction (``%gmm_pallas.25 = bf16[..]
+    trace names an op by its whole instruction (``%foo_pallas.25 = bf16[..]
     custom-call(..)``), the CPU by its name alone (``fusion.12``)."""
     name = op.split(" = ", 1)[0].lstrip("%")
     return re.sub(r"(\.\d+)+$", "", name)
@@ -124,26 +124,29 @@ def top_ops(ops: Sequence[Span], lo: float, hi: float, n: int = 10) -> List[Tupl
     return [(k, v * 1e-9) for k, v in sorted(total.items(), key=lambda kv: -kv[1])[:n]]
 
 
-def kernel_ns(ops: Sequence[Span], kernel: str, windows: Sequence[Interval]) -> float:
-    """Device nanoseconds of ``kernel``'s calls that lie inside ``windows``."""
+def kernel_ns(ops: Sequence[Span], windows: Sequence[Interval]) -> Dict[str, float]:
+    """Device nanoseconds, by kernel, of the Pallas kernels' calls that lie
+    inside ``windows``."""
     windows = sorted(windows)
     starts = [a for a, _ in windows]
-    out = 0.0
+    out: Dict[str, float] = defaultdict(float)
     for s in ops:
-        if base_name(s.name) != kernel:
+        name = base_name(s.name)
+        if not name.endswith(KERNEL_SUFFIX):
             continue
         i = bisect.bisect_right(starts, s.start) - 1
         if i >= 0 and s.end <= windows[i][1]:
-            out += s.end - s.start
-    return out
+            out[name] += s.end - s.start
+    return dict(out)
 
 
 def summarize(t: Trace, chips: int, step_span: str) -> dict:
     """The traced slice: the engine steps (host spans ``step_span``, with
     ``replica`` and ``step`` stats) that lie wholly in the trace; per
     device, the busy time within their extent (compute and async ops; the
-    compute alone apart); the kernels' device time inside the steps; the
-    top ops; and the idle time by what the serving threads were doing.
+    compute alone apart); the device time inside the steps of every Pallas
+    kernel that ran there; the top ops; and the idle time by what the
+    serving threads were doing.
     Empty where the trace holds no steps or devices."""
     steps = [(str(s.stats["replica"]), int(s.stats["step"]), s.start, s.end)
              for line in t.host for s in line if s.name == step_span and "step" in s.stats]
@@ -153,8 +156,10 @@ def summarize(t: Trace, chips: int, step_span: str) -> dict:
     devices = sorted(t.ops)[:chips]
     every = {d: t.ops[d] + t.async_ops.get(d, []) for d in devices}
     windows = [(a, b) for _, _, a, b in steps]
-    kernels = {k: sum(kernel_ns(t.ops[d], k, windows) for d in devices) * 1e-9
-               for k in KERNELS}
+    kernels: Dict[str, float] = defaultdict(float)
+    for d in devices:
+        for name, ns in kernel_ns(t.ops[d], windows).items():
+            kernels[name] += ns * 1e-9
     idle: Dict[str, float] = defaultdict(float)
     ops: Dict[str, float] = defaultdict(float)
     for d in devices:
@@ -168,6 +173,6 @@ def summarize(t: Trace, chips: int, step_span: str) -> dict:
 
     return {"window_s": (hi - lo) * 1e-9, "busy_s": mean_busy(every),
             "compute_busy_s": mean_busy(t.ops),
-            "steps": [(r, n) for r, n, _, _ in steps], "kernel_s": kernels,
+            "steps": [(r, n) for r, n, _, _ in steps], "kernel_s": dict(kernels),
             "device_ops": sorted(ops.items(), key=lambda kv: -kv[1])[:10],
             "idle_gaps": sorted(idle.items(), key=lambda kv: -kv[1])[:10]}

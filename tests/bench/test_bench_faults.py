@@ -20,7 +20,7 @@ def test_sound_run_is_correct(tiny_root):
     res = _run(*tiny_root)
     assert res["correct"], res["check"]
     assert list(res)[-1] == "check" and res["check"]["checked_tokens"]["value"] > 0
-    assert set(res["metrics"]) == {"output_tok_s", "ttft_p90_s", "itl_p99_ms", "setup_s"}
+    assert set(res["metrics"]) == {"output_tok_s", "itl_p99_ms", "setup_s"}
     assert res["device"]["platform"] == "cpu" and res["attempted"] > 0
 
 

@@ -5,10 +5,10 @@ from pathlib import Path
 
 import pytest
 
-from bench import flops, registry
+from bench import e2e, flops, registry
+from bench.arch import moe_decoder as arch
 from bench.client import Job, Sent
-from bench.model import moe_widths
-from bench.run import RunRecord
+from bench.run import MAX_WAIT_S, RunRecord
 from repro.core.metrics import Request
 from repro.core.timeline import StepRecord
 
@@ -42,7 +42,7 @@ def _run(trace=True):
         red = {"window_s": 0.2, "busy_s": 0.15, "steps": [("r0", 1), ("r0", 2)],
                "kernel_s": {"gmm_pallas": 0.05, "chunked_prefill_pallas": 0.02},
                "device_ops": [], "idle_gaps": []}
-    return RunRecord(cell={"name": "c"}, cfg=cfg, widths=moe_widths(cfg), chips=1,
+    return RunRecord(cell={"name": "c"}, cfg=cfg, arch=arch, widths=arch.widths(cfg), chips=1,
                      t_open=10.0, t_close=20.0, sent=sent, server=server, steps=steps,
                      stats_open={"r0": {"prefix_cached_tokens": 100.0}},
                      stats_close={"r0": {"prefix_cached_tokens": 350.0}},
@@ -63,17 +63,29 @@ def test_counter_and_span_metrics():
     assert _read("replica_spread", run) is None          # one chip: no router spread
 
 
+def test_first_token_p90_reads_what_ttft_p90_read():
+    run = _run(trace=False)
+    for i, s in enumerate(run.sent[:-1]):
+        s.t_first = s.t_due + 0.1 * (i + 1)          # the last one never gets a token
+    want = e2e.end_to_end(run.sent, run.t_open, run.t_close,
+                          run.t_close + MAX_WAIT_S - run.t_open)["ttft_p90_s"]
+    assert _read("first_token_p90_s", run) == pytest.approx(want)
+    assert want == pytest.approx(0.9)                 # 9th of 10: the missing one is 10th
+    run.sent = []
+    assert _read("first_token_p90_s", run) is None
+
+
 def test_device_metrics_are_lower_bounds_over_the_traced_steps():
     run = _run()
     w = run.widths
-    gmm = 4 * sum(flops.least_time(*flops.gmm_work(w, t), PEAK) for t in (256, 32, 64))
+    gmm = 4 * sum(flops.least_time(*arch.gmm_work(w, t), PEAK) for t in (256, 32, 64))
     assert _read("gmm_roofline", run) == pytest.approx(100 * gmm / 0.05)
-    attn = 4 * (flops.least_time(*flops.attn_work(w, [(0, 256)]), PEAK)
-                + flops.least_time(*flops.attn_work(w, [(c, 1) for c in range(100, 132)]), PEAK)
-                + flops.least_time(*flops.attn_work(w, [(c, 1) for c in range(200, 264)]), PEAK))
+    attn = 4 * (flops.least_time(*arch.attn_work(w, [(0, 256)]), PEAK)
+                + flops.least_time(*arch.attn_work(w, [(c, 1) for c in range(100, 132)]), PEAK)
+                + flops.least_time(*arch.attn_work(w, [(c, 1) for c in range(200, 264)]), PEAK))
     assert _read("attn_roofline", run) == pytest.approx(100 * attn / 0.02)
-    model = (flops.model_flops(w, 256 + 32, 33, [(0, 256)] + [(c, 1) for c in range(100, 132)])
-             + flops.model_flops(w, 64, 64, [(c, 1) for c in range(200, 264)]))
+    model = (arch.model_flops(w, 256 + 32, 33, [(0, 256)] + [(c, 1) for c in range(100, 132)])
+             + arch.model_flops(w, 64, 64, [(c, 1) for c in range(200, 264)]))
     assert _read("step_mfu", run) == pytest.approx(100 * model / 0.2 / 197e12)
 
 

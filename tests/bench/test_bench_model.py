@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from bench import model as bm
+from bench.arch import moe_decoder as arch
 from bench_tiny import make_root
 from bench.references import moe_decoder
 from repro.models import RunCtx, build_model
@@ -24,7 +25,7 @@ def _cfg(tiny_root):
 @pytest.mark.parametrize("name", ["mixtral-8x7b-pp8", "deepseek-moe-16b-pp4"])
 def test_published_configs_map_onto_the_program(name):
     cfg = json.loads((REPO / "bench" / "configs" / f"{name}.json").read_text())
-    mc = bm.program_config(cfg)
+    mc = arch.program_config(cfg)
     assert (mc.d_model, mc.n_heads, mc.n_kv_heads, mc.head_dim, mc.vocab) == \
         (cfg["hidden_size"], cfg["num_attention_heads"], cfg["num_key_value_heads"], 128,
          cfg["vocab_size"])
@@ -38,11 +39,11 @@ def test_a_router_the_program_cannot_run_is_refused():
     cfg = json.loads((REPO / "bench" / "configs" / "deepseek-moe-16b-pp4.json").read_text())
     cfg["norm_topk_prob"] = False
     with pytest.raises(ValueError, match="norm_topk_prob"):
-        bm.program_config(cfg)
+        arch.program_config(cfg)
 
 
 def test_weights_come_from_the_seed(tiny_root):
-    model = build_model(bm.program_config(_cfg(tiny_root)))
+    model = build_model(arch.program_config(_cfg(tiny_root)))
     dev = jax.devices()[0]
     a, b = (bm.make_weights(model, 2**31 + 5, jnp.bfloat16, dev) for _ in range(2))
     c = bm.make_weights(model, 6, jnp.bfloat16, dev)
@@ -56,7 +57,7 @@ def test_weights_come_from_the_seed(tiny_root):
 def test_reference_matches_the_program_forward_in_float32(tmp_path, family):
     root, _, _ = make_root(tmp_path, family)
     cfg = json.loads((root / "bench" / "configs" / "tiny.json").read_text())
-    model = build_model(bm.program_config(cfg))
+    model = build_model(arch.program_config(cfg))
     params = bm.make_weights(model, 11, jnp.float32, jax.devices()[0])
     tokens = np.random.default_rng(0).integers(1, cfg["vocab_size"], 70).astype(np.int32)
     want, _ = model.forward(params, {"tokens": jnp.asarray(tokens)[None]},

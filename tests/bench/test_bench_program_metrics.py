@@ -13,7 +13,7 @@ import pytest
 
 from bench import flops, registry
 from bench import trace as tr
-from bench.model import moe_widths
+from bench.arch import moe_decoder as arch
 from bench.run import SPAN_HANDLE, SPAN_PLAN, SPAN_STEP, RunRecord, instrument
 from repro.configs import tiny_config
 from repro.core import EngineConfig, InferenceEngine, Replica
@@ -55,7 +55,7 @@ def _run(steps, trace=True):
                "device_ops": [], "idle_gaps": []}
     plans = {("r0", 1): {"prefill": [(0, 256)], "decode": list(range(100, 132))},
              ("r0", 2): {"prefill": [], "decode": list(range(200, 264))}}
-    return RunRecord(cell={"name": "c"}, cfg=cfg, widths=moe_widths(cfg), chips=1,
+    return RunRecord(cell={"name": "c"}, cfg=cfg, arch=arch, widths=arch.widths(cfg), chips=1,
                      t_open=10.0, t_close=20.0, sent=[], server={}, steps={"r0": steps},
                      stats_open={}, stats_close={}, peak=PEAK, trace=red, plans=plans)
 
@@ -76,7 +76,7 @@ def test_gmm_expert_roofline_streams_every_expert_reached():
     def call(tokens, reached):
         total = 0.0
         for e in reached:
-            fl, nb = flops.gmm_work(w, tokens)
+            fl, nb = arch.gmm_work(w, tokens)
             total += flops.least_time(fl, nb + 3 * (e - w["k"]) * w["d"] * w["f"] * 2, PEAK)
         return total
 

@@ -20,8 +20,9 @@ def test_busy_union_and_gaps():
 def test_kernel_time_inside_step_windows_only():
     ops = _ops(("gmm_pallas", 0, 4), ("gmm_pallas.12", 10, 13), ("gmm_pallas", 30, 35),
                ("chunked_prefill_pallas.1", 11, 12), ("gmm_pallas_like", 10, 12))
-    assert tr.kernel_ns(ops, "gmm_pallas", [(9, 20), (29, 34)]) == 3
-    assert tr.kernel_ns(ops, "chunked_prefill_pallas", [(9, 20)]) == 1
+    assert tr.kernel_ns(ops, [(9, 20), (29, 34)]) == {"gmm_pallas": 3,
+                                                      "chunked_prefill_pallas": 1}
+    assert tr.kernel_ns(ops, [(9, 20)])["chunked_prefill_pallas"] == 1
     assert tr.base_name("fusion.3.1") == "fusion"
 
 
@@ -63,6 +64,17 @@ def test_summarize_steps_busy_kernels_and_gaps():
     assert tr.summarize(Trace(ops={}, host=_trace().host), 1, "engine_step") == {}
 
 
+def test_summarize_times_every_pallas_kernel():
+    t = _trace()
+    t.ops[0] += _ops(("%foo_pallas.7 = bf16[8] custom-call()", 60, 75),
+                     ("foo_pallas", 205, 210),                    # between steps
+                     ("foo_pallas_like.1", 125, 130))
+    s = tr.summarize(t, 1, "engine_step")
+    assert s["kernel_s"] == {"gmm_pallas": pytest.approx(80e-9),
+                             "chunked_prefill_pallas": pytest.approx(30e-9),
+                             "foo_pallas": pytest.approx(15e-9)}
+
+
 def test_async_ops_count_as_busy_and_compute_apart():
     t = _trace()
     t.async_ops = {0: _ops(("copy-start.3", 85, 115), ("copy-start.4", 250, 300))}
@@ -102,4 +114,4 @@ def test_tpu_op_names_and_containers():
                ("%fusion.2 = bf16[8] fusion()", 40, 50))
     assert dict(tr.top_ops(ops, 0, 100)) == {"gmm_pallas": pytest.approx(20e-9),
                                              "fusion": pytest.approx(10e-9)}
-    assert tr.kernel_ns(ops, "gmm_pallas", [(0, 100)]) == 20
+    assert tr.kernel_ns(ops, [(0, 100)]) == {"gmm_pallas": 20}
