@@ -13,6 +13,7 @@ from repro.configs.base import (  # noqa: F401 (re-export)
     EncoderConfig,
     LayerGroup,
     LONG_500K,
+    MLAConfig,
     ModelConfig,
     MoEConfig,
     PREFILL_32K,
@@ -21,6 +22,7 @@ from repro.configs.base import (  # noqa: F401 (re-export)
     SSMConfig,
     TRAIN_4K,
     VisionConfig,
+    YarnConfig,
     shape_applicable,
 )
 
@@ -36,9 +38,12 @@ _MODULES = {
     "mamba2-1.3b": "mamba2_1_3b",
     "jamba-v0.1-52b": "jamba_v0_1_52b",
     "mixtral-8x7b": "mixtral_8x7b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
-ASSIGNED_ARCHS: List[str] = [a for a in _MODULES if a != "mixtral-8x7b"]
+# mixtral is the paper's own model; deepseek-v2-lite the benchmark's latent one
+ASSIGNED_ARCHS: List[str] = [a for a in _MODULES
+                             if a not in ("mixtral-8x7b", "deepseek-v2-lite")]
 ALL_ARCHS: List[str] = list(_MODULES)
 
 _cache: Dict[str, ModelConfig] = {}
@@ -82,6 +87,8 @@ def tiny_config(name: str, *, seq_len: int = 64) -> ModelConfig:
     )
     enc = dataclasses.replace(cfg.encoder, n_layers=2, cross_attn_memory=32) if cfg.encoder else None
     vis = dataclasses.replace(cfg.vision, n_patches=8, d_patch=48) if cfg.vision else None
+    mla = (MLAConfig(kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=16, v_head_dim=16)
+           if cfg.mla else None)
     return cfg.scaled(
         name=cfg.name + "-tiny",
         n_layers=n_layers,
@@ -97,5 +104,6 @@ def tiny_config(name: str, *, seq_len: int = 64) -> ModelConfig:
         ssm=ssm,
         encoder=enc,
         vision=vis,
+        mla=mla,
         layer_groups=groups,
     )

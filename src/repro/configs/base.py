@@ -22,6 +22,42 @@ class MoEConfig:
     # impl: "capacity" (einsum dispatch, shards via GSPMD; used for dry-run/train)
     #       "dropless" (sort + ragged gmm; exact, used by the serving engine)
     impl: str = "capacity"
+    norm_topk: bool = True         # renormalize the top-k router weights to sum 1
+                                   # (DeepSeek's norm_topk_prob; Mixtral's softmax
+                                   # over the top-k logits is the same as True)
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434) without a
+    query LoRA: a token caches one normed latent of ``kv_lora_rank`` and one
+    roped key of ``qk_rope_head_dim`` shared by every head; each head's key
+    is its up-projected latent (``qk_nope_head_dim``) joined with that rope
+    key, and its value the latent up-projected to ``v_head_dim``."""
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+
+    @property
+    def q_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def cache_dim(self) -> int:
+        """Values one token caches per layer: the latent and the rope key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling (``rope_scaling.type`` "yarn", as DeepSeek-V2 has it)."""
+    factor: float = 40.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
 
 
 @dataclass(frozen=True)
@@ -95,6 +131,8 @@ class ModelConfig:
     encoder: Optional[EncoderConfig] = None
     vision: Optional[VisionConfig] = None
     layer_groups: Tuple[LayerGroup, ...] = ()
+    mla: Optional[MLAConfig] = None     # set: the attention layers are latent
+    rope_yarn: Optional[YarnConfig] = None   # YaRN on the latent layers' rope
     source: str = ""               # provenance tag from the assignment table
 
     def __post_init__(self):
@@ -107,6 +145,8 @@ class ModelConfig:
             )
         got = sum(g.n_layers for g in self.layer_groups)
         assert got == self.n_layers, f"{self.name}: layer_groups cover {got} != n_layers {self.n_layers}"
+        assert self.rope_yarn is None or self.mla is not None, \
+            f"{self.name}: YaRN rope is computed by the latent attention layers only"
 
     # -- derived ------------------------------------------------------------
     @property
@@ -166,4 +206,6 @@ def shape_applicable(model: ModelConfig, shape: ShapeConfig) -> Tuple[bool, str]
     full-attention archs per the assignment; see DESIGN.md §4."""
     if shape.name == "long_500k" and not model.has_subquadratic_path:
         return False, "pure full-attention arch: 524k context not deployable (skip per DESIGN.md)"
+    if shape.is_serve and model.mla is not None:
+        return False, "latent attention is served through the paged pool only (no dense cache)"
     return True, ""

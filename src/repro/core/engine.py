@@ -112,6 +112,17 @@ def _cached_jit(name: str, model, ctx, sampling, fn, donate: int):
     return _JIT_CACHE[key]
 
 
+# the paged cache's page pools, by key: K and V of plain attention layers,
+# the latent rows of latent layers; each (layers, num_pages, ...)
+PAGE_POOLS = ("kp", "vp", "lat")
+
+
+def kv_bytes_per_token(cache, pool_tokens: int) -> int:
+    """Bytes of page pool one cached token takes, summed over the layers."""
+    return sum(x.size * x.dtype.itemsize for path, x in jax.tree_util.tree_leaves_with_path(cache)
+               if getattr(path[-1], "key", None) in PAGE_POOLS) // pool_tokens
+
+
 def sample_tokens(logits, key, temperature: float, top_p: float, greedy: bool):
     """logits (B, V) -> (B,) int32. Nucleus sampling with temperature."""
     if greedy or temperature <= 0.0:
@@ -176,6 +187,7 @@ class InferenceEngine:
                 cfg.max_slots, cfg.max_seq, cfg.cache_dtype, kind="paged",
                 page_size=cfg.page_size, num_pages=cfg.num_pages))
             self._key = self._put(jax.random.PRNGKey(cfg.seed))
+        self.kv_bytes_per_token = kv_bytes_per_token(self.cache, cfg.num_pages * cfg.page_size)
         self.page_table = np.zeros((cfg.max_slots, cfg.max_pages_per_seq), np.int32)
         self.extras: Dict[str, Any] = {}  # frames/patches per request (encdec/vlm)
         sampling = (cfg.temperature, cfg.top_p, cfg.greedy, cfg.page_size)
@@ -273,12 +285,12 @@ class InferenceEngine:
         return lowered
 
     def _copy_pages_fn(self, cache, src, dst):
-        """Device-side page copy (the COW step): kp/vp[:, dst] = kp/vp[:, src]
-        across every attention layer, in one fused call. Padding entries are
-        0->0 null-page self-copies (inert)."""
+        """Device-side page copy (the COW step): pool[:, dst] = pool[:, src]
+        for every page pool (``PAGE_POOLS``) of every attention layer, in one
+        fused call. Padding entries are 0->0 null-page self-copies (inert)."""
         def walk(c):
             if isinstance(c, dict):
-                return {k: (v.at[:, dst].set(v[:, src]) if k in ("kp", "vp")
+                return {k: (v.at[:, dst].set(v[:, src]) if k in PAGE_POOLS
                             else walk(v)) for k, v in c.items()}
             if isinstance(c, (list, tuple)):
                 return type(c)(walk(x) for x in c)
@@ -783,6 +795,7 @@ class InferenceEngine:
             "accepted_tokens": float(self.accepted_tokens),
             "spec_acceptance_rate": (self.accepted_tokens / self.drafted_tokens
                                      if self.drafted_tokens else 0.0),
+            "kv_bytes_per_token": float(self.kv_bytes_per_token),
         }
 
     def cancel(self, req_id: str) -> bool:

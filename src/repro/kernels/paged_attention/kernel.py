@@ -372,3 +372,178 @@ def chunked_prefill_pallas(
     )(page_table.reshape(-1).astype(jnp.int32), lengths.astype(jnp.int32),
       starts.astype(jnp.int32), q4, k_pages, v_pages)
     return out.reshape(B, Hkv, C, G, D).transpose(0, 2, 1, 3, 4).reshape(B, C, H, D)
+
+
+# ------------------------------------------------------------------ latent
+# Latent (``mla_paged_pallas``, every attention call the engine serves to a
+# model of latent attention layers, ``models/mla.py``): one pool
+# (P, page_size, Dk) of each token's latent and rope key, shared by all
+# heads. The same page-block sweep as above, with the chunk's tokens and all
+# heads folded into one row axis; each page is read once, scored over all Dk
+# columns, and its first Dv columns (the latent) are the values. P.V takes P
+# in the pool's dtype, with f32 accumulation.
+def _mla_vmem_bytes(ppb, rows, page_size, dk, dv, kv_bytes, q_bytes):
+    """VMEM one grid step of the latent kernel holds, from its shapes."""
+    bk, dkp = ppb * page_size, _pad(dk, 128)
+    r = _pad(rows, 8)
+    pages = 2 * ppb * _pad(page_size, 32 // kv_bytes) * dkp * kv_bytes
+    q_o = 2 * _pad(rows, 32 // q_bytes) * (dkp + dv) * q_bytes
+    state = r * (2 * 128 + dv) * 4                        # m, l, acc in f32
+    temps = 4 * r * _pad(bk, 128) * 4 + bk * dv * kv_bytes  # scores, probs; V
+    return pages + q_o + state + temps
+
+
+def mla_block_sizes(page_size, max_pages, chunk, heads, dk, dv, kv_bytes, q_bytes):
+    """(pages per block, query tokens per row block) of a latent call:
+    blocks of ``BLOCK_TOKENS`` tokens (fewer where the table is shorter),
+    and the most query tokens (a divisor of ``chunk``) whose ``heads`` rows
+    each fit ``VMEM_BUDGET``."""
+    ppb = max(1, min(max_pages, BLOCK_TOKENS // page_size))
+    for c in range(chunk, 0, -1):
+        if chunk % c == 0 and _mla_vmem_bytes(ppb, c * heads, page_size, dk, dv,
+                                              kv_bytes, q_bytes) <= VMEM_BUDGET:
+            return ppb, c
+    return ppb, 1
+
+
+def _mla_kernel(
+    # scalar prefetch
+    pt_ref,      # (B * max_pages,) int32  page table, row-major
+    len_ref,     # (B,) int32   total resident tokens (incl. this chunk)
+    start_ref,   # (B,) int32   absolute position of the chunk's first token
+    # inputs
+    q_ref,       # (1, rows, Dk)  a block of the chunk's (token, head) rows
+    pool_hbm,    # (P, page_size, Dk)  latent pool, left in HBM
+    # outputs
+    o_ref,       # (1, rows, Dv)
+    # scratch
+    buf,         # (2, ppb, page_size, Dk)  two blocks of pages
+    sems,        # DMA (2,): one per buffer
+    m_scr, l_scr, acc_scr,   # (rows, 128), (rows, 128), (rows, Dv)
+    *,
+    scale: float,
+    page_size: int,
+    max_pages: int,
+    heads: int,
+):
+    b, rb = pl.program_id(0), pl.program_id(1)
+    ppb = buf.shape[1]
+    bk = ppb * page_size
+    rows, dk = q_ref.shape[1], q_ref.shape[2]
+    dv = o_ref.shape[2]
+    length, start = len_ref[b], start_ref[b]
+    # the block's last query sees no further than its own position
+    end = jnp.minimum(length, start + ((rb + 1) * rows - 1) // heads + 1)
+    n_pages = pl.cdiv(end, page_size)
+    hi = pl.cdiv(end, bk)
+
+    def dma(blk, slot, wait):
+        """Start (or wait for) the copies of block ``blk``'s resident pages
+        into buffer ``slot``, one whole page each."""
+        def page(j, carry):
+            phys = pt_ref[b * max_pages + blk * ppb + j]
+            copy = pltpu.make_async_copy(pool_hbm.at[phys], buf.at[slot, j], sems.at[slot])
+            copy.wait() if wait else copy.start()
+            return carry
+
+        jax.lax.fori_loop(0, jnp.minimum(ppb, n_pages - blk * ppb), page, 0)
+
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(hi > 0)
+    def _first_block():
+        dma(0, 0, wait=False)
+
+    def block(i, slot):
+        base = i * bk
+
+        @pl.when(i + 1 < hi)
+        def _next_block():
+            dma(i + 1, 1 - slot, wait=False)
+
+        dma(i, slot, wait=True)
+        q = q_ref[0]                                          # (rows, Dk)
+        kv = buf[slot].reshape(bk, dk)                        # (bk, Dk)
+        if q.dtype != kv.dtype:
+            q, kv = q.astype(jnp.float32), kv.astype(jnp.float32)
+        s = jax.lax.dot_general(
+            q, kv, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale                                             # (rows, bk)
+        kv_pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        q_pos = start + (rb * rows + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)) // heads
+        mask = jnp.logical_and(kv_pos < length, kv_pos <= q_pos)
+        s = jnp.where(mask, s, NEG_INF)
+        # pages past ``end`` were not copied: their rows hold an older
+        # block, or nothing, and must not reach P.V
+        live = base + jax.lax.broadcasted_iota(jnp.int32, (bk, dv), 0) < end
+        v = jnp.where(live, kv[:, :dv], jnp.zeros((), kv.dtype))   # the latent
+
+        m_prev, l_prev = m_scr[...], l_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        pr = jnp.where(mask, jnp.exp(s - m_new[:, 0:1]), 0.0)
+        l_scr[...] = alpha * l_prev + jnp.sum(pr, axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha[:, 0:1] + jax.lax.dot_general(
+            pr.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[...] = m_new
+        return 1 - slot
+
+    jax.lax.fori_loop(0, hi, block, 0)
+    denom = l_scr[:, 0:1]
+    out = jnp.where(denom > 0.0, acc_scr[...] / jnp.maximum(denom, 1e-30), 0.0)
+    o_ref[0] = out.astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "dv", "interpret"))
+def mla_paged_pallas(
+    q,            # (B, C, H, Dk)  absorbed queries: latent part, then rope part
+    pool,         # (P, page_size, Dk)  latent pool, chunk rows already written
+    page_table,   # (B, max_pages) int32
+    lengths,      # (B,) int32   resident tokens incl. this chunk
+    starts,       # (B,) int32   absolute position of q[:, 0]
+    *,
+    scale: float,
+    dv: int,
+    interpret: bool = False,
+):
+    """Latent paged attention (absorbed MLA): every head of every query
+    token scores the pool's rows over all ``Dk`` columns, and P.V runs over
+    the first ``dv`` (the latent itself). Returns (B, C, H, dv).
+
+    The chunk's tokens and heads fold into one row axis, row c*H + h, so a
+    page is read once for all heads; grid (B, row blocks), each step
+    sweeping the row's pages in blocks of ``ppb`` with two buffers and
+    stopping at the resident length (or its block's last query), as
+    ``chunked_prefill_pallas`` does."""
+    B, C, H, Dk = q.shape
+    P, ps, _ = pool.shape
+    maxp = page_table.shape[1]
+    ppb, cb = mla_block_sizes(ps, maxp, C, H, Dk, dv, pool.dtype.itemsize, q.dtype.itemsize)
+    rows = cb * H
+    kernel = functools.partial(_mla_kernel, scale=scale, page_size=ps, max_pages=maxp,
+                               heads=H)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B, C // cb),
+        in_specs=[pl.BlockSpec((1, rows, Dk), lambda b, r, *_: (b, r, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, rows, dv), lambda b, r, *_: (b, r, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, ppb, ps, Dk), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, dv), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, C * H, dv), q.dtype),
+        interpret=pltpu.InterpretParams() if interpret else False,
+    )(page_table.reshape(-1).astype(jnp.int32), lengths.astype(jnp.int32),
+      starts.astype(jnp.int32), q.reshape(B, C * H, Dk), pool)
+    return out.reshape(B, C, H, dv)

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 from repro.kernels.backend import resolve
 from repro.kernels.paged_attention import kernel as _kernel
 from repro.kernels.paged_attention.ref import (chunked_prefill_reference,
+                                               mla_paged_reference,
                                                paged_attention_reference)
 
 
@@ -60,3 +61,20 @@ def chunked_prefill_attention(
         q, k_pages, v_pages, page_table, lengths, starts,
         scale=scale, softcap=softcap, window=window, interpret=interpret,
     )
+
+
+def mla_paged_attention(
+    q, pool, page_table, lengths, q_positions, *, scale: float, dv: int,
+    backend: str = "auto", interpret: bool | None = None,
+):
+    """Latent paged attention: absorbed queries q (B, C, H, Dk) at absolute
+    q_positions (B, C) attend causally over the latent pool (the chunk's own
+    rows included); the values are the pool's first ``dv`` columns. Row
+    positions must be contiguous, as for ``chunked_prefill_attention``."""
+    backend, interpret = resolve(backend, interpret)
+    if backend == "xla":
+        return mla_paged_reference(q, pool, page_table, lengths, q_positions,
+                                   scale=scale, dv=dv)
+    starts = q_positions[:, 0].astype(jnp.int32)
+    return _kernel.mla_paged_pallas(q, pool, page_table, lengths, starts,
+                                    scale=scale, dv=dv, interpret=interpret)

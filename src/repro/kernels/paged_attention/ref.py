@@ -7,6 +7,11 @@ Decode (one query token per sequence):
   page_table: (B, max_pages)    int32 physical page id per logical page
   lengths:    (B,)              valid kv entries per sequence (incl. current)
 
+Latent (absorbed MLA; one pool shared by every head):
+  q:           (B, S, H, Dk)   absorbed queries
+  pool:        (P, page_size, Dk)  each token's latent, then its rope key
+  values:      the pool's first ``dv`` columns
+
 Chunked prefill (a chunk of S query tokens per sequence, causal against the
 KV already resident in the pool — which includes the chunk's own KV, written
 by the caller before attending):
@@ -92,4 +97,22 @@ def chunked_prefill_reference(
     denom = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
     out = jnp.einsum("bhsk,bkhd->bshd", (p / denom).astype(jnp.float32),
                      v.astype(jnp.float32))
+    return out.astype(q.dtype)
+
+
+def mla_paged_reference(q, pool, page_table, lengths, q_positions, *, scale: float, dv: int):
+    """Gather-based oracle for latent paged attention. Returns (B, S, H, dv):
+    query token i of row b (every head) attends causally over the pool's
+    rows, scored over all Dk columns, with the first ``dv`` as values."""
+    B, maxp = page_table.shape
+    _, ps, Dk = pool.shape
+    kv = pool[page_table].reshape(B, maxp * ps, Dk)
+    s = jnp.einsum("bshd,bkd->bhsk", q, kv, preferred_element_type=jnp.float32) * scale
+    kv_pos = jnp.arange(maxp * ps)[None, None, :]
+    mask = (kv_pos < lengths[:, None, None]) & (kv_pos <= q_positions[:, :, None])
+    s = jnp.where(mask[:, None], s, -1e30)
+    p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.where(mask[:, None], p, 0.0)
+    denom = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+    out = jnp.einsum("bhsk,bkd->bshd", p / denom, kv[..., :dv].astype(jnp.float32))
     return out.astype(q.dtype)

@@ -102,19 +102,24 @@ def _write_paged(cache, k, v, positions, page_table):
     }
 
 
+def paged_slots(positions, page_table, valid, page_size: int):
+    """(physical page, slot in it) of every position of a chunk, flattened:
+    positions (B, S) absolute; valid (B, S). Invalid positions are routed to
+    the reserved null page 0 (the allocator never hands it out), so one
+    fixed-shape scatter serves ragged chunks."""
+    maxp = page_table.shape[1]
+    logical = jnp.clip(positions // page_size, 0, maxp - 1)
+    phys = jnp.where(valid, jnp.take_along_axis(page_table, logical, axis=1), 0)
+    slot = positions % page_size
+    return phys.reshape(-1), slot.reshape(-1)
+
+
 def _write_paged_chunk(cache, k, v, positions, page_table, valid):
     """Scatter a whole chunk's KV into the paged pool in one shot.
 
-    k/v (B, S, Hkv, hd); positions (B, S) absolute; valid (B, S). Invalid
-    positions are routed to the reserved null page 0 (the allocator never
-    hands it out), so one fixed-shape scatter serves ragged chunks."""
-    ps = cache["kp"].shape[2]
+    k/v (B, S, Hkv, hd); positions (B, S) absolute; valid (B, S)."""
     B, S = positions.shape
-    maxp = page_table.shape[1]
-    logical = jnp.clip(positions // ps, 0, maxp - 1)
-    phys = jnp.where(valid, jnp.take_along_axis(page_table, logical, axis=1), 0)
-    slot = positions % ps
-    pf, sf = phys.reshape(-1), slot.reshape(-1)
+    pf, sf = paged_slots(positions, page_table, valid, cache["kp"].shape[2])
     kf = k.reshape(B * S, *k.shape[2:]).astype(cache["kp"].dtype)
     vf = v.reshape(B * S, *v.shape[2:]).astype(cache["vp"].dtype)
     return {

@@ -1,11 +1,13 @@
 """Shared model-runtime context and small layer primitives."""
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, replace
-from typing import Any
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels.backend import resolve
 
@@ -55,16 +57,51 @@ def rmsnorm(x, w, eps: float = 1e-6):
     return ((x * jax.lax.rsqrt(var + eps)) * (w.astype(jnp.float32))).astype(dt)
 
 
-def rope(x, positions, theta: float):
-    """x: (B, S, H, hd); positions: (S,) or (B, S) absolute token positions."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor: 0.1 * mscale * ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(yarn, dim: int, theta: float) -> Tuple[int, int]:
+    """(low, high): the frequency pairs below ``low`` rotate more than
+    ``beta_fast`` times over the original context and keep their frequency;
+    those above ``high`` rotate less than ``beta_slow`` times and are
+    interpolated by ``factor``; a linear ramp blends the pairs between."""
+    def pair(rotations):
+        return dim * math.log(yarn.original_max_position_embeddings
+                              / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+    return (max(math.floor(pair(yarn.beta_fast)), 0),
+            min(math.ceil(pair(yarn.beta_slow)), dim - 1))
+
+
+def yarn_freqs(yarn, dim: int, theta: float) -> np.ndarray:
+    """The ``dim // 2`` rope frequencies under YaRN (float32)."""
+    half = dim // 2
+    base = theta ** (-np.arange(half, dtype=np.float64) / half)
+    low, high = yarn_correction_range(yarn, dim, theta)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return (base * (1.0 - ramp) + base / yarn.factor * ramp).astype(np.float32)
+
+
+def rope(x, positions, theta: float, yarn: Optional[Any] = None):
+    """x: (B, S, H, hd); positions: (S,) or (B, S) absolute token positions.
+    ``yarn`` (a ``YarnConfig``) scales the frequencies and cos/sin as YaRN does."""
     B, S, H, hd = x.shape
     half = hd // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if yarn is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freqs = jnp.asarray(yarn_freqs(yarn, hd, theta))
     if positions.ndim == 1:
         positions = positions[None, :]
     ang = positions.astype(jnp.float32)[:, :, None] * freqs  # (B,S,half)
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
+    if yarn is not None:
+        m = (yarn_mscale(yarn.factor, yarn.mscale)
+             / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
 

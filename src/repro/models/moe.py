@@ -38,12 +38,15 @@ def _round_up(x: int, m: int) -> int:
 # --------------------------------------------------------------------------
 # Router
 # --------------------------------------------------------------------------
-def router_topk(xf, router_w, k: int):
-    """xf (T, d) -> (topw (T,k) f32, topi (T,k) i32, aux scalar)."""
+def router_topk(xf, router_w, k: int, norm: bool = True):
+    """xf (T, d) -> (topw (T,k) f32, topi (T,k) i32, aux scalar). ``norm``
+    renormalizes the k softmax weights to sum 1 (``MoEConfig.norm_topk``);
+    without it they stay the full softmax's probabilities."""
     logits = jnp.einsum("td,de->te", xf.astype(jnp.float32), router_w.astype(jnp.float32))
     probs = jax.nn.softmax(logits, axis=-1)
     topw, topi = jax.lax.top_k(probs, k)
-    topw = topw / jnp.maximum(jnp.sum(topw, axis=-1, keepdims=True), 1e-9)
+    if norm:
+        topw = topw / jnp.maximum(jnp.sum(topw, axis=-1, keepdims=True), 1e-9)
     E = probs.shape[-1]
     # Switch-style load-balance loss: E * sum_e f_e * P_e
     f = jnp.mean(jax.nn.one_hot(topi, E, dtype=jnp.float32).sum(axis=1), axis=0) / k
@@ -107,7 +110,7 @@ def moe_dropless(p, xf, cfg: ModelConfig, ctx: RunCtx, live=None):
     m = cfg.moe
     E, K = m.num_experts, m.top_k
     T, d = xf.shape
-    topw, topi, aux = router_topk(xf, p["router"], K)
+    topw, topi, aux = router_topk(xf, p["router"], K, m.norm_topk)
     e = topi.reshape(-1)
     tok = jnp.arange(T * K) // K
     order = jnp.argsort(e)
@@ -133,7 +136,7 @@ def moe_capacity(p, xf, cfg: ModelConfig, ctx: RunCtx, live=None):
     E, K = m.num_experts, m.top_k
     T, d = xf.shape
     cap = _round_up(max(int(math.ceil(T * K / E * m.capacity_factor)), 8), 8)
-    topw, topi, aux = router_topk(xf, p["router"], K)
+    topw, topi, aux = router_topk(xf, p["router"], K, m.norm_topk)
     ebuf, info = capacity_dispatch(xf, topi, E, cap)
     ye = expert_ffn_dense(ebuf, p["wg"], p["wu"], p["wd"])
     y = capacity_combine(ye, info, topw)
@@ -152,7 +155,7 @@ def _moe_local_tp(xf, router_w, wg, wu, wd, shared, cfg, tp_axis, cf):
     E, K = m.num_experts, m.top_k
     T = xf.shape[0]
     cap = _round_up(max(int(math.ceil(T * K / E * cf)), 8), 8)
-    topw, topi, aux = router_topk(xf, router_w, K)
+    topw, topi, aux = router_topk(xf, router_w, K, m.norm_topk)
     ebuf, info = capacity_dispatch(xf, topi, E, cap)
     h1 = jnp.einsum("ecd,edf->ecf", ebuf, wg)
     h2 = jnp.einsum("ecd,edf->ecf", ebuf, wu)
@@ -188,7 +191,7 @@ def _moe_local_ep(xf, router_w, wg, wu, wd, shared, cfg, ep_axis, tp_axis, cf,
     dp = jax.lax.axis_size(ep_axis)
     E_l = E // dp
     T, d = xf.shape
-    topw, topi, aux = router_topk(xf, router_w, K)
+    topw, topi, aux = router_topk(xf, router_w, K, m.norm_topk)
     e = topi.reshape(-1)                                          # (TK,) global expert
     tok = jnp.arange(T * K) // K
 

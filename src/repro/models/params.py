@@ -49,6 +49,23 @@ def _attn_specs(cfg: ModelConfig, R: int) -> Dict[str, Any]:
     return s
 
 
+def _mla_specs(cfg: ModelConfig, R: int) -> Dict[str, Any]:
+    """Latent attention without a query LoRA (``models/mla.py``): the query,
+    the joint latent + rope-key down-projection, the latent's norm, the
+    key and value up-projections (kept apart, so that the absorbed form
+    needs nothing prepared at load) and the output."""
+    d, H, m = cfg.d_model, cfg.n_heads, cfg.mla
+    r = m.kv_lora_rank
+    return {
+        "wq": ParamSpec((R, d, H, m.q_head_dim), ("layers", "embed", "heads", "head_dim")),
+        "wkv_a": ParamSpec((R, d, m.cache_dim), ("layers", "embed", None)),
+        "kv_norm": {"w": ParamSpec((R, r), ("layers", None), "ones")},
+        "wk_b": ParamSpec((R, r, H, m.qk_nope_head_dim), ("layers", "latent", "heads", "head_dim")),
+        "wv_b": ParamSpec((R, r, H, m.v_head_dim), ("layers", "latent", "heads", "head_dim")),
+        "wo": ParamSpec((R, H, m.v_head_dim, d), ("layers", "heads", "head_dim", "embed")),
+    }
+
+
 def _dense_mlp_specs(cfg: ModelConfig, R: int, d_ff: int) -> Dict[str, Any]:
     d = cfg.d_model
     return {
@@ -98,6 +115,8 @@ def _layer_specs(cfg: ModelConfig, kind: str, is_moe: bool, R: int, *, cross: bo
     spec: Dict[str, Any] = {"ln1": ParamSpec((R, d), ("layers", "embed"), "ones")}
     if kind == "M":
         spec["ssm"] = _ssm_specs(cfg, R)
+    elif cfg.mla is not None:
+        spec["attn"] = _mla_specs(cfg, R)
     else:
         spec["attn"] = _attn_specs(cfg, R)
     if cross:
@@ -169,7 +188,7 @@ def _init_leaf(spec: ParamSpec, key, dtype):
     # For (R, in, out...) matrices we take dim 1 (or dim 0 for 2D).
     fan_in = shape[-2] if len(shape) >= 2 else shape[0]
     if len(shape) == 4:            # (R, in, h, hd) or (R, E, in, out)
-        fan_in = shape[1] if spec.logical[1] == "embed" else shape[2]
+        fan_in = shape[1] if spec.logical[1] in ("embed", "latent") else shape[2]
     std = 1.0 / math.sqrt(max(fan_in, 1))
     return (std * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
 

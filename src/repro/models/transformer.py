@@ -22,6 +22,7 @@ from repro.models import params as params_lib
 from repro.models.attention import attention_sublayer
 from repro.models.common import RunCtx, dense_mlp, rmsnorm, shard_act
 from repro.models.mamba import mamba_sublayer
+from repro.models.mla import mla_sublayer, pool_width
 from repro.models.moe import moe_sublayer
 
 
@@ -60,11 +61,12 @@ class LM:
             if new_c is not None:
                 new_c["ssm"] = cm
         else:
-            sub, ca = attention_sublayer(
-                p["attn"], h, ctx, cfg, kind,
-                cache=c.get("attn") if c else None,
-                positions=positions, page_table=page_table, lengths=lengths,
-                chunk=chunk)
+            kw = dict(cache=c.get("attn") if c else None, positions=positions,
+                      page_table=page_table, lengths=lengths, chunk=chunk)
+            if cfg.mla is not None:
+                sub, ca = mla_sublayer(p["attn"], h, ctx, cfg, **kw)
+            else:
+                sub, ca = attention_sublayer(p["attn"], h, ctx, cfg, kind, **kw)
             if new_c is not None and ca is not None:
                 new_c["attn"] = ca
         x = x + shard_act(sub, seq_sharded)
@@ -400,7 +402,9 @@ class LM:
 
         kind="dense": per-layer ring buffers (window layers get W=window).
         kind="paged": per-layer physical page pools (engine supplies
-                      page_table/lengths at decode time).
+                      page_table/lengths at decode time); a latent layer
+                      holds one pool "lat" (R, P, page_size, latent + rope
+                      padded to whole lane tiles, ``mla.pool_width``).
         """
         cfg = self.cfg
         Hkv, hd = cfg.n_kv_heads, cfg.head_dim
@@ -417,6 +421,12 @@ class LM:
                         "conv": jnp.zeros((R, B, cfg.d_inner + 2 * ssm.n_groups * ssm.d_state,
                                            ssm.d_conv - 1), dtype),
                     }
+                elif cfg.mla is not None:
+                    if kind != "paged":
+                        raise NotImplementedError("latent attention caches in the paged pool only")
+                    # one pool of each token's latent and rope key, shared by all heads
+                    c["attn"] = {"lat": jnp.zeros((R, num_pages, page_size, pool_width(cfg)),
+                                                  dtype)}
                 else:
                     W = min(max_seq, cfg.sliding_window) if (k == "L" and cfg.sliding_window) else max_seq
                     if kind == "paged":

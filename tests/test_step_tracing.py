@@ -149,8 +149,8 @@ def test_experts_counter_recounts_the_routing_of_live_tokens(moe_stack, monkeypa
     seen = []
     route = moe_lib.router_topk
 
-    def recording(xf, router_w, k):
-        topw, topi, aux = route(xf, router_w, k)
+    def recording(xf, router_w, k, norm=True):
+        topw, topi, aux = route(xf, router_w, k, norm)
         jax.debug.callback(lambda t: seen.append(np.asarray(t)), topi, ordered=True)
         return topw, topi, aux
 

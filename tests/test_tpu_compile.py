@@ -15,7 +15,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.backend import compiled_kernels
 from repro.kernels.moe_gmm.kernel import gmm_pallas
-from repro.kernels.paged_attention.kernel import chunked_prefill_pallas
+from repro.kernels.paged_attention.kernel import chunked_prefill_pallas, mla_paged_pallas
 
 BF16, I32 = jnp.bfloat16, jnp.int32
 # Mixtral-8x7B: 32 query / 8 kv heads of 128, 8 experts, d_model 4096,
@@ -68,3 +68,18 @@ def test_gmm_compiles_for_v5e(one_chip, k, n):
     compiled = _compile(lambda x, w, te: gmm_pallas(x, w, te), one_chip,
                         ((rows, k), BF16), ((E, k, n), BF16), ((rows // 128,), I32))
     assert compiled_kernels(compiled.as_text()) == ["gmm_pallas"]
+
+
+# DeepSeek-V2-Lite's latent sweeps (bench/configs/deepseek-v2-lite-pp4.json):
+# 16 heads over one pool of 576 values a token in 640-lane rows, values the
+# first 512; the 12-slot decode and the 2-row prefill pack over max_seq 24576
+@pytest.mark.parametrize("rows,chunk", [(12, 1), (CHUNK_ROWS, CHUNK)],
+                         ids=["dsv2lite_decode", "dsv2lite_prefill_pack"])
+def test_mla_paged_compiles_for_v5e(one_chip, rows, chunk):
+    maxp = 24576 // PAGE
+    compiled = _compile(
+        lambda q, pool, pt, ln, st: mla_paged_pallas(q, pool, pt, ln, st, scale=0.114721,
+                                                     dv=512),
+        one_chip, ((rows, chunk, 16, 640), BF16), ((PAGES, PAGE, 640), BF16),
+        ((rows, maxp), I32), ((rows,), I32), ((rows,), I32))
+    assert compiled_kernels(compiled.as_text()) == ["mla_paged_pallas"]
