@@ -1,8 +1,11 @@
 """Grouped matmul op: builds the tile-aligned layout and dispatches.
 
-``gmm(x, w, group_sizes)`` computes ``out[m] = x[m] @ w[expert_of(m)]`` for
-rows sorted by expert. The wrapper scatters rows into a tile-aligned padded
-buffer (each expert starts on a ``block_m`` boundary), runs the kernel (or an
+``gmm(x, w, group_sizes, layer)`` computes
+``out[m] = x[m] @ w[layer, expert_of(m)]`` for rows sorted by expert, where
+``w`` stacks every layer's experts: the kernel reads layer ``layer``'s blocks
+in place. A caller holding one layer's ``(E, K, N)`` passes ``w[None]`` and
+layer 0. The wrapper scatters rows into a tile-aligned padded buffer (each
+expert starts on a ``block_m`` boundary), runs the kernel (or an
 einsum-select xla fallback for CPU), and gathers the real rows back. Static
 worst-case padding: Mp = M + E*block_m.
 """
@@ -21,8 +24,9 @@ from repro.kernels.moe_gmm.ref import expert_of_rows, gmm_reference
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "backend", "interpret"))
 def gmm(
     x,             # (M, K) rows sorted by expert
-    w,             # (E, K, N)
+    w,             # (L, E, K, N)
     group_sizes,   # (E,) int32, sum == M
+    layer,         # int32 scalar: the layer of ``w`` to read
     *,
     block_m: int = 128,
     block_n: int = 128,
@@ -30,10 +34,10 @@ def gmm(
     interpret: bool | None = None,
 ):
     M, K = x.shape
-    E, _, N = w.shape
+    _, E, _, N = w.shape
     backend, interpret = resolve(backend, interpret)
     if backend == "xla":
-        return gmm_reference(x, w, group_sizes)
+        return gmm_reference(x, w, group_sizes, layer)
 
     bn = min(block_n, N)
     # --- tile-aligned scatter ------------------------------------------------
@@ -51,6 +55,7 @@ def gmm(
     tile_expert = jnp.minimum(tile_expert, E - 1).astype(jnp.int32)
 
     out_pad = _kernel.gmm_pallas(
-        x_pad, w, tile_expert, block_m=block_m, block_n=bn, interpret=interpret
+        x_pad, w, tile_expert, jnp.reshape(layer, (1,)).astype(jnp.int32),
+        block_m=block_m, block_n=bn, interpret=interpret,
     )
     return out_pad[dst]

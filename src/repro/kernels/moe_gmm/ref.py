@@ -1,9 +1,10 @@
 """Oracle for the grouped (ragged) expert matmul.
 
 x:           (M, K)  rows sorted by expert id
-w:           (E, K, N)
+w:           (L, E, K, N)  every layer's experts, stacked
 group_sizes: (E,)    sum == M
-out[m] = x[m] @ w[expert_of(m)]
+layer:       int32 scalar
+out[m] = x[m] @ w[layer, expert_of(m)]
 """
 from __future__ import annotations
 
@@ -16,9 +17,9 @@ def expert_of_rows(group_sizes, M):
     return jnp.searchsorted(ends, jnp.arange(M), side="right")
 
 
-def gmm_reference(x, w, group_sizes):
+def gmm_reference(x, w, group_sizes, layer):
     M, K = x.shape
-    E, _, N = w.shape
+    w = w[layer]
     eid = expert_of_rows(group_sizes, M)
     # O(E * M * K * N) dense oracle: compute every expert for every row, select.
     all_out = jnp.einsum("mk,ekn->emn", x, w, preferred_element_type=jnp.float32)

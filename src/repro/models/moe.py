@@ -16,6 +16,10 @@ Four execution strategies (RunCtx.moe_strategy):
                  visible to the roofline.
 
 All strategies share the router and are validated against each other.
+Each takes the routed experts' ``wg``/``wu``/``wd`` as ``(L, E, ...)`` stacks
+of every layer of the scan, with the layer to run: dropless hands both to the
+kernel, which reads the layer's blocks in place; the others slice the layer
+out themselves.
 """
 from __future__ import annotations
 
@@ -106,7 +110,7 @@ def _shared_ffn(p_shared, xf, act_name):
 # --------------------------------------------------------------------------
 # Strategy: dropless (sort + ragged gmm) — serving engine path
 # --------------------------------------------------------------------------
-def moe_dropless(p, xf, cfg: ModelConfig, ctx: RunCtx, live=None):
+def moe_dropless(p, xf, cfg: ModelConfig, ctx: RunCtx, layer, live=None):
     m = cfg.moe
     E, K = m.num_experts, m.top_k
     T, d = xf.shape
@@ -117,10 +121,10 @@ def moe_dropless(p, xf, cfg: ModelConfig, ctx: RunCtx, live=None):
     xs = xf[tok[order]]
     gs = jnp.bincount(e, length=E).astype(jnp.int32)
     kw = dict(backend=ctx.attn_backend, interpret=ctx.interpret)
-    h1 = gmm(xs, p["wg"], gs, **kw)
-    h2 = gmm(xs, p["wu"], gs, **kw)
+    h1 = gmm(xs, p["wg"], gs, layer, **kw)
+    h2 = gmm(xs, p["wu"], gs, layer, **kw)
     ys = gmm((jax.nn.silu(h1.astype(jnp.float32)) * h2.astype(jnp.float32)).astype(xs.dtype),
-             p["wd"], gs, **kw)
+             p["wd"], gs, layer, **kw)
     w_flat = topw.reshape(-1)[order]
     y = jnp.zeros((T, d), jnp.float32).at[tok[order]].add(ys.astype(jnp.float32) * w_flat[:, None])
     if "shared" in p:
@@ -131,14 +135,14 @@ def moe_dropless(p, xf, cfg: ModelConfig, ctx: RunCtx, live=None):
 # --------------------------------------------------------------------------
 # Strategy: capacity (pure local)
 # --------------------------------------------------------------------------
-def moe_capacity(p, xf, cfg: ModelConfig, ctx: RunCtx, live=None):
+def moe_capacity(p, xf, cfg: ModelConfig, ctx: RunCtx, layer, live=None):
     m = cfg.moe
     E, K = m.num_experts, m.top_k
     T, d = xf.shape
     cap = _round_up(max(int(math.ceil(T * K / E * m.capacity_factor)), 8), 8)
     topw, topi, aux = router_topk(xf, p["router"], K, m.norm_topk)
     ebuf, info = capacity_dispatch(xf, topi, E, cap)
-    ye = expert_ffn_dense(ebuf, p["wg"], p["wu"], p["wd"])
+    ye = expert_ffn_dense(ebuf, p["wg"][layer], p["wu"][layer], p["wd"][layer])
     y = capacity_combine(ye, info, topw)
     if "shared" in p:
         y = y + _shared_ffn(p["shared"], xf, cfg.act).astype(jnp.float32)
@@ -245,18 +249,20 @@ def _moe_local_ep(xf, router_w, wg, wu, wd, shared, cfg, ep_axis, tp_axis, cf,
     return y.astype(xf.dtype), aux
 
 
-def moe_sublayer(p: Dict[str, Any], h, cfg: ModelConfig, ctx: RunCtx,
+def moe_sublayer(p: Dict[str, Any], h, cfg: ModelConfig, ctx: RunCtx, layer,
                  live=None) -> Tuple[Any, Any, Any]:
-    """h: (B, S, d) normed input; live (B, S) marks the tokens the experts
-    counter counts (None: all). Returns (out (B,S,d), aux loss scalar,
-    experts reached by live tokens; None on the sharded strategies)."""
+    """h: (B, S, d) normed input; ``p``'s ``wg``/``wu``/``wd`` stack the
+    routed experts of every layer, ``layer`` (int32 scalar) the one to run;
+    live (B, S) marks the tokens the experts counter counts (None: all).
+    Returns (out (B,S,d), aux loss scalar, experts reached by live tokens;
+    None on the sharded strategies)."""
     B, S, d = h.shape
     xf = h.reshape(B * S, d)
     live = None if live is None else live.reshape(B * S)
     strategy = ctx.moe_strategy
     if strategy in ("dropless", "capacity") or ctx.mesh is None:
         fn = moe_dropless if strategy == "dropless" else moe_capacity
-        y, aux, reached = fn(p, xf, cfg, ctx, live)
+        y, aux, reached = fn(p, xf, cfg, ctx, layer, live)
         return y.reshape(B, S, d), aux, reached
 
     from jax.experimental.shard_map import shard_map
@@ -303,5 +309,5 @@ def moe_sublayer(p: Dict[str, Any], h, cfg: ModelConfig, ctx: RunCtx,
 
     y, aux = shard_map(
         local, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False,
-    )(h, p["router"], p["wg"], p["wu"], p["wd"], shared)
+    )(h, p["router"], p["wg"][layer], p["wu"][layer], p["wd"][layer], shared)
     return y, aux, None

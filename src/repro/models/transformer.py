@@ -25,6 +25,32 @@ from repro.models.mamba import mamba_sublayer
 from repro.models.mla import mla_sublayer, pool_width
 from repro.models.moe import moe_sublayer
 
+_ROUTED = ("wg", "wu", "wd")
+
+
+def _split_routed(gp):
+    """Take the routed experts out of a group's per-position params: returns
+    (the rest, per position its ``wg``/``wu``/``wd`` stacks or None). The
+    layer loop slices the rest a layer at a time; the stacks reach the experts
+    whole, with the layer's index, so the kernel reads the layer's blocks in
+    place and no layer's experts are copied out of the stack."""
+    rest, stacks = [], []
+    for pp in gp:
+        moe = pp.get("moe")
+        if moe is None:
+            rest.append(pp)
+            stacks.append(None)
+        else:
+            rest.append({**pp, "moe": {k: v for k, v in moe.items() if k not in _ROUTED}})
+            stacks.append({k: moe[k] for k in _ROUTED})
+    return rest, stacks
+
+
+def _with_routed(p_sl, stacks):
+    """One layer's params (``p_sl``, per position) with the routed stacks put back."""
+    return [pp if st is None else {**pp, "moe": {**pp["moe"], **st}}
+            for pp, st in zip(p_sl, stacks)]
+
 
 class LM:
     def __init__(self, cfg: ModelConfig):
@@ -41,8 +67,10 @@ class LM:
         return params_lib.abstract_params(self.cfg, dtype)
 
     # ------------------------------------------------------------------ layers
-    def _apply_layer(self, p, x, c, *, kind: str, ctx: RunCtx,
+    def _apply_layer(self, p, x, c, *, kind: str, ctx: RunCtx, layer,
                      positions, memory, page_table, lengths, chunk=None):
+        """``layer``: this layer's index in its group's stacks, where ``p``'s
+        routed experts are the whole stacks (``_split_routed``)."""
         cfg = self.cfg
         aux = jnp.zeros((), jnp.float32)
         reached = None
@@ -82,7 +110,7 @@ class LM:
 
         if "moe" in p:
             h2 = rmsnorm(x, p["ln2"], cfg.rms_eps)
-            mo, aux, reached = moe_sublayer(p["moe"], h2, cfg, ctx,
+            mo, aux, reached = moe_sublayer(p["moe"], h2, cfg, ctx, layer,
                                             live=chunk["valid"] if chunk is not None else None)
             x = x + shard_act(mo, seq_sharded)
         elif "mlp" in p:
@@ -101,7 +129,7 @@ class LM:
         experts: List[Any] = []
         new_groups_cache: List[Any] = []
         for gi, g in enumerate(layer_groups):
-            gp = groups_params[gi]["layers"]
+            gp, stacks = _split_routed(groups_params[gi]["layers"])
             gc = cache["groups"][gi] if cache is not None else None
             pattern = kinds_override or g.pattern
 
@@ -110,7 +138,7 @@ class LM:
                 # loop bodies once, so the cost model must not use scan)
                 new_gc = gc
                 for r in range(g.repeats):
-                    p_sl = jax.tree.map(lambda x: x[r], gp)
+                    p_sl = _with_routed(jax.tree.map(lambda x: x[r], gp), stacks)
                     c_sl = (jax.tree.map(lambda x: x[r], new_gc)
                             if new_gc is not None else None)
                     for pos, kind in enumerate(pattern):
@@ -118,7 +146,7 @@ class LM:
 
                         def run_layer(pp, xx, cc, kind=kind):
                             return self._apply_layer(
-                                pp, xx, cc, kind=kind, ctx=ctx,
+                                pp, xx, cc, kind=kind, ctx=ctx, layer=r,
                                 positions=positions, memory=memory,
                                 page_table=page_table, lengths=lengths,
                                 chunk=chunk)
@@ -139,15 +167,16 @@ class LM:
                 new_groups_cache.append(new_gc)
                 continue
 
-            def body(carry, xs, pattern=pattern):
+            def body(carry, xs, pattern=pattern, stacks=stacks):
                 xcur = carry
-                p_sl, c_sl = xs
+                layer, p_sl, c_sl = xs
+                p_sl = _with_routed(p_sl, stacks)
                 auxes = jnp.zeros((), jnp.float32)
                 new_cs, reached_by_pos = [], []
                 for pos, kind in enumerate(pattern):
                     cpos = c_sl[pos] if c_sl is not None else None
                     xcur, cnew, aux, reached = self._apply_layer(
-                        p_sl[pos], xcur, cpos, kind=kind, ctx=ctx,
+                        p_sl[pos], xcur, cpos, kind=kind, ctx=ctx, layer=layer,
                         positions=positions, memory=memory,
                         page_table=page_table, lengths=lengths, chunk=chunk)
                     # residual stream seq-sharded between layers under the
@@ -162,7 +191,7 @@ class LM:
 
             if ctx.remat:
                 body = jax.checkpoint(body)
-            xs = (gp, gc)
+            xs = (jnp.arange(g.repeats, dtype=jnp.int32), gp, gc)
             x, (stacked_cache, auxes, reached) = jax.lax.scan(body, x, xs)
             new_groups_cache.append(stacked_cache)
             aux_total = aux_total + jnp.sum(auxes)

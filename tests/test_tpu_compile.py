@@ -7,6 +7,7 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and it keeps it until it exits.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,8 +20,9 @@ from repro.kernels.paged_attention.kernel import chunked_prefill_pallas, mla_pag
 
 BF16, I32 = jnp.bfloat16, jnp.int32
 # Mixtral-8x7B: 32 query / 8 kv heads of 128, 8 experts, d_model 4096,
-# d_ff 14336; the engine's pool in 16-token pages (chip_smoke.ENGINE)
-H, HKV, D, E, DM, DFF = 32, 8, 128, 8, 4096, 14336
+# d_ff 14336, 4 layers a stage (bench/configs/mixtral-8x7b-pp8.json); the
+# engine's pool in 16-token pages (chip_smoke.ENGINE)
+H, HKV, D, E, DM, DFF, LAYERS = 32, 8, 128, 8, 4096, 14336, 4
 PAGES, PAGE, MAXP, SLOTS, CHUNK_ROWS, CHUNK = 1024, 16, 68, 8, 2, 256
 
 
@@ -62,12 +64,48 @@ def test_chunked_prefill_compiles_for_v5e(one_chip, rows, chunk, h, hkv, maxp):
     assert compiled_kernels(compiled.as_text()) == ["chunked_prefill_pallas"]
 
 
+GMM_ROWS = (CHUNK_ROWS * CHUNK * 2 // 128 + E) * 128     # top-2 rows, tile-padded
+
+
+# the experts of every layer of a stage, stacked, read at one layer's index
 @pytest.mark.parametrize("k,n", [(DM, DFF), (DFF, DM)], ids=["gate_up", "down"])
 def test_gmm_compiles_for_v5e(one_chip, k, n):
-    rows = (CHUNK_ROWS * CHUNK * 2 // 128 + E) * 128     # top-2 rows, tile-padded
-    compiled = _compile(lambda x, w, te: gmm_pallas(x, w, te), one_chip,
-                        ((rows, k), BF16), ((E, k, n), BF16), ((rows // 128,), I32))
+    compiled = _compile(lambda x, w, te, layer: gmm_pallas(x, w, te, layer), one_chip,
+                        ((GMM_ROWS, k), BF16), ((LAYERS, E, k, n), BF16),
+                        ((GMM_ROWS // 128,), I32), ((1,), I32))
     assert compiled_kernels(compiled.as_text()) == ["gmm_pallas"]
+
+
+# one layer's experts (bf16[8,4096,14336] or bf16[8,14336,4096], with a
+# leading 1 or not) as the result of an instruction that a slice or a copy
+# names or is: `%constant_dynamic-slice_fusion.6 = bf16[1,8,4096,14336]{...}
+# fusion(...)`, `%dynamic_slice.11 = bf16[...] dynamic-slice(...)`, or a
+# `copy-start` whose tuple holds one
+_EXPERTS = r"bf16\[(1,)?8,(4096,14336|14336,4096)\]"
+EXPERT_COPY = re.compile(rf"%[\w.-]*(slice|copy)[\w.-]* = \(?{_EXPERTS}"
+                         rf"|= {_EXPERTS}\S* [\w-]*(slice|copy)")
+
+
+def test_gmm_layer_scan_reads_stacks_in_place(one_chip):
+    """A two-layer scan of the three expert matmuls, the stacks closed over
+    and the layer's index handed to the kernel: no layer's experts (0.94 GB
+    each) are sliced or copied out of a stack, and the program's temporaries
+    stay far below one of them."""
+    def two_layers(x, wg, wu, wd, te):
+        def body(h, layer):
+            layer = layer[None]
+            g = gmm_pallas(h, wg, te, layer).astype(jnp.float32)
+            u = gmm_pallas(h, wu, te, layer).astype(jnp.float32)
+            return gmm_pallas((jax.nn.silu(g) * u).astype(BF16), wd, te, layer), None
+        return jax.lax.scan(body, x, jnp.arange(2, dtype=I32))[0]
+
+    compiled = _compile(two_layers, one_chip, ((GMM_ROWS, DM), BF16),
+                        ((2, E, DM, DFF), BF16), ((2, E, DM, DFF), BF16),
+                        ((2, E, DFF, DM), BF16), ((GMM_ROWS // 128,), I32))
+    hlo = compiled.as_text()
+    assert compiled_kernels(hlo) == ["gmm_pallas"] * 3
+    assert not [line for line in hlo.splitlines() if EXPERT_COPY.search(line)]
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
 
 
 # DeepSeek-V2-Lite's latent sweeps (bench/configs/deepseek-v2-lite-pp4.json):
