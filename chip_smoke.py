@@ -140,8 +140,8 @@ def sequence_logits(model, params, ctx, tokens, engine_kw) -> list:
     C, ps, max_seq = engine_kw["prefill_chunk"], engine_kw["page_size"], engine_kw["max_seq"]
     maxp = -(-max_seq // ps)
     run = _chunk_program(model, ctx)
-    pool = model.init_cache(1, max_seq, engine_kw["cache_dtype"], kind="paged",
-                            page_size=ps, num_pages=maxp + 1)
+    pool = model.init_cache(1, max_seq, engine_kw["cache_dtype"], page_size=ps,
+                            num_pages=maxp + 1)
     pt = np.arange(1, maxp + 1, dtype=np.int32)[None]
     out, fed = [], 0
     while fed < len(tokens) - 1:

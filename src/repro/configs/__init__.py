@@ -9,21 +9,17 @@ import dataclasses
 from typing import Dict, List
 
 from repro.configs.base import (  # noqa: F401 (re-export)
-    DECODE_32K,
     EncoderConfig,
     LayerGroup,
-    LONG_500K,
     MLAConfig,
     ModelConfig,
     MoEConfig,
-    PREFILL_32K,
     ShapeConfig,
     SHAPES,
     SSMConfig,
     TRAIN_4K,
     VisionConfig,
     YarnConfig,
-    shape_applicable,
 )
 
 _MODULES = {

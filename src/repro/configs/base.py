@@ -1,8 +1,8 @@
 """Configuration dataclasses for the repro framework.
 
-Every architecture is described by a ``ModelConfig``; every workload cell by a
-``ShapeConfig``. Configs are plain frozen dataclasses so they hash, print, and
-serialize cleanly.
+Every architecture is described by a ``ModelConfig``; every dry-run cell's
+training shape by a ``ShapeConfig``. Configs are plain frozen dataclasses so
+they hash, print, and serialize cleanly.
 """
 from __future__ import annotations
 
@@ -154,14 +154,6 @@ class ModelConfig:
         return all(c == "M" for g in self.layer_groups for c in g.pattern)
 
     @property
-    def has_subquadratic_path(self) -> bool:
-        """True if the arch can serve 500k context: attention-free, hybrid, or
-        sliding-window on a fraction of layers (bounded-cache local attention
-        + mesh-sharded global cache)."""
-        chars = [c for g in self.layer_groups for c in g.pattern]
-        return any(c in ("M", "L") for c in chars)
-
-    @property
     def d_inner(self) -> int:
         return self.ssm.expand * self.d_model if self.ssm else 0
 
@@ -184,28 +176,12 @@ class ModelConfig:
 
 @dataclass(frozen=True)
 class ShapeConfig:
+    """A training step's shape for the dry-run: sequence length and global
+    batch."""
     name: str
     seq_len: int
     global_batch: int
-    kind: str                      # "train" | "prefill" | "decode"
-
-    @property
-    def is_serve(self) -> bool:
-        return self.kind in ("prefill", "decode")
 
 
-TRAIN_4K = ShapeConfig("train_4k", 4_096, 256, "train")
-PREFILL_32K = ShapeConfig("prefill_32k", 32_768, 32, "prefill")
-DECODE_32K = ShapeConfig("decode_32k", 32_768, 128, "decode")
-LONG_500K = ShapeConfig("long_500k", 524_288, 1, "decode")
-SHAPES = {s.name: s for s in (TRAIN_4K, PREFILL_32K, DECODE_32K, LONG_500K)}
-
-
-def shape_applicable(model: ModelConfig, shape: ShapeConfig) -> Tuple[bool, str]:
-    """(runnable, reason-if-skipped). long_500k is skipped for pure
-    full-attention archs per the assignment; see DESIGN.md §4."""
-    if shape.name == "long_500k" and not model.has_subquadratic_path:
-        return False, "pure full-attention arch: 524k context not deployable (skip per DESIGN.md)"
-    if shape.is_serve and model.mla is not None:
-        return False, "latent attention is served through the paged pool only (no dense cache)"
-    return True, ""
+TRAIN_4K = ShapeConfig("train_4k", 4_096, 256)
+SHAPES = {s.name: s for s in (TRAIN_4K,)}

@@ -184,7 +184,7 @@ class InferenceEngine:
             tracer=tracer)
         with jax.default_device(self.device):
             self.cache = self._put(model.init_cache(
-                cfg.max_slots, cfg.max_seq, cfg.cache_dtype, kind="paged",
+                cfg.max_slots, cfg.max_seq, cfg.cache_dtype,
                 page_size=cfg.page_size, num_pages=cfg.num_pages))
             self._key = self._put(jax.random.PRNGKey(cfg.seed))
         self.kv_bytes_per_token = kv_bytes_per_token(self.cache, cfg.num_pages * cfg.page_size)

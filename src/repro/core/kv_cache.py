@@ -2,7 +2,7 @@
 with refcounted pages, copy-on-write, and an automatic prefix cache.
 
 The device side is a global physical page pool per layer (see
-``LM.init_cache(kind="paged")`` and the Pallas paged_attention kernel); this
+``LM.init_cache`` and the Pallas ``chunked_prefill_pallas`` kernel); this
 module owns the *allocator*: free-page list, per-slot page tables, refcounts,
 the LRU pool of retired-but-cached pages, and the capacity queries the
 scheduler's max-utilization policy needs.

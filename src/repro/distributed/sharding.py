@@ -1,11 +1,11 @@
 """Logical-axis sharding rules with divisibility fallback.
 
-Every parameter/cache/activation dim carries a *logical* axis name; a RuleSet
+Every parameter/activation dim carries a *logical* axis name; a RuleSet
 maps logical names to ordered candidate mesh-axis assignments. A mesh axis is
 assigned to a dim only if (a) it exists in the mesh, (b) it is not already
 used by another dim of the same tensor, and (c) its size divides the dim.
 Assignment order follows per-name priority (e.g. kv_heads outranks kv_seq, so
-a GQA cache shards heads first and falls back to sequence sharding only when
+a GQA tensor shards heads first and falls back to sequence sharding only when
 the head count doesn't divide — the flash-decode style layout).
 
 This is how qwen's kv=2 ends up replicated across model=16 while gemma2's
@@ -74,8 +74,7 @@ def make_rules(mode: str = "serve", moe: str = "ep", *, multi_pod: bool = False,
         "vocab": ((t,),),
         "experts": ((e,),) if moe == "ep" else (),
         # kv_seq: fallback when kv_heads can't take the tensor axis; on the
-        # factored Exp4 mesh it may also spill onto the expert axis (decode
-        # attention handles seq-sharded caches via softmax-combine collectives)
+        # factored Exp4 mesh it may also spill onto the expert axis
         "kv_seq": ((t,), (e,)) if expert_axis else ((t,),),
         "ssm_proj": ((t,),),
         "ssm_inner": ((t,),),
@@ -159,38 +158,6 @@ def param_partition_specs(spec_tree, mesh: Mesh, ruleset: RuleSet):
         lambda s: partition_spec(s.shape, s.logical, mesh, ruleset),
         spec_tree, is_leaf=lambda x: isinstance(x, ParamSpec),
     )
-
-
-_CACHE_LOGICAL = {
-    "k": ("layers", "batch", "kv_seq", "kv_heads", None),
-    "v": ("layers", "batch", "kv_seq", "kv_heads", None),
-    "slot_pos": ("layers", "batch", "kv_seq"),
-    "kp": ("layers", None, "kv_heads", None, None),
-    "vp": ("layers", None, "kv_heads", None, None),
-    "ck": ("layers", "batch", None, "kv_heads", None),
-    "cv": ("layers", "batch", None, "kv_heads", None),
-    "state": ("layers", "batch", "ssm_heads", None, None),
-    "conv": ("layers", "batch", "conv_dim", None),
-}
-
-
-def cache_partition_specs(cache_shapes, mesh: Mesh, ruleset: RuleSet):
-    """Map an (abstract) cache tree to PartitionSpecs by leaf name."""
-    def walk(node):
-        if isinstance(node, dict):
-            out = {}
-            for key, val in node.items():
-                if key in _CACHE_LOGICAL and hasattr(val, "shape"):
-                    out[key] = partition_spec(val.shape, _CACHE_LOGICAL[key], mesh, ruleset)
-                else:
-                    out[key] = walk(val)
-            return out
-        if isinstance(node, (list, tuple)):
-            return type(node)(walk(v) for v in node)
-        if hasattr(node, "shape"):   # unnamed leaf
-            return P()
-        return node
-    return walk(cache_shapes)
 
 
 def named(mesh: Mesh, spec: P) -> NamedSharding:

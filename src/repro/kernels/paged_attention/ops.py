@@ -1,5 +1,5 @@
-"""Public paged attention ops (decode + chunked prefill) with pallas/xla
-dispatch.
+"""Public paged attention ops (chunked prefill over K/V pools, the latent
+sweep) with pallas/xla dispatch; a decode step is a chunk of one.
 
 The pallas path is what the engine serves on a TPU; the xla path (gather via
 page_table indexing) is what it serves elsewhere and the oracle the kernels
@@ -12,27 +12,7 @@ import jax.numpy as jnp
 from repro.kernels.backend import resolve
 from repro.kernels.paged_attention import kernel as _kernel
 from repro.kernels.paged_attention.ref import (chunked_prefill_reference,
-                                               mla_paged_reference,
-                                               paged_attention_reference)
-
-
-def paged_attention(
-    q, k_pages, v_pages, page_table, lengths, *,
-    scale: float | None = None, softcap: float = 0.0, window: int = 0,
-    backend: str = "auto", interpret: bool | None = None,
-):
-    if scale is None:
-        scale = q.shape[-1] ** -0.5
-    backend, interpret = resolve(backend, interpret)
-    if backend == "xla":
-        return paged_attention_reference(
-            q, k_pages, v_pages, page_table, lengths,
-            scale=scale, softcap=softcap, window=window,
-        )
-    return _kernel.paged_attention_pallas(
-        q, k_pages, v_pages, page_table, lengths,
-        scale=scale, softcap=softcap, window=window, interpret=interpret,
-    )
+                                               mla_paged_reference)
 
 
 def chunked_prefill_attention(

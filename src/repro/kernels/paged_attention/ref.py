@@ -1,6 +1,6 @@
 """Oracles for paged attention.
 
-Decode (one query token per sequence):
+Decode (one query token per sequence; the oracle of a one-token chunk):
   q:          (B, H, D)
   k_pages:    (P, Hkv, page_size, D)   global physical page pool
   v_pages:    (P, Hkv, page_size, D)

@@ -12,8 +12,8 @@ if _DEVICE_FLAG not in os.environ.get("XLA_FLAGS", ""):
 __doc__ = """Multi-pod dry-run on the CPU: prove the distribution config is coherent
 for meshes of TPU v5e chips (roofline peaks: ``roofline.peaks(V5E_KIND)``).
 
-For every (architecture x input shape) cell, on the single-pod (16,16) and
-multi-pod (2,16,16) production meshes:
+For every (architecture x training shape) cell, on the single-pod (16,16) and
+multi-pod (2,16,16) production meshes, the training step:
 
     with mesh:
         lowered  = jax.jit(step, in_shardings=...).lower(**input_specs)
@@ -37,11 +37,10 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.configs import ALL_ARCHS, SHAPES, get_config, shape_applicable
+from repro.configs import ALL_ARCHS, SHAPES, get_config
 from repro.configs.base import ModelConfig, ShapeConfig
-from repro.distributed.sharding import (cache_partition_specs, make_rules, named,
-                                        param_partition_specs, partition_spec,
-                                        use_sharding)
+from repro.distributed.sharding import (make_rules, named, param_partition_specs,
+                                        partition_spec, use_sharding)
 from repro.launch.mesh import make_production_mesh
 from repro.launch.roofline import (V5E_KIND, local_bytes, model_flops,
                                    parse_collective_bytes, peaks, roofline)
@@ -49,8 +48,6 @@ from repro.models import RunCtx, build_model
 from repro.models.params import abstract_params, param_specs
 from repro.training.optimizer import AdamWState, adamw_init
 from repro.training.train_step import TrainConfig, make_train_step
-
-DEC_START = 64          # enc-dec decoder segment length for prefill cells
 
 
 def _struct(shape, dtype):
@@ -60,87 +57,38 @@ def _struct(shape, dtype):
 def input_specs(cfg: ModelConfig, shape: ShapeConfig) -> Dict[str, Any]:
     """ShapeDtypeStruct stand-ins for every model input of this cell."""
     B, S = shape.global_batch, shape.seq_len
-    if shape.kind == "train":
-        if cfg.vision is not None:
-            S_text = S - cfg.vision.n_patches
-            return {"tokens": _struct((B, S_text), jnp.int32),
-                    "labels": _struct((B, S_text), jnp.int32),
-                    "patches": _struct((B, cfg.vision.n_patches, cfg.vision.d_patch), jnp.bfloat16)}
-        if cfg.encoder is not None:
-            return {"tokens": _struct((B, S), jnp.int32),
-                    "labels": _struct((B, S), jnp.int32),
-                    "frames": _struct((B, S, cfg.d_model), jnp.bfloat16)}
+    if cfg.vision is not None:
+        S_text = S - cfg.vision.n_patches
+        return {"tokens": _struct((B, S_text), jnp.int32),
+                "labels": _struct((B, S_text), jnp.int32),
+                "patches": _struct((B, cfg.vision.n_patches, cfg.vision.d_patch), jnp.bfloat16)}
+    if cfg.encoder is not None:
         return {"tokens": _struct((B, S), jnp.int32),
-                "labels": _struct((B, S), jnp.int32)}
-    if shape.kind == "prefill":
-        if cfg.vision is not None:
-            S_text = S - cfg.vision.n_patches
-            return {"tokens": _struct((B, S_text), jnp.int32),
-                    "patches": _struct((B, cfg.vision.n_patches, cfg.vision.d_patch), jnp.bfloat16)}
-        if cfg.encoder is not None:   # encode S frames, prefill a short decoder start
-            return {"tokens": _struct((B, DEC_START), jnp.int32),
-                    "frames": _struct((B, S, cfg.d_model), jnp.bfloat16)}
-        return {"tokens": _struct((B, S), jnp.int32)}
-    # decode: one new token against a seq_len cache
-    return {"tokens": _struct((B, 1), jnp.int32),
-            "positions": _struct((B,), jnp.int32)}
+                "labels": _struct((B, S), jnp.int32),
+                "frames": _struct((B, S, cfg.d_model), jnp.bfloat16)}
+    return {"tokens": _struct((B, S), jnp.int32),
+            "labels": _struct((B, S), jnp.int32)}
 
 
 def _batch_shardings(batch_structs, mesh, rules):
     logical = {"tokens": ("batch", "seq"), "labels": ("batch", "seq"),
-               "patches": ("batch", None, None), "frames": ("batch", "seq", None),
-               "positions": ("batch",)}
+               "patches": ("batch", None, None), "frames": ("batch", "seq", None)}
     return {k: named(mesh, partition_spec(v.shape, logical[k], mesh, rules))
             for k, v in batch_structs.items()}
-
-
-def _quantize_abstract(params_t, p_sh):
-    """Mirror quant.quantize_params_int8 over abstract params + shardings:
-    big floating >=2D leaves become QuantizedLinear(q int8, scale f32) with
-    the same spec on q and the last dim un-sharded on scale."""
-    import jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.quant.quantize import QuantizedLinear, _QUANT_MIN_SIZE
-
-    def is_big(v):
-        import numpy as np
-        return (len(v.shape) >= 2 and int(np.prod(v.shape)) >= _QUANT_MIN_SIZE
-                and jnp.issubdtype(v.dtype, jnp.floating))
-
-    def walk(v, sh):
-        if isinstance(v, dict):
-            return ({k: walk(v[k], sh[k])[0] for k in v},
-                    {k: walk(v[k], sh[k])[1] for k in v})
-        if isinstance(v, list):
-            pairs = [walk(a, b) for a, b in zip(v, sh)]
-            return [p[0] for p in pairs], [p[1] for p in pairs]
-        if is_big(v):
-            scale_shape = tuple(v.shape[:-1]) + (1,)
-            spec = sh.spec
-            scale_spec = P(*(tuple(spec) + (None,) * (len(v.shape) - len(tuple(spec))))[:-1], None)
-            qv = QuantizedLinear(
-                q=jax.ShapeDtypeStruct(v.shape, jnp.int8),
-                scale=jax.ShapeDtypeStruct(scale_shape, jnp.float32))
-            qs = QuantizedLinear(q=sh, scale=NamedSharding(sh.mesh, scale_spec))
-            return qv, qs
-        return v, sh
-
-    return walk(params_t, p_sh)
 
 
 def build_cell(cfg: ModelConfig, shape: ShapeConfig, mesh, *, moe_mode: str = "tp",
                seq_shard: bool = False, cost_mode: bool = False,
                block_div: int = 4, quant: str = "none",
                xent_chunk: int = 0, microbatches: int = 1):
-    """Build (lowered, meta) for one cell. ``cost_mode`` unrolls layers and
-    attention tiles so XLA cost_analysis counts every iteration (see the
-    affine calibration in run_cell)."""
+    """Build (lowered, t_lower, sizes) for one cell's training step.
+    ``cost_mode`` unrolls layers and attention tiles so XLA cost_analysis
+    counts every iteration (see the affine calibration in run_cell)."""
     multi_pod = "pod" in mesh.axis_names
     factored = "tensor" in mesh.axis_names           # Exp4 mesh (data,expert,tensor)
     tensor_axis = "tensor" if factored else "model"
     expert_axis = "expert" if factored else None
-    mode = "train" if shape.kind == "train" else "serve"
-    rules = make_rules(mode, moe=moe_mode, multi_pod=multi_pod,
+    rules = make_rules("train", moe=moe_mode, multi_pod=multi_pod,
                        seq_shard=seq_shard, tensor_axis=tensor_axis,
                        expert_axis=expert_axis)
     strategy = ({"tp": "tp_shardmap", "ep": "ep_shardmap"}[moe_mode]
@@ -148,95 +96,32 @@ def build_cell(cfg: ModelConfig, shape: ShapeConfig, mesh, *, moe_mode: str = "t
     model = build_model(cfg)
     specs = param_specs(cfg)
     blk = max(shape.seq_len // block_div, 1024) if cost_mode else 1024
-    knobs = dict(scan_layers=not cost_mode, attn_unroll=cost_mode,
+    ctx = RunCtx(mesh=mesh, attn_backend="xla", moe_strategy=strategy, remat=True,
+                 scan_layers=not cost_mode, attn_unroll=cost_mode,
                  block_q=blk, block_kv=blk,
-                 ep_axis=expert_axis or "data", tp_axis=tensor_axis,
-                 quant="a2a_int8" if quant == "a2a_int8" else "none")
+                 ep_axis=expert_axis or "data", tp_axis=tensor_axis, quant=quant)
+    _, step_fn = make_train_step(
+        model, TrainConfig(remat=True, xent_chunk=xent_chunk,
+                           microbatches=microbatches), ctx)
+    params_t = abstract_params(cfg, jnp.float32)
+    opt_t = jax.eval_shape(adamw_init, params_t)
+    state_t = (opt_t, None)
+    batch_t = input_specs(cfg, shape)
 
-    if shape.kind == "train":
-        ctx = RunCtx(mode="train", mesh=mesh, attn_backend="xla",
-                     moe_strategy=strategy, remat=True, **knobs)
-        _, step_fn = make_train_step(
-            model, TrainConfig(remat=True, xent_chunk=xent_chunk,
-                               microbatches=microbatches), ctx)
-        params_t = abstract_params(cfg, jnp.float32)
-        opt_t = jax.eval_shape(adamw_init, params_t)
-        state_t = (opt_t, None)
-        batch_t = input_specs(cfg, shape)
-
-        p_sh = jax.tree.map(lambda s: named(mesh, s),
-                            param_partition_specs(specs, mesh, rules))
-        opt_sh = AdamWState(step=named(mesh, partition_spec((), (), mesh, rules)),
-                            m=p_sh, v=p_sh)
-        in_sh = (p_sh, (opt_sh, None), _batch_shardings(batch_t, mesh, rules))
-
-        def fn(params, state, batch):
-            return step_fn(params, state, batch)
-
-        args = (params_t, state_t, batch_t)
-        donate = (0, 1)          # params + opt state update in place
-    else:
-        ctx = RunCtx(mode=shape.kind, mesh=mesh, attn_backend="xla",
-                     moe_strategy=strategy, **knobs)
-        params_t = abstract_params(cfg, jnp.bfloat16)
-        p_sh = jax.tree.map(lambda s: named(mesh, s),
-                            param_partition_specs(specs, mesh, rules))
-        batch_t = input_specs(cfg, shape)
-        b_sh = _batch_shardings(batch_t, mesh, rules)
-        mem_len = cfg.encoder.cross_attn_memory if cfg.encoder is not None else 0
-
-        if shape.kind == "prefill":
-            cache_t = jax.eval_shape(
-                lambda: model.init_cache(shape.global_batch, shape.seq_len,
-                                         jnp.bfloat16, kind="dense",
-                                         memory_len=shape.seq_len if cfg.encoder else 0))
-            c_sh = jax.tree.map(lambda s: named(mesh, s),
-                                cache_partition_specs(cache_t, mesh, rules))
-
-            def fn(params, batch, cache):
-                return model.prefill(params, batch, cache, ctx)
-
-            args = (params_t, batch_t, cache_t)
-            in_sh = (p_sh, b_sh, c_sh)
-            donate = (2,)        # cache filled in place
-        else:
-            cache_t = jax.eval_shape(
-                lambda: model.init_cache(shape.global_batch, shape.seq_len,
-                                         jnp.bfloat16, kind="dense",
-                                         memory_len=mem_len))
-            c_sh = jax.tree.map(lambda s: named(mesh, s),
-                                cache_partition_specs(cache_t, mesh, rules))
-            positions = batch_t.pop("positions")
-            tokens = batch_t["tokens"]
-
-            if quant == "int8":
-                # the paper's weight-only quantization: int8 weights in HBM,
-                # dequantized in-register before each matmul (w8a16)
-                from repro.quant import dequantize_tree
-                params_t, p_sh = _quantize_abstract(params_t, p_sh)
-
-                def fn(params, tokens, cache, positions):
-                    deq = dequantize_tree(params, jnp.bfloat16)
-                    return model.decode_step(deq, tokens, cache, positions, ctx)
-            else:
-                def fn(params, tokens, cache, positions):
-                    return model.decode_step(params, tokens, cache, positions, ctx)
-
-            args = (params_t, tokens, cache_t, positions)
-            in_sh = (p_sh, b_sh["tokens"], c_sh,
-                     named(mesh, partition_spec((shape.global_batch,), ("batch",), mesh, rules)))
-            donate = (2,)        # cache updated in place
+    p_sh = jax.tree.map(lambda s: named(mesh, s),
+                        param_partition_specs(specs, mesh, rules))
+    opt_sh = AdamWState(step=named(mesh, partition_spec((), (), mesh, rules)),
+                        m=p_sh, v=p_sh)
+    in_sh = (p_sh, (opt_sh, None), _batch_shardings(batch_t, mesh, rules))
 
     with mesh:
         with use_sharding(mesh, rules):
             t0 = time.time()
-            lowered = jax.jit(fn, in_shardings=in_sh,
-                              donate_argnums=donate).lower(*args)
+            lowered = jax.jit(step_fn, in_shardings=in_sh,
+                              donate_argnums=(0, 1)   # params + opt state in place
+                              ).lower(params_t, state_t, batch_t)
             t_lower = time.time() - t0
-    sizes = {"params_local_bytes": local_bytes(params_t, p_sh, mesh)}
-    if shape.kind != "train":
-        sizes["cache_local_bytes"] = local_bytes(cache_t, c_sh, mesh)
-    return lowered, t_lower, sizes
+    return lowered, t_lower, {"params_local_bytes": local_bytes(params_t, p_sh, mesh)}
 
 
 def _compile_costs(lowered) -> Dict[str, float]:
@@ -311,15 +196,11 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, moe_mode: str = "tp
     cfg = get_config(arch)
     shape = SHAPES[shape_name]
     meta: Dict[str, Any] = {"arch": arch, "shape": shape_name,
-                            "multi_pod": multi_pod, "mode": shape.kind,
+                            "multi_pod": multi_pod,
                             "moe": moe_mode if cfg.moe is not None else None,
                             "seq_shard": seq_shard, "quant": quant,
                             "exp4": exp4, "xent_chunk": xent_chunk,
                             "microbatches": microbatches}
-    ok, why = shape_applicable(cfg, shape)
-    if not ok:
-        meta["skipped"] = why
-        return meta
     if exp4:
         from repro.launch.mesh import make_moe_mesh
         ep, tp = (int(x) for x in exp4.split("x"))
@@ -369,11 +250,10 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, moe_mode: str = "tp
     hw = peaks(V5E_KIND)
     terms = roofline(full["flops"], full["bytes"], full["coll_total"], hw)
     meta["roofline"] = terms.as_dict()
-    # memory floor: weights read once + cache streamed once — the fused-TPU
-    # lower bound; XLA's bytes_accessed counts unfused copies and is the
-    # upper bound. Real HBM time lies between.
-    floor_bytes = sizes["params_local_bytes"] + sizes.get("cache_local_bytes", 0)
-    meta["roofline"]["memory_floor_s"] = floor_bytes / hw["hbm_bw"]
+    # memory floor: weights read once — the fused-TPU lower bound; XLA's
+    # bytes_accessed counts unfused copies and is the upper bound. Real HBM
+    # time lies between.
+    meta["roofline"]["memory_floor_s"] = sizes["params_local_bytes"] / hw["hbm_bw"]
     mf = model_flops(cfg, shape)
     meta["model_flops_global"] = mf
     meta["model_flops_per_dev"] = mf / n_dev
@@ -383,7 +263,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, moe_mode: str = "tp
     return meta
 
 
-ALL_SHAPES = ["train_4k", "prefill_32k", "decode_32k", "long_500k"]
+ALL_SHAPES = list(SHAPES)
 
 
 def main() -> int:
@@ -398,9 +278,8 @@ def main() -> int:
     ap.add_argument("--timeout", type=int, default=1800)
     ap.add_argument("--skip-cost", action="store_true",
                     help="compile + memory proof only (no cost probes)")
-    ap.add_argument("--quant", default="none", choices=["none", "int8", "a2a_int8"],
-                    help="int8 weight-only serving quant, or int8-compressed "
-                         "MoE all-to-all dispatch (decode cells)")
+    ap.add_argument("--quant", default="none", choices=["none", "a2a_int8"],
+                    help="int8-compressed MoE all-to-all dispatch (--moe ep)")
     ap.add_argument("--exp4", default=None,
                     help="factored Exp4 mesh 'EPxTP' e.g. 4x4 (256 chips)")
     ap.add_argument("--xent-chunk", type=int, default=0,

@@ -142,14 +142,6 @@ def local_bytes(shape_dtype_tree, spec_tree, mesh) -> int:
 
 
 def model_flops(cfg, shape) -> float:
-    """Useful-FLOPs yardstick: 6·N·D for training (fwd+bwd), 2·N·D for
-    serving, with N = active params for MoE. D = tokens processed."""
-    n = cfg.active_param_count()
-    if shape.kind == "train":
-        tokens = shape.global_batch * shape.seq_len
-        return 6.0 * n * tokens
-    if shape.kind == "prefill":
-        tokens = shape.global_batch * shape.seq_len
-        return 2.0 * n * tokens
-    tokens = shape.global_batch                 # decode: one token per sequence
-    return 2.0 * n * tokens
+    """Useful-FLOPs yardstick of a training step: 6·N·D (fwd+bwd), with N =
+    active params for MoE and D = the tokens of the global batch."""
+    return 6.0 * cfg.active_param_count() * shape.global_batch * shape.seq_len

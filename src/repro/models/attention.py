@@ -1,34 +1,31 @@
-"""GQA attention sublayer: train / prefill / chunk (paged serving) / decode
-(dense ring-buffer cache or paged cache) / cross-attention. One code path per
-mode, shared projections.
+"""GQA attention sublayer, in two modes with shared projections:
+
+  full sequence: no cache (train, ``LM.forward``, the encoder, and the
+      cross-attention of an encoder-decoder); causal or bidirectional.
+  chunk: the serving engine's one iteration (``LM.decode_chunk``, DESIGN.md
+      §2). Each batch row carries a chunk of S tokens of one sequence (S == 1
+      is decode); its KV is written straight into the paged pool and its
+      queries attend causally over the pool, which already holds the chunk
+      itself. ``chunk`` is the pack {"slots", "nvalid", "first", "valid",
+      "prefix"}: the engine-slot id, the per-row count of live tokens,
+      whether this is the row's first chunk, the per-position validity mask
+      (invalid positions write to null page 0), and whether rows carry a
+      vision patch prefix.
 
 Cache formats (per layer, unstacked — the scan adds the leading layers dim):
-  dense: {"k": (B, W, Hkv, hd), "v": ..., "slot_pos": (B, W) int32}
-         W = min(max_seq, window) — a ring buffer; slot_pos holds the absolute
-         position stored in each slot (-1 = empty). Full attention is W=max_seq
-         (slot == position) through the same code.
-  paged: {"kp": (P, Hkv, ps, hd), "vp": ...} + engine-level page_table/lengths.
-  cross: {"ck": (B, M, Hkv, hd), "cv": ...} built once at prefill.
-
-Mode "chunk" is the serving engine's unified iteration (DESIGN.md §2): each
-batch row carries a chunk of S tokens of one sequence (S == 1 is decode); KV
-is written straight into the paged pool (no dense intermediate) and queries
-attend causally over the pool, which already contains the chunk itself.
-``chunk`` carries {"slots", "nvalid", "first", "valid"} — the engine-slot id,
-the per-row count of live tokens, whether this is the row's first chunk, and
-the per-position validity mask (invalid positions write to null page 0).
+  paged: {"kp": (P, Hkv, ps, hd), "vp": ...} + the engine's page_table/lengths.
+  cross: {"ck": (slots, M, Hkv, hd), "cv": ...}, one slot per engine slot,
+         filled by the slot's prefill chunks.
 """
 from __future__ import annotations
 
 from typing import Any, Dict, Optional
 
-import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.paged_attention import (chunked_prefill_attention,
-                                           paged_attention)
+from repro.kernels.paged_attention import chunked_prefill_attention
 from repro.models.common import RunCtx, rope, shard_act
 
 
@@ -45,61 +42,6 @@ def _project_qkv(p, h, cfg: ModelConfig):
 
 def _out_proj(p, o):
     return jnp.einsum("bshk,hkd->bsd", o, p["wo"])
-
-
-def _decode_dense_attn(q, cache, positions, *, window: int, softcap: float, scale: float):
-    """q: (B,1,H,hd); ring-buffer cache. Plain einsum (q len 1 needs no tiling);
-    shards under GSPMD, incl. seq-sharded caches (softmax combine collectives)."""
-    k, v, slot_pos = cache["k"], cache["v"], cache["slot_pos"]
-    B, W, Hkv, hd = k.shape
-    H = q.shape[2]
-    G = H // Hkv
-    q5 = q.reshape(B, 1, Hkv, G, hd)
-    s = jnp.einsum("bqngd,bsnd->bnqgs", q5, k, preferred_element_type=jnp.float32) * scale
-    if softcap > 0:
-        s = softcap * jnp.tanh(s / softcap)
-    pos = positions[:, None]                       # (B,1) current absolute position
-    ok = (slot_pos >= 0) & (slot_pos <= pos)
-    if window > 0:
-        ok &= slot_pos > pos - window
-    s = jnp.where(ok[:, None, None, None, :], s, -1e30)
-    p_attn = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bnqgs,bsnd->bqngd", p_attn.astype(jnp.float32), v.astype(jnp.float32))
-    return o.reshape(B, 1, H, hd).astype(q.dtype)
-
-
-def _write_ring(cache, k, v, positions):
-    """Scatter new kv at positions into the ring buffer. decode: k (B,1,Hkv,hd),
-    positions (B,). prefill: k (B,S,...), positions (S,) shared across batch."""
-    W = cache["k"].shape[1]
-    if k.shape[1] == 1 and positions.ndim == 1 and positions.shape[0] == k.shape[0]:
-        slots = positions % W                       # (B,)
-        b_idx = jnp.arange(k.shape[0])
-        new_k = cache["k"].at[b_idx, slots].set(k[:, 0])
-        new_v = cache["v"].at[b_idx, slots].set(v[:, 0])
-        new_sp = cache["slot_pos"].at[b_idx, slots].set(positions)
-    else:                                           # prefill: positions (S,)
-        S = k.shape[1]
-        if S > W:                                   # keep the last W tokens
-            k, v, positions = k[:, -W:], v[:, -W:], positions[-W:]
-        slots = positions % W
-        new_k = cache["k"].at[:, slots].set(k)
-        new_v = cache["v"].at[:, slots].set(v)
-        new_sp = cache["slot_pos"].at[:, slots].set(positions[None, :])
-    return {"k": new_k, "v": new_v, "slot_pos": new_sp}
-
-
-def _write_paged(cache, k, v, positions, page_table):
-    """k (B,1,Hkv,hd); positions (B,) absolute; page_table (B, maxp)."""
-    ps = cache["kp"].shape[2]
-    b_idx = jnp.arange(k.shape[0])
-    logical = positions // ps
-    slot = positions % ps
-    phys = page_table[b_idx, logical]
-    return {
-        "kp": cache["kp"].at[phys, :, slot].set(k[:, 0].astype(cache["kp"].dtype)),
-        "vp": cache["vp"].at[phys, :, slot].set(v[:, 0].astype(cache["vp"].dtype)),
-    }
 
 
 def paged_slots(positions, page_table, valid, page_size: int):
@@ -135,14 +77,14 @@ def attention_sublayer(
     cfg: ModelConfig,
     kind: str,               # 'A' | 'L' | 'G' | 'X' (cross) | 'E' (encoder, bidirectional)
     cache: Optional[Dict[str, Any]] = None,
-    positions=None,          # decode: (B,) abs position of the new token;
-                             # prefill: (S,); chunk: (B, S) absolute
+    positions=None,          # chunk: (B, S) absolute; full sequence: (S,) or None
     memory=None,             # cross: encoder output (B, M, d)
     page_table=None,
     lengths=None,
-    chunk=None,              # chunk mode: {"slots", "nvalid", "first", "valid"}
+    chunk=None,              # the chunk pack; None: the full sequence
 ):
-    """Returns (attn_out (B,S,d), new_cache)."""
+    """Returns (attn_out (B,S,d), new_cache); new_cache is None for the full
+    sequence."""
     window = cfg.sliding_window if kind == "L" else 0
     softcap = cfg.attn_softcap
     scale = cfg.head_dim ** -0.5
@@ -151,7 +93,7 @@ def attention_sublayer(
     # ---------------- cross attention ----------------
     if kind == "X":
         q = jnp.einsum("bsd,dhk->bshk", h, p["wq"])
-        if ctx.mode == "chunk":
+        if chunk is not None:
             # slot-pooled cross cache: rows map to engine slots. With encoder
             # memory supplied (prefill chunks) recompute ck/cv and persist
             # them at the row's slot; without (decode sweep) read the slot.
@@ -170,13 +112,10 @@ def attention_sublayer(
             else:
                 ck, cv = cache["ck"][slots], cache["cv"][slots]
                 new_cache = cache
-        elif cache is not None and ctx.mode == "decode":
-            ck, cv = cache["ck"], cache["cv"]
-            new_cache = cache
         else:
             ck = jnp.einsum("bmd,dnk->bmnk", memory, p["wk"])
             cv = jnp.einsum("bmd,dnk->bmnk", memory, p["wv"])
-            new_cache = {"ck": ck, "cv": cv} if cache is not None else None
+            new_cache = None
         o = flash_attention(
             q, ck, cv, causal=False, softcap=softcap, scale=scale,
             backend=ctx.attn_backend, interpret=ctx.interpret,
@@ -186,7 +125,7 @@ def attention_sublayer(
 
     q, k, v = _project_qkv(p, h, cfg)
 
-    if ctx.mode == "chunk" and kind != "E":   # encoder runs full-seq below
+    if chunk is not None:
         # serving chunk: write this chunk's KV straight into the paged pool,
         # then attend causally over the pool (history + the chunk itself).
         # Rows carrying a vision patch prefix have non-affine positions,
@@ -203,23 +142,7 @@ def attention_sublayer(
         )
         return _out_proj(p, o), new_cache
 
-    if ctx.mode == "decode":
-        q = rope(q, positions[:, None], cfg.rope_theta)   # (B,1,...)
-        k = rope(k, positions[:, None], cfg.rope_theta)
-        if cache is not None and "kp" in cache:           # paged
-            new_cache = _write_paged(cache, k, v, positions, page_table)
-            o = paged_attention(
-                q[:, 0], new_cache["kp"], new_cache["vp"], page_table, lengths,
-                scale=scale, softcap=softcap, window=window,
-                backend=ctx.attn_backend, interpret=ctx.interpret,
-            )[:, None]                                     # (B,1,H,hd)
-        else:                                              # dense ring cache
-            new_cache = _write_ring(cache, k, v, positions)
-            o = _decode_dense_attn(q, new_cache, positions, window=window,
-                                   softcap=softcap, scale=scale)
-        return _out_proj(p, o), new_cache
-
-    # ---------------- train / prefill / encoder ----------------
+    # ---------------- full sequence: train / forward / encoder ----------------
     if positions is None:
         positions = jnp.arange(S)
     causal = kind != "E"
@@ -236,7 +159,4 @@ def attention_sublayer(
         backend=ctx.attn_backend, interpret=ctx.interpret,
         block_q=ctx.block_q, block_kv=ctx.block_kv, unroll=ctx.attn_unroll,
     )
-    new_cache = cache
-    if cache is not None and "k" in cache:                 # prefill: persist kv
-        new_cache = _write_ring(cache, k_roped, v, positions)
-    return _out_proj(p, o), new_cache
+    return _out_proj(p, o), None

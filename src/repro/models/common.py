@@ -2,7 +2,7 @@
 from __future__ import annotations
 
 import math
-from dataclasses import dataclass, replace
+from dataclasses import dataclass
 from typing import Any, Optional, Tuple
 
 import jax
@@ -14,9 +14,11 @@ from repro.kernels.backend import resolve
 
 @dataclass(frozen=True)
 class RunCtx:
-    """Threaded through every layer: mode + execution knobs.
+    """Execution knobs threaded through every layer. What a sublayer runs is
+    set by its arguments, not here: the full sequence with no cache (train,
+    ``LM.forward``, the encoder), or a chunk over the paged pool when the
+    chunk pack (``LM.decode_chunk``) is given.
 
-    mode: "train" | "prefill" | "decode"
     attn_backend: "auto" | "pallas" | "xla" for attention and the MoE gmm
         (xla = the jnp references, which the dry-run lowers; pallas = the
         TPU kernels). "auto" is resolved once, at construction, from the
@@ -25,7 +27,6 @@ class RunCtx:
     moe_strategy: "dropless" (exact, serving engine) | "capacity" (local
         capacity buffers) | "tp_shardmap" | "ep_shardmap" (explicit collectives)
     """
-    mode: str = "train"
     mesh: Any = None
     attn_backend: str = "auto"
     moe_strategy: str = "capacity"
@@ -35,7 +36,7 @@ class RunCtx:
     ep_axis: str = "data"
     tp_axis: str = "model"
     interpret: bool = False
-    quant: str = "none"         # none | int8 (weight-only serving quant)
+    quant: str = "none"         # none | a2a_int8 (int8 all-to-all in ep_shardmap)
     # Cost-model lowering knobs (launch/dryrun): XLA's cost_analysis counts
     # loop bodies ONCE, so the roofline cost lowering unrolls layers and
     # attention tiles (small repeat counts; affine extrapolation).
@@ -45,9 +46,6 @@ class RunCtx:
     def __post_init__(self):
         backend, _ = resolve(self.attn_backend, self.interpret)
         object.__setattr__(self, "attn_backend", backend)
-
-    def with_mode(self, mode: str) -> "RunCtx":
-        return replace(self, mode=mode)
 
 
 def rmsnorm(x, w, eps: float = 1e-6):

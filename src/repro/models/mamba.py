@@ -1,10 +1,12 @@
 """Mamba2 layer via SSD (state-space duality, arXiv:2405.21060).
 
-Chunked algorithm (train/prefill): within-chunk quadratic ("attention-like")
-term + cross-chunk state recurrence (lax.scan over chunks). Decode is an O(1)
-state update — this is why the SSM archs run the long_500k cell.
+Chunked algorithm (the full sequence, and serving chunks of more than one
+token): within-chunk quadratic ("attention-like") term + cross-chunk state
+recurrence (lax.scan over chunks). A one-token chunk (decode) is an O(1)
+state update.
 
-Cache per layer: {"state": (B, H, P, N) f32, "conv": (B, conv_dim, d_conv-1)}.
+Cache per layer, one slot per engine slot: {"state": (slots, H, P, N) f32,
+"conv": (slots, conv_dim, d_conv-1)}; the full sequence runs with none.
 All decays are exp(<=0) — numerically bounded by construction.
 """
 from __future__ import annotations
@@ -15,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.common import RunCtx, rmsnorm
+from repro.models.common import rmsnorm
 
 
 def _split_proj(zxbcdt, cfg: ModelConfig):
@@ -145,10 +147,12 @@ def mamba_sublayer(
     p: Dict[str, Any],
     h,                      # normed (B, S, d)
     cfg: ModelConfig,
-    ctx: RunCtx,
     cache: Optional[Dict[str, Any]] = None,
     chunk: Optional[Dict[str, Any]] = None,
 ) -> Tuple[Any, Optional[Dict[str, Any]]]:
+    """Returns (out (B,S,d), new_cache): with the chunk pack, a chunk over the
+    slot-pooled state; without, the full sequence from zero state (new_cache
+    None)."""
     ssm = cfg.ssm
     d_in, H, Pd = cfg.d_inner, cfg.ssm_heads, ssm.head_dim
     G, N, K = ssm.n_groups, ssm.d_state, ssm.d_conv
@@ -159,7 +163,7 @@ def mamba_sublayer(
     A = -jnp.exp(p["A_log"].astype(jnp.float32))          # (H,)
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
 
-    if ctx.mode == "chunk":
+    if chunk is not None:
         # serving chunk over the slot-pooled cache: rows map to engine slots,
         # first chunks start from zero state, ragged tails are masked via dt
         # (dt == 0 => exp(dt*A) == 1 and zero input: the state is untouched).
@@ -197,11 +201,6 @@ def mamba_sublayer(
                 jnp.where(row_valid[:, None, None],
                           conv_new.astype(cache["conv"].dtype), c_orig)),
         }
-    elif ctx.mode == "decode":
-        xbc_c, new_conv = _conv_step(xbc, cache["conv"], p["conv_w"], p["conv_b"])
-        y, state = _ssm_decode_update(xbc_c, dt[:, 0], A, p,
-                                      cache["state"].astype(jnp.float32), cfg)
-        new_cache = {"state": state, "conv": new_conv}
     else:
         xbc_c = _conv_full(xbc, p["conv_w"], p["conv_b"])
         xh = xbc_c[..., :d_in].reshape(B, S, H, Pd)
@@ -209,16 +208,10 @@ def mamba_sublayer(
         Cm = xbc_c[..., d_in + G * N :].reshape(B, S, G, N)
         Bm = jnp.repeat(Bm, H // G, axis=2)
         Cm = jnp.repeat(Cm, H // G, axis=2)
-        init_state = cache["state"] if (cache is not None and ctx.mode == "prefill") else None
-        y, final_state = ssd_chunked(xh, dt, A, Bm, Cm, ssm.chunk_size, init_state=init_state)
+        y, _ = ssd_chunked(xh, dt, A, Bm, Cm, ssm.chunk_size)
         y = y + p["D"].astype(jnp.float32)[None, None, :, None] * xh.astype(jnp.float32)
         y = y.reshape(B, S, d_in)
-        new_cache = cache
-        if cache is not None:                             # prefill: hand off state
-            tail = xbc[:, -(K - 1):, :]
-            if S < K - 1:
-                tail = jnp.pad(tail, ((0, 0), (K - 1 - S, 0), (0, 0)))
-            new_cache = {"state": final_state, "conv": tail.transpose(0, 2, 1)}
+        new_cache = None
 
     # gated RMSNorm + out projection
     y = rmsnorm((y * jax.nn.silu(z.astype(jnp.float32))).astype(h.dtype), p["norm"], cfg.rms_eps)

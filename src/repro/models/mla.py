@@ -8,11 +8,11 @@ a query LoRA. For each token with normed input h:
   score = (q_nope·k_nope + q_pe·k_pe) · s, causal softmax, s = mscale² / √(nope + rope)
   out = Σ_heads (P·v)·Wo
 
-Serving (mode "chunk") caches [c, k_pe] — ``MLAConfig.cache_dim`` values a
-token, shared by every head — in one paged pool per layer, and attends in
-the absorbed form: q_lat = q_nope·Wukᵀ joined with q_pe scores the pool's
-rows directly, o_lat = P·c, and out = (o_lat·Wuv)·Wo. Full-sequence modes
-(train, the forward pass) use the expanded form in plain XLA.
+Serving (``LM.decode_chunk``) caches [c, k_pe] — ``MLAConfig.cache_dim``
+values a token, shared by every head — in one paged pool per layer, and
+attends in the absorbed form: q_lat = q_nope·Wukᵀ joined with q_pe scores
+the pool's rows directly, o_lat = P·c, and out = (o_lat·Wuv)·Wo. The full sequence
+with no cache (train, the forward pass) uses the expanded form in plain XLA.
 
 A pool row is ``pool_width`` wide: the cached values, then zeros up to a
 whole number of 128-lane tiles (576 -> 640). A TPU lays a 576-wide array
@@ -101,17 +101,17 @@ def mla_sublayer(
     positions=None,          # chunk: (B, S) absolute; full sequence: (S,)
     page_table=None,
     lengths=None,
-    chunk=None,              # chunk mode: {"valid", ...}
+    chunk=None,              # the chunk pack ({"valid", ...}); None: the full sequence
 ):
-    """Returns (attn_out (B,S,d), new_cache); the chunk mode's cache is
-    {"lat": (P, page_size, pool_width)}."""
+    """Returns (attn_out (B,S,d), new_cache); a chunk's cache is
+    {"lat": (P, page_size, pool_width)}, the full sequence's None."""
     with jax.named_scope("mla"):
         m = cfg.mla
         scale = softmax_scale(cfg)
         if positions is None:
             positions = jnp.arange(h.shape[1])
         q_nope, q_pe, c, k_pe = project(p, h, positions, cfg)
-        if ctx.mode == "chunk":
+        if chunk is not None:
             width = cache["lat"].shape[-1]
             rows = _pad_to(jnp.concatenate([c, k_pe.astype(c.dtype)], axis=-1), width)
             lat = _write_latent_chunk(cache["lat"], rows, positions, page_table,
@@ -122,10 +122,7 @@ def mla_sublayer(
                 backend=ctx.attn_backend, interpret=ctx.interpret)
             o = jnp.einsum("bshr,rhk->bshk", o_lat, p["wv_b"])
             new_cache = {"lat": lat}
-        elif ctx.mode in ("train", "prefill") and cache is None:
+        else:
             o = expanded_attention(q_nope, q_pe, c, k_pe, p, scale)
             new_cache = None
-        else:
-            raise NotImplementedError(
-                "latent attention caches only in the paged pool (mode 'chunk')")
         return jnp.einsum("bshk,hkd->bsd", o, p["wo"]), new_cache

@@ -7,8 +7,9 @@ encdec        : seamless (bidirectional encoder + cross-attention decoder)
 vlm           : phi-3-vision (patch embeddings prepended via a real projector)
 
 Repeated layers are stacked and executed with ``lax.scan`` over the group's
-repeats (small HLO, fast 512-device compiles, remat-friendly). The decode path
-consumes either dense ring-buffer caches or the paged KV pool.
+repeats (small HLO, fast 512-device compiles, remat-friendly). Two entry
+points run the layers: ``forward``/``loss`` over whole sequences with no
+cache, and ``decode_chunk``, the one serving path, over the paged KV pool.
 """
 from __future__ import annotations
 
@@ -83,7 +84,7 @@ class LM:
         seq_sharded = ("batch", "seq", None)
         h = rmsnorm(x, p["ln1"], cfg.rms_eps)
         if kind == "M":
-            sub, cm = mamba_sublayer(p["ssm"], h, cfg, ctx,
+            sub, cm = mamba_sublayer(p["ssm"], h, cfg,
                                      cache=c.get("ssm") if c else None,
                                      chunk=chunk)
             if new_c is not None:
@@ -202,21 +203,19 @@ class LM:
 
     # ------------------------------------------------------------------ embed
     def _embed(self, params, batch, ctx: RunCtx):
-        """Returns (x (B,S,d), text_offset) — text_offset = #prefix positions
-        (vision patches) preceding the first text token."""
+        """Returns x (B,S,d): the embedded tokens, after the projected vision
+        patches where the batch has them."""
         cfg = self.cfg
         tokens = batch["tokens"]
         x = params["embed"]["w"][tokens]
         if cfg.scale_embedding:
             x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
-        offset = 0
         if cfg.vision is not None and "patches" in batch:
             proj = (jnp.einsum("bpk,kd->bpd", batch["patches"].astype(x.dtype),
                                params["vision_proj"]["w"].astype(x.dtype))
                     + params["vision_proj"]["b"].astype(x.dtype))
             x = jnp.concatenate([proj, x], axis=1)
-            offset = proj.shape[1]
-        return shard_act(x, ("batch", "seq", None)), offset
+        return shard_act(x, ("batch", "seq", None))
 
     def _head(self, params, x):
         cfg = self.cfg
@@ -239,7 +238,7 @@ class LM:
     def forward(self, params, batch, ctx: RunCtx):
         """Teacher-forced full-sequence logits. Returns (logits, aux)."""
         cfg = self.cfg
-        x, _ = self._embed(params, batch, ctx)
+        x = self._embed(params, batch, ctx)
         memory = None
         if cfg.encoder is not None:
             memory = self._encode(params, batch["frames"].astype(x.dtype), ctx)
@@ -256,7 +255,7 @@ class LM:
         (B, S, vocab) f32 logits never materialize at once — the head matmul
         + logsumexp run per seq-chunk under remat. Cuts the train-step temp
         memory by the vocab-logits term (the dominant one for 150k-256k
-        vocabs); a beyond-paper memory optimization (EXPERIMENTS.md §Perf)."""
+        vocabs); a beyond-paper memory optimization."""
         cfg = self.cfg
         labels = batch["labels"]
         mask = (labels >= 0).astype(jnp.float32)
@@ -274,7 +273,7 @@ class LM:
             return total, {"xent": xent, "aux": aux}
 
         # chunked path: trunk features once, head+xent per sequence chunk
-        x, offset = self._embed(params, batch, ctx)
+        x = self._embed(params, batch, ctx)
         memory = None
         if cfg.encoder is not None:
             memory = self._encode(params, batch["frames"].astype(x.dtype), ctx)
@@ -307,29 +306,6 @@ class LM:
         xent = -jnp.sum(lls) / jnp.maximum(jnp.sum(mask), 1.0)
         total = xent + aux_weight * aux
         return total, {"xent": xent, "aux": aux}
-
-    def prefill(self, params, batch, cache, ctx: RunCtx, last_pos=None):
-        """Full-sequence pass that also fills the cache. ``last_pos`` (B,)
-        selects the logits position (true prompt end when the engine pads to a
-        bucket); defaults to the final position.
-        Returns (last_logits (B, vocab), cache)."""
-        cfg = self.cfg
-        ctx = ctx.with_mode("prefill")
-        x, offset = self._embed(params, batch, ctx)
-        memory = None
-        if cfg.encoder is not None:
-            memory = self._encode(params, batch["frames"].astype(x.dtype), ctx)
-        positions = jnp.arange(x.shape[1])
-        x, new_cache, _, _ = self._run_groups(
-            params["groups"], x, cache, ctx=ctx, layer_groups=cfg.layer_groups,
-            positions=positions, memory=memory)
-        x = rmsnorm(x, params["final_norm"]["w"], cfg.rms_eps)
-        if last_pos is None:
-            last = x[:, -1:]
-        else:
-            last = jnp.take_along_axis(x, (last_pos + offset)[:, None, None], axis=1)
-        logits = self._head(params, last)
-        return logits[:, 0], new_cache
 
     def decode_chunk(self, params, tokens, cache, starts, nvalid, slots, first,
                      ctx: RunCtx, page_table, frames=None, patches=None,
@@ -364,8 +340,7 @@ class LM:
             # would treat a patch prefix as live tokens — refuse loudly
             # rather than corrupt state (no current config hits this).
             raise NotImplementedError(
-                "chunk mode: vision patch prefix + SSM layers is unsupported")
-        ctx = ctx.with_mode("chunk")
+                "decode_chunk: vision patch prefix + SSM layers is unsupported")
         B, C = tokens.shape
         x = params["embed"]["w"][tokens]
         if cfg.scale_embedding:
@@ -405,43 +380,25 @@ class LM:
         logits = self._head(params, x_last)
         return logits[:, 0], new_cache
 
-    def decode_step(self, params, tokens, cache, positions, ctx: RunCtx,
-                    page_table=None, lengths=None):
-        """tokens (B,1); positions (B,) absolute position of the new token.
-        Returns (logits (B, vocab), new_cache)."""
-        cfg = self.cfg
-        ctx = ctx.with_mode("decode")
-        x = params["embed"]["w"][tokens]
-        if cfg.scale_embedding:
-            x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
-        if lengths is None:
-            lengths = positions + 1
-        x, new_cache, _, _ = self._run_groups(
-            params["groups"], x, cache, ctx=ctx, layer_groups=cfg.layer_groups,
-            positions=positions, page_table=page_table, lengths=lengths)
-        x = rmsnorm(x, params["final_norm"]["w"], cfg.rms_eps)
-        logits = self._head(params, x)
-        return logits[:, 0], new_cache
-
     # ------------------------------------------------------------------ cache
     def init_cache(self, B: int, max_seq: int, dtype=jnp.float32, *,
-                   kind: str = "dense", page_size: int = 16,
+                   kind: str = "paged", page_size: int = 16,
                    num_pages: int = 0, memory_len: int = 0):
-        """Build the cache pytree (call under jax.eval_shape for the dry-run).
-
-        kind="dense": per-layer ring buffers (window layers get W=window).
-        kind="paged": per-layer physical page pools (engine supplies
-                      page_table/lengths at decode time); a latent layer
-                      holds one pool "lat" (R, P, page_size, latent + rope
-                      padded to whole lane tiles, ``mla.pool_width``).
-        """
+        """Build the serving cache pytree for ``decode_chunk``: per layer a
+        physical page pool (the engine supplies page_table/lengths) — K and V
+        pools, or for a latent layer one pool "lat" (R, P, page_size, latent
+        + rope padded to whole lane tiles, ``mla.pool_width``) — plus B
+        slots of SSM state and of cross-KV where the family has them.
+        ``max_seq`` is not used; ``kind`` must be "paged"."""
+        if kind != "paged":
+            raise NotImplementedError(f"the cache is the paged pool only, not {kind!r}")
         cfg = self.cfg
         Hkv, hd = cfg.n_kv_heads, cfg.head_dim
         groups_cache = []
         for g in cfg.layer_groups:
             R = g.repeats
             per_pos = []
-            for pos, k in enumerate(g.pattern):
+            for k in g.pattern:
                 c: Dict[str, Any] = {}
                 if k == "M":
                     ssm = cfg.ssm
@@ -451,24 +408,14 @@ class LM:
                                            ssm.d_conv - 1), dtype),
                     }
                 elif cfg.mla is not None:
-                    if kind != "paged":
-                        raise NotImplementedError("latent attention caches in the paged pool only")
                     # one pool of each token's latent and rope key, shared by all heads
                     c["attn"] = {"lat": jnp.zeros((R, num_pages, page_size, pool_width(cfg)),
                                                   dtype)}
                 else:
-                    W = min(max_seq, cfg.sliding_window) if (k == "L" and cfg.sliding_window) else max_seq
-                    if kind == "paged":
-                        c["attn"] = {
-                            "kp": jnp.zeros((R, num_pages, Hkv, page_size, hd), dtype),
-                            "vp": jnp.zeros((R, num_pages, Hkv, page_size, hd), dtype),
-                        }
-                    else:
-                        c["attn"] = {
-                            "k": jnp.zeros((R, B, W, Hkv, hd), dtype),
-                            "v": jnp.zeros((R, B, W, Hkv, hd), dtype),
-                            "slot_pos": jnp.full((R, B, W), -1, jnp.int32),
-                        }
+                    c["attn"] = {
+                        "kp": jnp.zeros((R, num_pages, Hkv, page_size, hd), dtype),
+                        "vp": jnp.zeros((R, num_pages, Hkv, page_size, hd), dtype),
+                    }
                 if cfg.family == "encdec":
                     M = memory_len or cfg.encoder.cross_attn_memory
                     c["cross"] = {

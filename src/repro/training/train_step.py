@@ -34,7 +34,7 @@ def make_train_step(model: LM, tcfg: TrainConfig, ctx: Optional[RunCtx] = None
                     ) -> Tuple[Callable, Callable]:
     """Returns (init_fn(params) -> state, step_fn(params, state, batch) ->
     (params, state, metrics)). state = (AdamWState, error_feedback|None)."""
-    ctx = ctx or RunCtx(mode="train", attn_backend="xla", moe_strategy="capacity",
+    ctx = ctx or RunCtx(attn_backend="xla", moe_strategy="capacity",
                         remat=tcfg.remat)
 
     def init_fn(params):

@@ -10,8 +10,7 @@ from repro.configs import ALL_ARCHS, get_config, tiny_config
 from repro.models import RunCtx, build_model
 from repro.training.train_step import TrainConfig, make_train_step
 
-CTX = RunCtx(mode="train", attn_backend="xla", moe_strategy="capacity",
-             block_q=16, block_kv=16)
+CTX = RunCtx(attn_backend="xla", moe_strategy="capacity", block_q=16, block_kv=16)
 
 
 def _batch(cfg, B=2, S=24, seed=0):

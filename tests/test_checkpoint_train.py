@@ -122,8 +122,7 @@ def test_chunked_xent_matches_full():
     r = np.random.default_rng(0)
     batch = {"tokens": jnp.asarray(r.integers(0, cfg.vocab, (2, 24))),
              "labels": jnp.asarray(r.integers(0, cfg.vocab, (2, 24)))}
-    ctx = RunCtx(mode="train", attn_backend="xla", moe_strategy="capacity",
-                 block_q=8, block_kv=8)
+    ctx = RunCtx(attn_backend="xla", moe_strategy="capacity", block_q=8, block_kv=8)
     l0, _ = m.loss(params, batch, ctx)
     l1, _ = m.loss(params, batch, ctx, xent_chunk=7)
     assert abs(float(l0) - float(l1)) < 1e-4

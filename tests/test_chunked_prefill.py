@@ -1,5 +1,5 @@
 """Chunked paged prefill (DESIGN.md §2): kernel oracle equivalence, chunked
-== dense prefill logits across chunk sizes (incl. ragged prompts),
+prefill logits == the forward pass's across chunk sizes (incl. ragged prompts),
 preempt-then-resume determinism, and the per-iteration token budget."""
 import jax
 import jax.numpy as jnp
@@ -16,7 +16,7 @@ from repro.models import RunCtx, build_model
 
 CTX = RunCtx(attn_backend="xla", moe_strategy="dropless", block_q=128, block_kv=128)
 
-# rtol/atol for chunked-vs-dense logits: both paths compute attention and
+# atol for chunked-vs-forward logits: both paths compute attention and
 # softmax in f32; the differences are reduction-order only.
 LOGIT_ATOL = 2e-3
 
@@ -149,23 +149,17 @@ def test_chunked_kernel_matches_bruteforce(case, monkeypatch):
 @pytest.mark.parametrize("chunk", [3, 5, 13, 16])
 def test_chunked_prefill_matches_dense_logits(setup, chunk):
     """Prompt length 13 is not divisible by chunks 3/5/16; chunk 13 is the
-    whole-prompt case. All must match the dense-prefill reference."""
+    whole-prompt case. All must match the teacher-forced forward pass at the
+    prompt's last position and at every decoded one."""
     cfg, model, params = setup
     S, gen, ps, maxp = 13, 4, 4, 16
     r = np.random.default_rng(2)
     toks = r.integers(0, cfg.vocab, S + gen).astype(np.int32)
 
-    dense = model.init_cache(1, 64, jnp.float32, kind="dense")
-    lg, dcache = model.prefill(params, {"tokens": jnp.asarray(toks[:S])[None]},
-                               dense, CTX)
-    ref = [np.asarray(lg[0])]
-    for i in range(gen):
-        lg, dcache = model.decode_step(params, jnp.asarray(toks[S + i:S + i + 1])[None],
-                                       dcache, jnp.asarray([S + i], jnp.int32), CTX)
-        ref.append(np.asarray(lg[0]))
+    full, _ = model.forward(params, {"tokens": jnp.asarray(toks)[None]}, CTX)
+    ref = [np.asarray(full[0, i]) for i in range(S - 1, S + gen)]
 
-    paged = model.init_cache(2, 64, jnp.float32, kind="paged",
-                             page_size=ps, num_pages=64)
+    paged = model.init_cache(2, 64, jnp.float32, page_size=ps, num_pages=64)
     pt = jnp.asarray(np.arange(1, maxp + 1, dtype=np.int32)[None])
     slot = jnp.asarray([1], jnp.int32)
     out = []
@@ -191,13 +185,16 @@ def test_chunked_prefill_matches_dense_logits(setup, chunk):
 
 
 def _ref_greedy(model, params, prompt, n):
-    cache = model.init_cache(1, 128, jnp.float32, kind="dense")
-    lg, cache = model.prefill(params, {"tokens": jnp.asarray(prompt)[None]}, cache, CTX)
-    out = [int(jnp.argmax(lg[0]))]
-    for i in range(n - 1):
-        lg, cache = model.decode_step(params, jnp.asarray([[out[-1]]]), cache,
-                                      jnp.asarray([len(prompt) + i], jnp.int32), CTX)
-        out.append(int(jnp.argmax(lg[0])))
+    """Greedy decoding by the teacher-forced forward pass: the prompt and
+    the tokens chosen so far, padded to one length (the model is causal, so
+    the padding changes no earlier position's logits)."""
+    seq = np.zeros((1, len(prompt) + n), np.int32)
+    seq[0, :len(prompt)] = prompt
+    logits = jax.jit(lambda p, t: model.forward(p, {"tokens": t}, CTX)[0])
+    out = []
+    for i in range(len(prompt), len(prompt) + n):
+        out.append(int(jnp.argmax(logits(params, jnp.asarray(seq))[0, i - 1])))
+        seq[0, i] = out[-1]
     return out
 
 
@@ -281,25 +278,23 @@ def test_oversized_prompt_fails_fast(setup):
 
 
 def test_no_dense_cache_on_serving_path(setup):
-    """The serving engine must never allocate a dense per-request cache or
-    run a scatter copy: the legacy hooks are gone and init_cache(dense) is
-    not called during generate()."""
+    """The model has one serving path, ``decode_chunk`` over the paged pool:
+    no dense-cache prefill or one-token decode step remains beside it, and
+    the engine keeps none of the legacy prefill/scatter hooks."""
     cfg, model, params = setup
+    from repro.models.transformer import LM
+    for attr in ("prefill", "decode_step"):
+        assert not hasattr(LM, attr)
     for attr in ("_run_prefill", "_scatter_fn", "_prefill_fn", "_bucket"):
         assert not hasattr(InferenceEngine, attr)
+    with pytest.raises(NotImplementedError):
+        model.init_cache(1, 64, kind="dense")
     eng = InferenceEngine(model, params, EngineConfig(
         max_slots=2, page_size=8, num_pages=32, max_seq=64,
         prefill_chunk=8, greedy=True))
-    calls = []
-    orig = model.init_cache
-    model.init_cache = lambda *a, **k: (calls.append(k.get("kind", "dense")),
-                                        orig(*a, **k))[1]
-    try:
-        r = np.random.default_rng(6)
-        reqs = [Request(req_id="d0", prompt_tokens=r.integers(1, cfg.vocab, 9).astype(np.int32),
-                        max_new_tokens=4)]
-        eng.generate(reqs)
-    finally:
-        model.init_cache = orig
+    r = np.random.default_rng(6)
+    reqs = [Request(req_id="d0", prompt_tokens=r.integers(1, cfg.vocab, 9).astype(np.int32),
+                    max_new_tokens=4)]
+    eng.generate(reqs)
     assert reqs[0].finished
-    assert "dense" not in calls
+    assert reqs[0].generated == _ref_greedy(model, params, reqs[0].prompt_tokens, 4)

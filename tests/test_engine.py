@@ -23,13 +23,16 @@ def setup():
 
 
 def _ref_greedy(model, params, prompt, n):
-    cache = model.init_cache(1, 128, jnp.float32, kind="dense")
-    lg, cache = model.prefill(params, {"tokens": jnp.asarray(prompt)[None]}, cache, CTX)
-    out = [int(jnp.argmax(lg[0]))]
-    for i in range(n - 1):
-        lg, cache = model.decode_step(params, jnp.asarray([[out[-1]]]), cache,
-                                      jnp.asarray([len(prompt) + i], jnp.int32), CTX)
-        out.append(int(jnp.argmax(lg[0])))
+    """Greedy decoding by the teacher-forced forward pass: the prompt and
+    the tokens chosen so far, padded to one length (the model is causal, so
+    the padding changes no earlier position's logits)."""
+    seq = np.zeros((1, len(prompt) + n), np.int32)
+    seq[0, :len(prompt)] = prompt
+    logits = jax.jit(lambda p, t: model.forward(p, {"tokens": t}, CTX)[0])
+    out = []
+    for i in range(len(prompt), len(prompt) + n):
+        out.append(int(jnp.argmax(logits(params, jnp.asarray(seq))[0, i - 1])))
+        seq[0, i] = out[-1]
     return out
 
 

@@ -170,8 +170,7 @@ def serve_logits(model, params, ctx, toks, cache_dtype=jnp.float32, chunk=16, ps
     token at a time from position ``chunk + 5``, through the paged pool."""
     B, N = toks.shape
     maxp = -(-N // ps)
-    cache = model.init_cache(B, N, cache_dtype, kind="paged", page_size=ps,
-                             num_pages=B * maxp + 1)
+    cache = model.init_cache(B, N, cache_dtype, page_size=ps, num_pages=B * maxp + 1)
     pt = jnp.asarray(1 + np.arange(B * maxp).reshape(B, maxp)[:, ::-1].copy(), jnp.int32)
     out = np.zeros((B, N, model.cfg.vocab), np.float32)
     spans, t = [], 0
@@ -193,7 +192,7 @@ def serve_logits(model, params, ctx, toks, cache_dtype=jnp.float32, chunk=16, ps
 
 def test_latent_pool_holds_one_row_a_token(setup):
     cfg, model, _ = setup
-    cache = model.init_cache(2, 64, jnp.bfloat16, kind="paged", page_size=8, num_pages=10)
+    cache = model.init_cache(2, 64, jnp.bfloat16, page_size=8, num_pages=10)
     pool = cache["groups"][1][0]["attn"]
     # 32 latent + 16 rope values a token, padded to one 128-lane tile
     assert set(pool) == {"lat"} and pool["lat"].shape == (2, 10, 8, 128)
@@ -295,7 +294,7 @@ def _pool_bytes(cfg, layers):
     groups = (LayerGroup("A", 1, moe_mask="0"), LayerGroup("A", layers - 1, moe_mask="1"))
     model = build_model(cfg.scaled(n_layers=layers, layer_groups=groups))
     cache = jax.eval_shape(lambda: model.init_cache(
-        12, 4096, jnp.bfloat16, kind="paged", page_size=16, num_pages=64))
+        12, 4096, jnp.bfloat16, page_size=16, num_pages=64))
     return kv_bytes_per_token(cache, 64 * 16)
 
 

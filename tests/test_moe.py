@@ -136,7 +136,7 @@ def _serve(model, params, ctx):
     the scan's body and the unrolled layers apart, and rounds them apart)."""
     cfg = model.cfg
     toks = np.random.default_rng(5).integers(0, cfg.vocab, (2, 5)).astype(np.int32)
-    cache = model.init_cache(2, 32, jnp.float32, kind="paged", page_size=4, num_pages=16)
+    cache = model.init_cache(2, 32, jnp.float32, page_size=4, num_pages=16)
     pt = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], jnp.int32)
     slots = jnp.asarray([0, 1], jnp.int32)
     step = jax.jit(lambda p, t, c, st, nv, first: model.decode_chunk(

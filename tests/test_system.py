@@ -95,16 +95,16 @@ def test_metrics_persisted_to_disk(stack):
 
 @pytest.mark.slow
 def test_dryrun_subprocess_single_cell():
-    """Smoke the real dry-run entry point (512 fake devices) on the cheapest
-    cell; asserts lower+compile succeeded and the roofline terms exist."""
+    """Smoke the real dry-run entry point (512 fake devices) on one cell;
+    asserts lower+compile succeeded and the roofline terms exist."""
     with tempfile.TemporaryDirectory() as d:
         env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
         r = subprocess.run(
             [sys.executable, "-m", "repro.launch.dryrun", "--arch", "qwen2.5-3b",
-             "--shape", "decode_32k", "--mesh", "single", "--out", d],
+             "--shape", "train_4k", "--mesh", "single", "--out", d],
             capture_output=True, text=True, timeout=1500, env=env, cwd=REPO)
         assert r.returncode == 0, r.stderr[-2000:]
         out = json.load(open(os.path.join(
-            d, "qwen2.5-3b__decode_32k__single__tp.json")))
+            d, "qwen2.5-3b__train_4k__single__tp.json")))
         assert out["compiled_ok"]
         assert out["roofline"]["dominant"] in ("compute", "memory", "collective")
